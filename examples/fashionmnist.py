@@ -46,8 +46,8 @@ def main() -> int:
                              "(TensorBoard/xprof-readable)")
     args = parser.parse_args()
 
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
 
     from examples.utils.data import (iid_partition, load_fashion_mnist,
                                      non_iid_partition)
@@ -83,11 +83,10 @@ def main() -> int:
         batch_size=args.batch_size, secure_scheme=args.secure)
     if args.profile_dir:
         config.train.profile_dir = args.profile_dir
-    template = FlaxModelOps(FashionMnistCNN(),
-                            np.zeros((2, 28, 28, 1), np.float32),
-                            rng_seed=0).get_variables()
-
-    session = DriverSession(config, template,
+    # no template built here: this process launches the learners, so it
+    # must never touch a JAX backend itself (initial model = recipe 0's,
+    # built in a CPU child)
+    session = DriverSession(config, None,
                             [make_recipe(s) for s in shards],
                             workdir=args.workdir or None)
     stats = session.run()

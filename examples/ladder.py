@@ -34,7 +34,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from metisfl_tpu.platform import honor_platform_env  # noqa: E402
+from metisfl_tpu.platform import enter_process  # noqa: E402
 
 
 def _image_shards(num_learners, n_per, shape, classes, seed):
@@ -259,7 +259,7 @@ RUNGS = {"resnet": rung_resnet, "vit": rung_vit, "bert": rung_bert,
 
 
 def main() -> int:
-    honor_platform_env()
+    enter_process()
     parser = argparse.ArgumentParser("baseline config ladder")
     parser.add_argument("--rungs", default="resnet,vit,bert",
                         help=f"comma list from {sorted(RUNGS)}")

@@ -38,8 +38,8 @@ def main() -> int:
                         help="0 = absorb remaining devices")
     args = parser.parse_args()
 
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
 
     import numpy as np
 

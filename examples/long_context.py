@@ -51,8 +51,8 @@ def main() -> int:
                      "kernels); the ulysses local attention routes to "
                      "the flash kernel on its own")
 
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
 
     import numpy as np
 

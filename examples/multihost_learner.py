@@ -37,8 +37,8 @@ def main() -> int:
     parser.add_argument("--workdir", default="")
     args = parser.parse_args()
 
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
 
     from metisfl_tpu.comm.messages import TrainParams
     from metisfl_tpu.config import (
@@ -49,8 +49,6 @@ def main() -> int:
         TerminationConfig,
     )
     from metisfl_tpu.driver import DriverSession
-    from metisfl_tpu.models import FlaxModelOps
-    from metisfl_tpu.models.zoo import MLP
 
     rng = np.random.default_rng(0)
     w = rng.standard_normal((8, 3)).astype(np.float32)
@@ -75,9 +73,6 @@ def main() -> int:
                            np.zeros((2, 8), np.float32), rng_seed=0, **kwargs)
         return ops, ArrayDataset(x, y, seed=0), None, ArrayDataset(x, y)
 
-    template = FlaxModelOps(MLP(features=(16,), num_outputs=3),
-                            np.zeros((2, 8), np.float32),
-                            rng_seed=0).get_variables()
     config = FederationConfig(
         controller_port=free_port(),
         aggregation=AggregationConfig(scaler="participants"),
@@ -89,11 +84,13 @@ def main() -> int:
         termination=TerminationConfig(federation_rounds=args.rounds),
         learners=[LearnerEndpoint(world_size=args.world)],
     )
+    # no template built here: this process launches the learner world, so
+    # it must never touch a JAX backend itself (initial model = the
+    # recipe's, built in a CPU child)
     session = DriverSession(
-        config, template, [recipe],
+        config, None, [recipe],
         workdir=args.workdir or None,
         learner_env={
-            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
             "XLA_FLAGS": os.environ.get(
                 "XLA_FLAGS", "--xla_force_host_platform_device_count=4"),
         })

@@ -75,8 +75,8 @@ def main() -> int:
     parser.add_argument("--workdir", default="")
     args = parser.parse_args()
 
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
 
     from examples.utils.environment import generate_localhost_env
     from metisfl_tpu.config import EvalConfig
@@ -118,11 +118,11 @@ def main() -> int:
         batch_size=args.batch_size, learning_rate=0.02)
     config.eval = EvalConfig(batch_size=64, datasets=["test"],
                              metrics=["loss", "mse", "mae"])
-    template = FlaxModelOps(BrainAge3DCNN(), sample, loss="mse",
-                            rng_seed=0).get_variables()
-
+    # no template built here: this process launches the learners, so it
+    # must never touch a JAX backend itself (initial model = recipe 0's,
+    # built in a CPU child)
     session = DriverSession(
-        config, template,
+        config, None,
         [make_recipe(sx, sy, seed=i) for i, (sx, sy) in enumerate(shards)],
         workdir=args.workdir or None)
     stats = session.run()

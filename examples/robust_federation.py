@@ -32,8 +32,8 @@ def main() -> int:
                              "Gram matmul) instead of on the host")
     args = parser.parse_args()
 
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
 
     import numpy as np
 

@@ -26,8 +26,8 @@ def main() -> int:
     parser.add_argument("--local-steps", type=int, default=2)
     args = parser.parse_args()
 
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
 
     import jax
     import numpy as np

@@ -15,11 +15,14 @@ model into one executable instead of the reference's per-variable OpenMP loop
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Dict, Iterable, List, Optional, Protocol, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+logger = logging.getLogger("metisfl_tpu.aggregation")
 
 Pytree = Any
 
@@ -54,7 +57,7 @@ def is_host_tree(tree) -> bool:
 
     Fold locale policy: models that arrived over the wire (gRPC transport)
     are host numpy and fold on host BLAS — FedAvg is a ~1 FLOP/byte streaming
-    op, so shipping N models over PCIe/tunnel to reduce them on the device
+    op, so shipping N models over PCIe to reduce them on the device
     wastes exactly the bandwidth the reference's north star budgets
     (BASELINE.md ≤2 s @ 64 learners). Device-resident trees (co-located
     learner output, pod mode) fold on device; cross-learner pod aggregation
@@ -163,15 +166,27 @@ _hostfold_lib = None
 
 def _get_hostfold():
     """Native streaming-fold library (metisfl_tpu/native/hostfold.cc), or
-    None when the toolchain is unavailable — the numpy path then serves."""
+    None when it cannot be built here — the numpy fold then serves. Which
+    of the two it is gets logged once (and rides every round's metadata,
+    :func:`host_fold_backend`): the two differ several-fold in speed, so a
+    failed build must never be a silent one."""
     global _hostfold_lib
     if _hostfold_lib is None:
         try:
             from metisfl_tpu.native import load_hostfold
             _hostfold_lib = load_hostfold()
-        except Exception:  # no g++ / build failure: numpy fallback
+            logger.info("host fold: native (native/hostfold.cc)")
+        except (OSError, RuntimeError) as exc:  # no g++ / build failure
             _hostfold_lib = False
+            logger.warning("host fold: numpy — the native hostfold.cc "
+                           "build failed: %s", exc)
     return _hostfold_lib or None
+
+
+def host_fold_backend() -> str:
+    """Which implementation folds host-resident f32/f64 trees in this
+    process: ``"native"`` (hostfold.cc) or ``"numpy"``."""
+    return "native" if _get_hostfold() is not None else "numpy"
 
 
 def _native_fold(a, arrs, scales):

@@ -48,6 +48,7 @@ from metisfl_tpu import telemetry as _tel
 from metisfl_tpu.store import durable as _durable
 from metisfl_tpu.aggregation.tree import _DEFAULT_SUBBLOCK, TreeReducer
 from metisfl_tpu.comm.codec import dumps, loads
+from metisfl_tpu.comm.rpc import StopOnce
 from metisfl_tpu.secure.distributed import MaskedAccumulator
 from metisfl_tpu.telemetry import metrics as _tmetrics
 from metisfl_tpu.telemetry import prof as _prof
@@ -345,7 +346,7 @@ class SliceAggregator:
             }
 
 
-class SliceServer:
+class SliceServer(StopOnce):
     """Host a :class:`SliceAggregator` behind gRPC: the BytesService role
     (ListMethods / GetMetrics / CollectTelemetry mounted like every other
     role) plus grpc.health.v1 — the controller's slice supervision probes
@@ -356,6 +357,7 @@ class SliceServer:
         from metisfl_tpu.comm.health import SERVING, HealthServicer
         from metisfl_tpu.comm.rpc import BytesService, RpcServer
 
+        super().__init__()
         self.aggregator = SliceAggregator(spool_dir=spool_dir, name=name)
         self._server = RpcServer(host, port, ssl=ssl)
         self._health = HealthServicer()
@@ -370,7 +372,6 @@ class SliceServer:
             "GetMetrics": self._get_metrics,
             "ShutDown": self._shutdown_rpc,
         }, role="slice"))
-        self._shutdown_event = threading.Event()
         self.port: Optional[int] = None
 
     # -- handlers (RPC threads) -------------------------------------------
@@ -418,17 +419,11 @@ class SliceServer:
         self.port = self._server.start()
         return self.port
 
-    def stop(self) -> None:
-        if self._shutdown_event.is_set():
-            return
+    def _teardown(self) -> None:
         from metisfl_tpu.comm.health import NOT_SERVING
 
         self._health.set_all(NOT_SERVING)
-        self._shutdown_event.set()
         self._server.stop()
-
-    def wait_for_shutdown(self, timeout: Optional[float] = None) -> bool:
-        return self._shutdown_event.wait(timeout)
 
 
 class SliceClient:
@@ -493,6 +488,8 @@ class SliceClient:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from metisfl_tpu.platform import enter_process
+    enter_process()
     parser = argparse.ArgumentParser(
         "metisfl_tpu.aggregation.slice",
         description="slice aggregator process (BytesService role 'slice')")

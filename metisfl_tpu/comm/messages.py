@@ -120,8 +120,8 @@ class TrainParams(Message):
     device_stats: bool = True
     # Fuse this many optimizer steps into ONE jit-compiled lax.scan program.
     # Cuts host→device dispatch to 1/scan_chunk of the per-step path — the
-    # difference is pure overhead on TPU (and dominant when the chip sits
-    # behind a network tunnel). Cancellation is checked between chunks.
+    # difference is pure overhead on TPU. Cancellation is checked between
+    # chunks.
     scan_chunk: int = 1
     # Wire dtype for shipped model weights (a DType name: "bf16", "f16",
     # "f32", ..., or "int8q" for int8 absmax quantization with per-tensor

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import json
 import logging
+import threading
 import time
 from concurrent import futures
 from typing import Callable, Dict, Optional
@@ -355,6 +356,38 @@ class RpcServer:
 
     def wait(self) -> None:
         self._server.wait_for_termination()
+
+
+class StopOnce:
+    """Stop-once + wait-until-stopped lifecycle of a process's server.
+
+    ``wait_for_shutdown`` returns when teardown has FINISHED, not when it
+    began. An entry point's main thread exits on it, and interpreter
+    finalization flips ``concurrent.futures``' global shutdown flag: an
+    RPC that arrives while a ShutDown-RPC daemon thread is still tearing
+    the server down then kills gRPC's serve thread with "cannot schedule
+    new futures after shutdown", the server never finishes stopping, and
+    the process hangs until it is SIGKILLed — holding its chip. (Seen on
+    the first four-learner TPU run, where teardown waits on an 805 MB
+    task in flight.) Subclasses implement ``_teardown``."""
+
+    def __init__(self):
+        self._stop_lock = threading.Lock()
+        self._stopping = False
+        self._stopped = threading.Event()
+
+    def stop(self, **kwargs) -> None:
+        with self._stop_lock:
+            if self._stopping:
+                return
+            self._stopping = True
+        try:
+            self._teardown(**kwargs)
+        finally:
+            self._stopped.set()
+
+    def wait_for_shutdown(self, timeout: Optional[float] = None) -> bool:
+        return self._stopped.wait(timeout)
 
 
 class RpcClient:

@@ -196,8 +196,8 @@ def _standby_main(args, config, parser, metrics_http) -> int:
 
 
 def main(argv=None) -> int:
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
     parser = argparse.ArgumentParser("metisfl_tpu.controller")
     parser.add_argument("--config", required=True,
                         help="path to FederationConfig (.bin codec or .yaml)")

@@ -38,6 +38,7 @@ from typing import (Any, Callable, Dict, List, Optional, Protocol, Sequence,
 import numpy as np
 
 from metisfl_tpu.aggregation import make_aggregation_rule
+from metisfl_tpu.aggregation.base import host_fold_backend
 from metisfl_tpu.aggregation.secure import SecureAgg
 from metisfl_tpu.comm.codec import dumps as codec_dumps
 from metisfl_tpu.comm.codec import loads as codec_loads
@@ -210,6 +211,9 @@ class RoundMetadata:
     aggregation_block_sizes: List[int] = field(default_factory=list)
     aggregation_block_duration_ms: List[float] = field(default_factory=list)
     aggregation_duration_ms: float = 0.0
+    # which implementation folds host-resident trees in this controller
+    # ("native" = native/hostfold.cc, "numpy" = its build failed here)
+    host_fold: str = ""
     # phase breakdown sourced from the round's telemetry spans (trace and
     # lineage agree by construction): total train-dispatch time and the
     # dispatch-to-barrier-release wait. Absent in pre-telemetry payloads —
@@ -2160,6 +2164,7 @@ class Controller:
         # close the span here so its duration covers collection +
         # combine + blob encode — the same interval the old t0 delta did
         agg_sp.end()
+        host_fold = host_fold_backend()  # may build the library: no lock
         with self._lock:
             if self.config.secure.enabled:
                 self._community_opaque = community
@@ -2181,6 +2186,7 @@ class Controller:
             meta.aggregation_block_sizes = meta_blocks
             meta.aggregation_block_duration_ms = meta_durations
             meta.aggregation_duration_ms = agg_sp.duration_ms
+            meta.host_fold = host_fold
             if not self.config.secure.enabled:
                 sizes = {"values": 0, "non_zeros": 0, "zeros": 0, "bytes": 0}
                 for arr in community.values():

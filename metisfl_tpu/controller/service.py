@@ -23,7 +23,8 @@ from metisfl_tpu.comm.messages import (
     TaskResult,
     TrainTask,
 )
-from metisfl_tpu.comm.rpc import BytesService, RpcClient, RpcServer
+from metisfl_tpu.comm.rpc import (BytesService, RpcClient, RpcServer,
+                                  StopOnce)
 from metisfl_tpu.controller.core import Controller, LearnerRecord
 from metisfl_tpu.telemetry import profile as _tprofile
 
@@ -117,13 +118,14 @@ class RpcLearnerProxy:
             pass
 
 
-class ControllerServer:
+class ControllerServer(StopOnce):
     """Host a :class:`Controller` behind gRPC."""
 
     def __init__(self, controller: Controller, host: str = "0.0.0.0",
                  port: int = 50051, ssl=None):
         from metisfl_tpu.comm.health import SERVING, HealthServicer
 
+        super().__init__()
         self.controller = controller
         self._server = RpcServer(host, port, ssl=ssl)
         # standard grpc.health.v1 alongside the custom status RPC
@@ -150,7 +152,6 @@ class ControllerServer:
             "RollbackVersion": self._rollback_version,
             "ShutDown": self._shutdown_rpc,
         }, role="controller"))
-        self._shutdown_event = threading.Event()
         self.port: Optional[int] = None
 
     # -- handlers (RPC threads) -------------------------------------------
@@ -267,18 +268,12 @@ class ControllerServer:
         self.port = self._server.start()
         return self.port
 
-    def stop(self) -> None:
-        if self._shutdown_event.is_set():
-            return
+    def _teardown(self) -> None:
         from metisfl_tpu.comm.health import NOT_SERVING
 
         self._health_servicer.set_all(NOT_SERVING)
-        self._shutdown_event.set()
         self.controller.shutdown()
         self._server.stop()
-
-    def wait_for_shutdown(self, timeout: Optional[float] = None) -> bool:
-        return self._shutdown_event.wait(timeout)
 
 
 class ControllerClient:

@@ -36,6 +36,8 @@ from metisfl_tpu.chaos import ENV_VAR as _CHAOS_ENV_VAR
 from metisfl_tpu.comm.messages import TrainParams
 from metisfl_tpu.config import FederationConfig
 from metisfl_tpu.controller.service import ControllerClient
+from metisfl_tpu.platform import (CACHE_DIR_ENV, chip_env,
+                                  package_pythonpath)
 from metisfl_tpu.telemetry import events as _tevents
 from metisfl_tpu.telemetry import metrics as _tmetrics
 from metisfl_tpu.telemetry import postmortem as _tpostmortem
@@ -90,6 +92,36 @@ def _terminate_process(process: subprocess.Popen,
         process.wait(timeout=grace_s)
     except subprocess.TimeoutExpired:  # pragma: no cover - unkillable
         pass
+
+
+_INIT_MODEL_SNIPPET = """
+import sys, cloudpickle
+from metisfl_tpu.tensor.pytree import pack_model
+with open(sys.argv[1], "rb") as f:
+    recipe = cloudpickle.load(f)
+with open(sys.argv[2], "wb") as f:
+    f.write(pack_model(recipe()[0].get_variables()))
+"""
+
+
+def build_initial_model(recipe: Callable[[], tuple], workdir: str) -> bytes:
+    """Wire blob of the model ``recipe`` builds, computed in a short-lived
+    CPU child. The launching process must never initialize a JAX backend
+    itself: a parent that has touched the chip holds it, and the learners
+    it launches then fail or hang."""
+    recipe_path = os.path.join(workdir, "initial_model_recipe.pkl")
+    blob_path = os.path.join(workdir, "initial_model.bin")
+    with open(recipe_path, "wb") as f:
+        cloudpickle.dump(recipe, f)
+    subprocess.run(
+        [sys.executable, "-c", _INIT_MODEL_SNIPPET, recipe_path, blob_path],
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": package_pythonpath()},
+        check=True)
+    with open(blob_path, "rb") as f:
+        blob = f.read()
+    os.unlink(blob_path)  # a full model: not left behind in the workdir
+    return blob
 
 
 class LocalLauncher:
@@ -182,13 +214,35 @@ class DriverSession:
         learner_env: Optional[Dict[str, str]] = None,
         launcher_factory: Optional[Callable[[str], Any]] = None,
         resume: bool = False,
+        accelerator: str = "",
+        host_chips: int = 0,
     ):
+        """``initial_model_variables``: the host-numpy model the federation
+        starts from; ``None`` takes what learner recipe 0 builds
+        (:func:`build_initial_model`). ``accelerator``: the JAX platform
+        learners and the serving gateway are launched on (``"tpu"``: a
+        missing chip is then an error in the child, never a silent CPU
+        run). Empty leaves ``JAX_PLATFORMS`` to each child's launch
+        environment. Host roles
+        (controller, standby, router, slice aggregators) always run on
+        the CPU. ``host_chips`` > 1 splits that many chips of the local
+        host one per accelerator process (learner ``idx`` gets chip
+        ``idx % host_chips``, the gateway the next ones); 0 or 1 leaves
+        every accelerator process all the chips it can see — the
+        one-learner-owns-the-host shape."""
         self.config = config
-        self.initial_blob = pack_model(initial_model_variables)
         self.learner_recipes = list(learner_recipes)
         self.workdir = workdir or tempfile.mkdtemp(prefix="metisfl_tpu_")
         os.makedirs(self.workdir, exist_ok=True)
+        if initial_model_variables is None:
+            # what learner recipe 0 builds, computed off this process
+            self.initial_blob = build_initial_model(self.learner_recipes[0],
+                                                    self.workdir)
+        else:
+            self.initial_blob = pack_model(initial_model_variables)
         self.learner_env = learner_env or {}
+        self.accelerator = accelerator
+        self.host_chips = int(host_chips)
         self.resume = resume
         self._launcher_factory = launcher_factory
         self._local_launcher = LocalLauncher(self.workdir)
@@ -263,14 +317,29 @@ class DriverSession:
                             self.config.ssl.key_path) if p]
 
     def _base_env(self) -> Dict[str, str]:
-        # make the package importable in child processes regardless of cwd
-        import metisfl_tpu
-        pkg_root = os.path.dirname(os.path.dirname(
-            os.path.abspath(metisfl_tpu.__file__)))
-        pythonpath = os.pathsep.join(
-            p for p in (pkg_root, os.environ.get("PYTHONPATH", "")) if p)
-        return {"JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
-                "PYTHONPATH": pythonpath}
+        env = {"PYTHONPATH": package_pythonpath()}
+        # SSH children receive only this dict: an externally placed
+        # compile cache must reach them too
+        if os.environ.get(CACHE_DIR_ENV):
+            env[CACHE_DIR_ENV] = os.environ[CACHE_DIR_ENV]
+        return env
+
+    def _host_env(self) -> Dict[str, str]:
+        """Controller, standby, router, slice aggregators: wire models are
+        host numpy and fold on the host (aggregation/base.py), so these
+        never touch — or hold — a chip."""
+        return {**self._base_env(), "JAX_PLATFORMS": "cpu"}
+
+    def _accelerator_env(self, slot: int) -> Dict[str, str]:
+        """Learners and gateways: the platform the operator asked for and,
+        on a split host, the chip that is theirs. ``slot`` numbers the
+        accelerator processes: learners first, then gateway replicas."""
+        env = self._base_env()
+        if self.accelerator:
+            env["JAX_PLATFORMS"] = self.accelerator
+        if self.host_chips > 1:
+            env.update(chip_env(slot % self.host_chips))
+        return env
 
     def _prepare_secure(self) -> None:
         """Generate + distribute secure-aggregation material (the reference's
@@ -323,7 +392,12 @@ class DriverSession:
         return files
 
     def initialize_federation(self, health_retries: int = 30,
-                              health_sleep_s: float = 1.0) -> None:
+                              health_sleep_s: float = 1.0,
+                              launch_serving: bool = True) -> None:
+        """``launch_serving=False`` pins the serving ports but leaves the
+        gateway to a later :meth:`launch_serving` — for a host whose
+        chips the learners fill: train, :meth:`stop_learners`, then
+        serve the registry's model from a freed chip."""
         self._prepare_secure()
         # telemetry trace sinks default into the experiment workdir so
         # controller + learner spans stitch into one tree on disk; the
@@ -509,17 +583,24 @@ class DriverSession:
 
         for idx in range(len(self.learner_recipes)):
             self.launch_learner(idx)
-        if self.config.serving.enabled:
-            fleet = self.config.serving.fleet
-            if fleet.enabled:
-                for idx in range(len(fleet.gateways)):
-                    self._launch_gateway(idx)
-                self._launch_router()
-                self._setup_autoscaler()
-            else:
-                self._launch_gateway()
+        if launch_serving:
+            self.launch_serving()
         self._start_fleet_collector()
         self._started_at = time.time()
+
+    def launch_serving(self) -> None:
+        """Boot the serving plane (single gateway, or fleet replicas +
+        router) against the running controller's registry."""
+        if not self.config.serving.enabled:
+            return
+        fleet = self.config.serving.fleet
+        if fleet.enabled:
+            for idx in range(len(fleet.gateways)):
+                self._launch_gateway(idx)
+            self._launch_router()
+            self._setup_autoscaler()
+        else:
+            self._launch_gateway()
 
     # ------------------------------------------------------------------ #
     # fleet telemetry fabric (telemetry/fabric.py)
@@ -660,7 +741,7 @@ class DriverSession:
             argv.append("--resume")
         if isinstance(launcher, SSHLauncher):
             launcher.ship([self._config_path] + self._ssl_files())
-        env = dict(self._base_env())
+        env = self._host_env()
         if self._controller_restarts == 0:
             env.update(self._chaos_env("controller"))
         self._procs = [p for p in self._procs if p.name != "controller"]
@@ -684,7 +765,7 @@ class DriverSession:
                 "--standby"]
         if isinstance(launcher, SSHLauncher):
             launcher.ship([self._config_path] + self._ssl_files())
-        env = dict(self._base_env())
+        env = self._host_env()
         if not self._chaos_armed_standby:
             # original incarnation only, same posture as every other
             # chaos-killable process: a supervised relaunch runs clean
@@ -904,7 +985,8 @@ class DriverSession:
         if isinstance(launcher, SSHLauncher):
             launcher.ship([self._config_path, recipe_path]
                           + self._ssl_files())
-        env = dict(self._base_env())
+        env = self._accelerator_env(len(self.learner_recipes)
+                                    + (replica or 0))
         if name not in self._chaos_armed_serving:
             # original incarnation only — a supervised relaunch runs
             # clean, same contract as the controller/learner chaos
@@ -929,7 +1011,7 @@ class DriverSession:
                 "--config", self._config_path]
         if isinstance(launcher, SSHLauncher):
             launcher.ship([self._config_path] + self._ssl_files())
-        env = dict(self._base_env())
+        env = self._host_env()
         if "router" not in self._chaos_armed_serving:
             self._chaos_armed_serving.add("router")
             env.update(self._chaos_env("router"))
@@ -989,7 +1071,7 @@ class DriverSession:
                 "--index", str(idx)]
         if isinstance(launcher, SSHLauncher):
             launcher.ship([self._config_path] + self._ssl_files())
-        env = dict(self._base_env())
+        env = self._host_env()
         if idx not in self._chaos_armed_slices:
             # original incarnation only: kill-at-slice rules
             # (process="slice" / "slice_<idx>") must not re-fire on the
@@ -1344,7 +1426,7 @@ class DriverSession:
             # absolute paths (metisfl_tpu itself must be installed remotely)
             launcher.ship([recipe_path] + self._ssl_files()
                           + self._secure_files(idx))
-        env = {**self._base_env(), **self.learner_env}
+        env = {**self._accelerator_env(idx), **self.learner_env}
         if idx not in self._chaos_armed_learners:
             # original incarnation only: a relaunch (crash-rejoin) runs
             # clean, or a kill rule would re-fire on every restart and
@@ -1757,6 +1839,55 @@ class DriverSession:
                 len(paths), dest, dest)
         return paths
 
+    def _shutdown_learners(self) -> None:
+        """ShutDown RPC to every learner, dialing the endpoints learners
+        actually registered on join, not assumed port arithmetic. An
+        in-flight train task cancels between steps and the learner exits
+        on its own — the clean exit that releases its chip."""
+        from metisfl_tpu.comm.rpc import RpcClient
+        from metisfl_tpu.controller.service import LEARNER_SERVICE
+
+        endpoints: List[dict] = []
+        try:
+            endpoints = self._client.list_learners() if self._client else []
+        except Exception:  # noqa: BLE001 - controller may already be gone
+            # fall back to the last snapshot (+ any statically configured
+            # endpoints) so remote learners still get a ShutDown even when
+            # the controller died first
+            endpoints = list(self._known_endpoints)
+            known = {(e["hostname"], e["port"]) for e in endpoints}
+            for ep in self.config.learners:
+                if ep.port and (ep.hostname, ep.port) not in known:
+                    endpoints.append({"hostname": ep.hostname,
+                                      "port": ep.port})
+        for ep in endpoints:
+            try:
+                client = RpcClient(ep["hostname"], ep["port"], LEARNER_SERVICE,
+                                   retries=0, ssl=self.config.ssl)
+                client.call("ShutDown", b"", timeout=5.0, wait_ready=False)
+                client.close()
+            except Exception:  # noqa: BLE001 - learner may already be gone
+                pass
+
+    def stop_learners(self, timeout_s: float = 60.0) -> None:
+        """Shut every learner down and wait until its process has exited
+        (the controller, and so the registry, stays up). Raises unless
+        every learner exits cleanly within ``timeout_s``: a killed chip
+        holder can leave the chip locked for the next process."""
+        self._shutdown_learners()
+        deadline = time.time() + timeout_s
+        for proc in self._procs:
+            if not proc.name.startswith("learner_"):
+                continue
+            try:
+                proc.process.wait(timeout=max(0.5, deadline - time.time()))
+            except subprocess.TimeoutExpired:
+                _terminate_process(proc.process, grace_s=30.0)
+            if proc.process.returncode != 0:
+                raise RuntimeError(
+                    f"{proc.name} exited with code "
+                    f"{proc.process.returncode} while stopping")
+
     def shutdown_federation(self, timeout_s: Optional[float] = None) -> None:
         # Default drain budget: 15 s, or 150 s when any learner is a
         # multi-host world — its leader can only release the followers
@@ -1801,33 +1932,10 @@ class DriverSession:
             multihost = any(int(getattr(ep, "world_size", 1)) > 1
                             for ep in self.config.learners)
             timeout_s = 150.0 if multihost else 15.0
-        # learners first (reference _shutdown :344-364), then the controller —
-        # dialing the endpoints learners actually registered on join, not
-        # assumed port arithmetic
+        # learners first (reference _shutdown :344-364), then the controller
         from metisfl_tpu.comm.rpc import RpcClient
-        from metisfl_tpu.controller.service import LEARNER_SERVICE
 
-        endpoints: List[dict] = []
-        try:
-            endpoints = self._client.list_learners() if self._client else []
-        except Exception:  # noqa: BLE001 - controller may already be gone
-            # fall back to the last snapshot (+ any statically configured
-            # endpoints) so remote learners still get a ShutDown even when
-            # the controller died first
-            endpoints = list(self._known_endpoints)
-            known = {(e["hostname"], e["port"]) for e in endpoints}
-            for ep in self.config.learners:
-                if ep.port and (ep.hostname, ep.port) not in known:
-                    endpoints.append({"hostname": ep.hostname,
-                                      "port": ep.port})
-        for ep in endpoints:
-            try:
-                client = RpcClient(ep["hostname"], ep["port"], LEARNER_SERVICE,
-                                   retries=0, ssl=self.config.ssl)
-                client.call("ShutDown", b"", timeout=5.0, wait_ready=False)
-                client.close()
-            except Exception:  # noqa: BLE001 - learner may already be gone
-                pass
+        self._shutdown_learners()
         tree = self.config.aggregation.tree
         if tree.enabled and tree.distributed:
             # slice aggregators get the same fail-fast ShutDown as

@@ -51,8 +51,8 @@ def save_credentials(creds_dir: str, learner_id: str, auth_token: str) -> None:
 
 
 def main(argv=None) -> int:
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import enter_process
+    enter_process()
     parser = argparse.ArgumentParser("metisfl_tpu.learner")
     parser.add_argument("--controller-host", default="localhost")
     parser.add_argument("--controller-port", type=int, required=True)
@@ -125,8 +125,10 @@ def main(argv=None) -> int:
     # multi-host learner (one learner owning a multi-host TPU slice): join
     # the global runtime before any jax use (after logging setup so the
     # confirmation line is visible)
-    from metisfl_tpu.platform import maybe_init_distributed
+    from metisfl_tpu.platform import announce_devices, maybe_init_distributed
     maybe_init_distributed()
+    # first backend use: on JAX_PLATFORMS=tpu a missing chip fails here
+    announce_devices("learner")
 
     with open(args.recipe, "rb") as f:
         recipe = cloudpickle.load(f)

@@ -652,10 +652,9 @@ class Learner:
                 train_sp.set_attr("steps", out.completed_steps)
                 train_sp.set_attr("ms_per_step", round(out.ms_per_step, 3))
                 # steady-state step time x steps leaves (mostly) the
-                # one-off jit compile of the step/scan program — a live
-                # proxy for the trace capture the TPU watch scripts lost
-                # (ISSUE motivation). Attrs must land BEFORE the span
-                # ends: end() is what serializes the record to the sink.
+                # one-off jit compile of the step/scan program. Attrs
+                # must land BEFORE the span ends: end() is what
+                # serializes the record to the sink.
                 compile_s = max(0.0, train_sp.duration_ms / 1e3
                                 - out.completed_steps * out.ms_per_step / 1e3)
                 train_sp.set_attr("jit_compile_s_est", round(compile_s, 3))
@@ -746,27 +745,23 @@ class Learner:
     def _capture_device_stats(self, params, out) -> Dict[str, float]:
         """Device-utilization snapshot for one train task (performance
         observatory): step-time EWMA, achieved-MFU estimate from the
-        engine's FLOPs accounting, HBM watermark. Never raises — a
-        telemetry capture must not fail a completed task."""
+        engine's FLOPs accounting, HBM watermark. A device query that
+        fails raises like any other step of the task: a chip that stopped
+        answering is not a zero in a gauge."""
         from metisfl_tpu.telemetry import profile as _tprofile
 
-        try:
-            if self._device_monitor is None:
-                self._device_monitor = _tprofile.DeviceMonitor()
-            flops = 0.0
-            # probe through wrappers like the freeze-mask check above
-            # (multi-host LeaderOps has no FLOPs accounting — mfu reads 0)
-            engine = getattr(self.model_ops, "inner", self.model_ops)
-            step_flops = getattr(engine, "step_flops", None)
-            if callable(step_flops):
-                flops = float(step_flops(params.batch_size))
-            return self._device_monitor.observe(
-                steps=out.completed_steps, ms_per_step=out.ms_per_step,
-                flops_per_step=flops)
-        except Exception:  # noqa: BLE001 - telemetry never fails a task
-            logger.exception("%s: device-stats capture failed",
-                             self.learner_id)
-            return {}
+        if self._device_monitor is None:
+            self._device_monitor = _tprofile.DeviceMonitor()
+        flops = 0.0
+        # probe through wrappers like the freeze-mask check above
+        # (multi-host LeaderOps has no FLOPs accounting — no mfu sample)
+        engine = getattr(self.model_ops, "inner", self.model_ops)
+        step_flops = getattr(engine, "step_flops", None)
+        if callable(step_flops):
+            flops = float(step_flops(params.batch_size))
+        return self._device_monitor.observe(
+            steps=out.completed_steps, ms_per_step=out.ms_per_step,
+            flops_per_step=flops)
 
     def _scaffold_offset(self, control_bytes: bytes):
         """(c, c - c_i) for this task — both params-shaped f32 trees.

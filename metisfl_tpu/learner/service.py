@@ -13,18 +13,19 @@ from typing import Optional
 
 from metisfl_tpu.comm.codec import dumps, loads
 from metisfl_tpu.comm.messages import EvalTask, InferTask, TrainTask
-from metisfl_tpu.comm.rpc import BytesService, RpcServer
+from metisfl_tpu.comm.rpc import BytesService, RpcServer, StopOnce
 from metisfl_tpu.controller.service import LEARNER_SERVICE, ControllerClient
 from metisfl_tpu.learner.learner import Learner
 
 logger = logging.getLogger("metisfl_tpu.learner.service")
 
 
-class LearnerServer:
+class LearnerServer(StopOnce):
     def __init__(self, learner: Learner, host: str = "0.0.0.0", port: int = 0,
                  ssl=None):
         from metisfl_tpu.comm.health import SERVING, HealthServicer
 
+        super().__init__()
         self.learner = learner
         self._server = RpcServer(host, port, ssl=ssl)
         self._health_servicer = HealthServicer()
@@ -39,7 +40,6 @@ class LearnerServer:
             "GetMetrics": self._get_metrics,
             "ShutDown": self._shutdown_rpc,
         }, role="learner"))
-        self._shutdown_event = threading.Event()
         self._tasks_received = 0
         self.port: Optional[int] = None
 
@@ -80,14 +80,11 @@ class LearnerServer:
         self.learner.port = self.port
         return self.port
 
-    def stop(self, leave: bool = True) -> None:
-        if self._shutdown_event.is_set():
-            return
+    def _teardown(self, leave: bool = True) -> None:
         from metisfl_tpu.comm.health import NOT_SERVING
 
         self._health_servicer.set_all(NOT_SERVING)
         logger.info("learner server stopping (leave=%s)", leave)
-        self._shutdown_event.set()
         try:
             if leave:
                 self.learner.leave_federation()
@@ -95,6 +92,3 @@ class LearnerServer:
             logger.warning("leave_federation during shutdown failed")
         self.learner.shutdown()
         self._server.stop()
-
-    def wait_for_shutdown(self, timeout: Optional[float] = None) -> bool:
-        return self._shutdown_event.wait(timeout)

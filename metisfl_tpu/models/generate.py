@@ -11,8 +11,7 @@ path the TPU way:
   compiled program serves every step, no shape respecialization;
 - the whole generation (prefill + N decode steps) is ONE jitted program:
   ``lax.scan`` drives the token loop, sampling included, so the host
-  dispatches once per *sequence*, not once per token (behind a network
-  tunnel the per-token dispatch would dominate end-to-end latency);
+  dispatches once per *sequence*, not once per token;
 - GQA caches stay at kv-head size in HBM — decode is memory-bound, and
   heads/kv_heads is exactly the cache-bandwidth saving Llama-3 GQA buys;
 - early termination via an ``eos_id`` done-mask (scan has no data-dependent

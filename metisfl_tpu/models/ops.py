@@ -322,9 +322,8 @@ class FlaxModelOps:
         """``chunk`` optimizer steps as ONE compiled program: lax.scan over
         stacked batches with the training state as carry. One dispatch and
         one host sync per chunk instead of per step — on TPU the difference
-        is pure launch overhead (and dominant when the chip sits behind a
-        network tunnel). Same math as the per-step path: the scan body IS
-        the per-step function."""
+        is pure launch overhead. Same math as the per-step path: the scan
+        body IS the per-step function."""
         key = self._cfg_key(params_cfg) + ("scan", chunk)
         cached = self._step_cache.get(key)
         if cached is not None:

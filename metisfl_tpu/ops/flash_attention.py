@@ -256,7 +256,7 @@ def _resolve_blocks(L: int, blk_q: Optional[int], blk_k: Optional[int]):
     return blk_q, blk_k, Lp
 
 
-_SEQ_PARAMS = pltpu.TPUCompilerParams(
+_SEQ_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 

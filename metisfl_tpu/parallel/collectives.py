@@ -24,14 +24,10 @@ def to_varying(tree, axis_names):
     Required before ``jax.grad`` inside ``shard_map``: differentiating w.r.t.
     an *unvarying* (replicated) input transposes the implicit broadcast into
     a psum over the mesh — per-device gradients silently become cross-device
-    sums. (jax ≥0.9 VMA semantics; fixed here by casting params to varying
-    so the cotangent stays per-device.)"""
-    def cast(t):
-        try:
-            return jax.lax.pcast(t, axis_names, to="varying")
-        except AttributeError:  # pragma: no cover - older jax
-            return jax.lax.pvary(t, axis_names)
-    return jax.tree.map(cast, tree)
+    sums. (VMA semantics; fixed here by casting params to varying so the
+    cotangent stays per-device.)"""
+    return jax.tree.map(
+        lambda t: jax.lax.pcast(t, axis_names, to="varying"), tree)
 
 
 def federated_mean_psum(params, scale, axis_name: str = "fed"):

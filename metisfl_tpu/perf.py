@@ -14,7 +14,7 @@ The reading half of the performance observatory (telemetry/profile.py):
   direction-aware relative-threshold regression flags and a CI-friendly
   exit code (1 = regression detected, 0 = clean)::
 
-      python -m metisfl_tpu.perf --compare BENCH_r04.json BENCH_r05.json
+      python -m metisfl_tpu.perf --compare BENCH_r08.json BENCH_r09.json
 
 - **--trajectory <dir-or-files>** — the same diff across a whole series
   of captures (consecutive pairs), e.g. the repo's ``BENCH_r0*.json``
@@ -218,7 +218,7 @@ def render_waterfall(profiles: List[dict], width: int = 40,
                 codec_s = (float(entry.get("codec_encode_s", 0.0))
                            + float(entry.get("codec_decode_s", 0.0)))
                 device = entry.get("device") or {}
-                mfu = float(device.get("mfu", 0.0))
+                mfu = float(device.get("mfu") or 0.0)
                 step = float(device.get("step_ms_ewma", 0.0))
                 hbm = float(device.get("hbm_peak_bytes", 0))
                 lines.append(
@@ -380,9 +380,9 @@ def _parse_capture_tail(tail: str) -> Dict[str, Any]:
 
 _EXCLUDE_KEYS = {
     # harness bookkeeping, timestamps, and identity keys — never judged
-    "n", "rc", "ts", "schema_version", "errors", "last_dead_ts",
-    "probe_attempts", "recover_probes", "devices", "cpu_retry",
-    "degraded_to_cpu", "post_loop_recovery", "bench_wall_s",
+    # (probe_attempts: captures from before PR 21 still carry it)
+    "n", "rc", "ts", "schema_version", "errors", "probe_attempts",
+    "devices", "bench_wall_s",
 }
 
 

@@ -72,8 +72,8 @@ def run_router(config, host: str = "", port: int = -1) -> int:
 
 
 def main(argv=None) -> int:
-    from metisfl_tpu.platform import honor_platform_env
-    honor_platform_env()
+    from metisfl_tpu.platform import announce_devices, enter_process
+    enter_process()
     parser = argparse.ArgumentParser("metisfl_tpu.serving")
     parser.add_argument("--config", default="",
                         help="path to FederationConfig (.bin codec or .yaml)")
@@ -116,6 +116,8 @@ def main(argv=None) -> int:
     if not args.recipe:
         parser.error("--recipe is required for the gateway role")
     _apply_telemetry(config, service="serving")
+    # first backend use: on JAX_PLATFORMS=tpu a missing chip fails here
+    announce_devices("serving")
 
     with open(args.recipe, "rb") as f:
         recipe = cloudpickle.load(f)

@@ -45,6 +45,7 @@ import zlib
 from typing import Any, Callable, Dict, List, Optional
 
 from metisfl_tpu import telemetry as _tel
+from metisfl_tpu.comm.rpc import StopOnce
 from metisfl_tpu.telemetry import events as _tevents
 from metisfl_tpu.telemetry import metrics as _tmetrics
 from metisfl_tpu.telemetry import trace as _ttrace
@@ -463,7 +464,7 @@ class ServingRouter:
                 self._close_client(replica)
 
 
-class RouterServer:
+class RouterServer(StopOnce):
     """Host a :class:`ServingRouter` behind gRPC. Same service name as a
     gateway (``metisfl_tpu.Serving`` — a :class:`ServingClient` dials a
     router transparently) but ``role="router"`` on the reflection
@@ -475,6 +476,7 @@ class RouterServer:
         from metisfl_tpu.comm.rpc import BytesService, RpcServer
         from metisfl_tpu.serving.service import SERVING_SERVICE
 
+        super().__init__()
         self.router = router
         self._server = RpcServer(host, port, ssl=ssl)
         self._health_servicer = HealthServicer()
@@ -491,7 +493,6 @@ class RouterServer:
             "RemoveReplica": self._remove_replica,
             "ShutDown": self._shutdown_rpc,
         }, role="router"))
-        self._shutdown_event = threading.Event()
         self.port: Optional[int] = None
 
     # -- handlers (RPC threads) ----------------------------------------- #
@@ -557,17 +558,11 @@ class RouterServer:
         self.router.start_probes()
         return self.port
 
-    def stop(self) -> None:
-        if self._shutdown_event.is_set():
-            return
+    def _teardown(self) -> None:
         from metisfl_tpu.comm.health import NOT_SERVING
         self._health_servicer.set_all(NOT_SERVING)
-        self._shutdown_event.set()
         self._server.stop()
         self.router.shutdown()
-
-    def wait_for_shutdown(self, timeout: Optional[float] = None) -> bool:
-        return self._shutdown_event.wait(timeout)
 
 
 class FleetAutoscaler:

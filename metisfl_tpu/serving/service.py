@@ -18,7 +18,8 @@ import numpy as np
 from metisfl_tpu.comm.codec import dumps, loads
 from metisfl_tpu.comm.messages import (GenerateReply, GenerateRequest,
                                        ServeReply, ServeRequest)
-from metisfl_tpu.comm.rpc import BytesService, RpcClient, RpcServer
+from metisfl_tpu.comm.rpc import (BytesService, RpcClient, RpcServer,
+                                  StopOnce)
 from metisfl_tpu.serving.gateway import ServingGateway
 from metisfl_tpu.telemetry import trace as _ttrace
 from metisfl_tpu.tensor.pytree import ModelBlob
@@ -28,13 +29,14 @@ logger = logging.getLogger("metisfl_tpu.serving.service")
 SERVING_SERVICE = "metisfl_tpu.Serving"
 
 
-class ServingServer:
+class ServingServer(StopOnce):
     """Host a :class:`ServingGateway` behind gRPC."""
 
     def __init__(self, gateway: ServingGateway, host: str = "0.0.0.0",
                  port: int = 0, ssl=None):
         from metisfl_tpu.comm.health import SERVING, HealthServicer
 
+        super().__init__()
         self.gateway = gateway
         self._server = RpcServer(host, port, ssl=ssl)
         self._health_servicer = HealthServicer()
@@ -48,7 +50,6 @@ class ServingServer:
             "GetMetrics": self._get_metrics,
             "ShutDown": self._shutdown_rpc,
         }, role="serving"))
-        self._shutdown_event = threading.Event()
         self.port: Optional[int] = None
 
     # -- handlers (RPC threads) ---------------------------------------- #
@@ -112,21 +113,15 @@ class ServingServer:
         self.port = self._server.start()
         return self.port
 
-    def stop(self) -> None:
-        if self._shutdown_event.is_set():
-            return
+    def _teardown(self) -> None:
         from metisfl_tpu.comm.health import NOT_SERVING
 
         self._health_servicer.set_all(NOT_SERVING)
-        self._shutdown_event.set()
         # RPC server first: no new Predicts can race the gateway teardown
         # (a racing request would otherwise respawn a batcher worker on a
         # torn-down gateway)
         self._server.stop()
         self.gateway.shutdown()
-
-    def wait_for_shutdown(self, timeout: Optional[float] = None) -> bool:
-        return self._shutdown_event.wait(timeout)
 
 
 class ServingClient:

@@ -101,14 +101,11 @@ class _StubRegistry:
 
 def _launch_replica(config_path: str, recipe_path: str, idx: int,
                     port: int, replicas: int, workdir: str):
-    import metisfl_tpu
-    pkg_root = os.path.dirname(os.path.dirname(
-        os.path.abspath(metisfl_tpu.__file__)))
-    env = {**os.environ,
-           "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
-           "PYTHONPATH": os.pathsep.join(
-               p for p in (pkg_root,
-                           os.environ.get("PYTHONPATH", "")) if p)}
+    from metisfl_tpu.platform import package_pythonpath
+
+    # the replicas run on whatever platform this smoke was started on
+    # (the CI gate says JAX_PLATFORMS=cpu outright): no default here
+    env = {**os.environ, "PYTHONPATH": package_pythonpath()}
     log = open(os.path.join(workdir, f"replica_{idx}.log"), "a")
     return subprocess.Popen(
         [sys.executable, "-m", "metisfl_tpu.serving",

@@ -70,7 +70,7 @@ _M_DOWNLINK = _REG.counter(
 _M_MFU = _REG.gauge(
     _tel.M_LEARNER_ACHIEVED_MFU,
     "Achieved model FLOPs utilization per learner (estimated step FLOPs "
-    "over the chip's bf16 peak; 0 where the peak is unknown, e.g. CPU)",
+    "over the chip's bf16 peak; no sample from CPU learners)",
     ("learner",), budget_label="learner")
 _M_STEP_EWMA = _REG.gauge(
     _tel.M_LEARNER_STEP_MS_EWMA,
@@ -82,24 +82,33 @@ _M_HBM = _REG.gauge(
     "(device.memory_stats peak_bytes_in_use; 0 where unsupported)",
     ("learner",), budget_label="learner")
 
-# bf16 peak FLOP/s per chip by device_kind substring (first match wins) —
-# the MFU denominator. The ONE table: bench.py imports
-# device_peak_flops from here rather than keeping its own copy.
-CHIP_PEAKS = [
-    ("v5 lite", 197e12), ("v5e", 197e12),
-    ("v6 lite", 918e12), ("v6e", 918e12), ("trillium", 918e12),
-    ("v5p", 459e12), ("v5", 459e12),
-    ("v4", 275e12), ("v3", 123e12), ("v2", 46e12),
-]
+# bf16 peak FLOP/s per chip, keyed by the exact ``device_kind`` JAX
+# reports — the MFU denominator. The ONE table: bench.py imports
+# device_peak_flops from here rather than keeping its own copy. Every entry
+# names its source; an accelerator that is not here is an error, never a
+# default (a near-miss substring match once handed every unknown "v5…"
+# the v5p's peak).
+CHIP_PEAK_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip.
+    # The device_kind string is what the chip reports under jax 0.9.0 /
+    # libtpu 0.0.34 (CHANGES.md, PR 21).
+    "TPU v5 lite": 197e12,
+}
 
 
-def device_peak_flops(device_kind: str) -> float:
-    """bf16 peak FLOP/s for a jax device_kind string (0.0 = unknown)."""
-    kind = (device_kind or "").lower()
-    for key, peak in CHIP_PEAKS:
-        if key in kind:
-            return peak
-    return 0.0
+def device_peak_flops(device_kind: str) -> Optional[float]:
+    """bf16 peak FLOP/s for a jax ``device_kind``. ``None`` on the CPU
+    (there is no utilization to report); raises for an accelerator the
+    table does not know."""
+    if device_kind == "cpu":
+        return None
+    try:
+        return CHIP_PEAK_FLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s on record for accelerator {device_kind!r}: "
+            "add it to telemetry/profile.py CHIP_PEAK_FLOPS with its "
+            "source") from None
 
 
 # --------------------------------------------------------------------- #
@@ -182,9 +191,10 @@ class DeviceMonitor:
     """Per-learner device-utilization capture across train tasks:
     step-time EWMA (same alpha posture as the straggler analytics),
     achieved-MFU estimate, and the HBM high-water mark. ``observe``
-    returns the stats dict that ships in ``TaskResult.device_stats``;
-    everything device-specific is guarded — on CPU (or a backend without
-    memory_stats) the fields degrade to 0 instead of raising."""
+    returns the stats dict that ships in ``TaskResult.device_stats``.
+    On the CPU there is no peak to divide by and no ``memory_stats``:
+    ``mfu`` is left out and the HBM mark reads 0. A device query that
+    fails on an accelerator raises — it is never folded into a zero."""
 
     def __init__(self, alpha: float = 0.3):
         self.alpha = alpha
@@ -193,27 +203,18 @@ class DeviceMonitor:
         self._device_kind = ""
 
     def _resolve_device(self) -> None:
-        if self._peak_flops is not None:
+        if self._device_kind:
             return
-        try:
-            import jax
+        import jax
 
-            dev = jax.local_devices()[0]
-            self._device_kind = getattr(dev, "device_kind", "") or ""
-        except Exception:  # noqa: BLE001 - no backend is a valid state
-            self._device_kind = ""
+        self._device_kind = jax.local_devices()[0].device_kind
         self._peak_flops = device_peak_flops(self._device_kind)
 
     def _hbm_peak_bytes(self) -> int:
-        try:
-            import jax
+        import jax
 
-            stats = jax.local_devices()[0].memory_stats()
-            if stats:
-                return int(stats.get("peak_bytes_in_use", 0) or 0)
-        except Exception:  # noqa: BLE001 - unsupported backends return 0
-            pass
-        return 0
+        stats = jax.local_devices()[0].memory_stats()  # None on the CPU
+        return int((stats or {}).get("peak_bytes_in_use", 0) or 0)
 
     def observe(self, steps: int, ms_per_step: float,
                 flops_per_step: float = 0.0) -> Dict[str, Any]:
@@ -224,18 +225,18 @@ class DeviceMonitor:
             else:
                 self.step_ms_ewma = (self.alpha * ms_per_step
                                      + (1.0 - self.alpha) * self.step_ms_ewma)
-        mfu = 0.0
-        if (self._peak_flops and flops_per_step > 0.0 and ms_per_step > 0.0):
-            mfu = flops_per_step / (ms_per_step / 1e3) / self._peak_flops
-        return {
+        stats = {
             "steps": int(steps),
             "ms_per_step": round(float(ms_per_step), 4),
             "step_ms_ewma": round(self.step_ms_ewma, 4),
             "flops_per_step": float(flops_per_step),
-            "mfu": round(float(mfu), 5),
             "hbm_peak_bytes": self._hbm_peak_bytes(),
             "device_kind": self._device_kind,
         }
+        if self._peak_flops and flops_per_step > 0.0 and ms_per_step > 0.0:
+            stats["mfu"] = round(
+                flops_per_step / (ms_per_step / 1e3) / self._peak_flops, 5)
+        return stats
 
 
 # --------------------------------------------------------------------- #
@@ -371,8 +372,8 @@ class ProfileCollector:
         try:
             _M_STEP_EWMA.set(float(stats.get("step_ms_ewma", 0.0) or 0.0),
                              learner=learner_id)
-            _M_MFU.set(float(stats.get("mfu", 0.0) or 0.0),
-                       learner=learner_id)
+            if stats.get("mfu") is not None:
+                _M_MFU.set(float(stats["mfu"]), learner=learner_id)
             _M_HBM.set(float(stats.get("hbm_peak_bytes", 0) or 0),
                        learner=learner_id)
         except (TypeError, ValueError):

@@ -19,10 +19,10 @@ layer's telemetry, native to the existing planes:
   (steady-state call, nothing compiling) costs one attribute check plus
   a thread-local set/restore; the abstract shape signature is computed
   ONLY when a compile actually fired during the call. Compiles outside
-  any wrapper record as ``(unattributed)``. When ``jax.monitoring`` is
-  unavailable the wrapper falls back to per-call signature tracking
-  (a new signature for a wrapped function = one compile, duration = the
-  call's wall time — an upper bound).
+  any wrapper record as ``(unattributed)``. A compile the persistent
+  compilation cache served (``metisfl_tpu.platform.enter_process``
+  places it) still fires the duration event — its duration is the
+  retrieval — and is counted per function as a ``cache_hits``.
 
 - **Classification** — the first compile for a function name is
   ``cold``; every later compile of the same name is a **recompile**
@@ -90,6 +90,8 @@ DEFAULT_STORM_THRESHOLD = 4   # recompiles of ONE fn inside the window
 # (jaxpr trace / MLIR lowering fire their own events; counting those
 # would triple every compile)
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# fires inside that compile's window when the persistent cache served it
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 UNATTRIBUTED = "(unattributed)"
 OTHER = "_other"
@@ -130,7 +132,8 @@ class _State:
         self.storm_threshold = DEFAULT_STORM_THRESHOLD
         self.plane = "host"
         self.lock = threading.Lock()
-        # fn -> {"cold", "recompiles", "total_s", "max_s", "last_sig"}
+        # fn -> {"cold", "recompiles", "cache_hits", "total_s", "max_s",
+        #        "last_sig"}
         self.fns: Dict[str, Dict[str, Any]] = {}
         self.compiles = 0
         self.recompiles = 0
@@ -146,7 +149,7 @@ class _State:
 
 _STATE = _State()
 _TLS = threading.local()
-# "none" (never armed) | "monitoring" | "fallback"
+# "none" (never armed) | "monitoring"
 _LISTENER_MODE = "none"
 _LISTENER_LOCK = threading.Lock()
 
@@ -157,8 +160,7 @@ def enabled() -> bool:
 
 def listener_mode() -> str:
     """How compiles are observed: ``monitoring`` (jax.monitoring duration
-    listener), ``fallback`` (per-call signature tracking), or ``none``
-    (never armed — the opt-out pin)."""
+    listener) or ``none`` (never armed — the opt-out pin)."""
     return _LISTENER_MODE
 
 
@@ -191,16 +193,18 @@ def _install_listener() -> None:
     with _LISTENER_LOCK:
         if _LISTENER_MODE != "none":
             return
-        try:
-            from jax import monitoring as _monitoring
+        from jax import monitoring as _monitoring
 
-            _monitoring.register_event_duration_secs_listener(_on_duration)
-            _LISTENER_MODE = "monitoring"
-        except Exception:  # noqa: BLE001 - no jax / an older jax without
-            # monitoring: the wrapper-based signature fallback takes over
-            _LISTENER_MODE = "fallback"
-            logger.info("jax.monitoring unavailable; compile tracking "
-                        "falls back to per-call signature detection")
+        _monitoring.register_event_duration_secs_listener(_on_duration)
+        _monitoring.register_event_listener(_on_event)
+        _LISTENER_MODE = "monitoring"
+
+
+def _on_event(event: str, **kwargs) -> None:
+    """Persistent-cache hit: flag the compile in flight on this thread;
+    the backend-compile duration event that closes it consumes the flag."""
+    if _STATE.enabled and event == _CACHE_HIT_EVENT:
+        _TLS.cache_hit = True
 
 
 def _on_duration(event: str, duration: float, **kwargs) -> None:
@@ -209,13 +213,15 @@ def _on_duration(event: str, duration: float, **kwargs) -> None:
     context a :func:`monitored_jit` wrapper set around its call."""
     if not _STATE.enabled or event != _BACKEND_COMPILE_EVENT:
         return
+    cached = getattr(_TLS, "cache_hit", False)
+    _TLS.cache_hit = False
     pending = getattr(_TLS, "pending", None)
     if pending is not None:
         # inside a monitored call window: the wrapper records it (with
         # the signature it only computes because this fired)
-        pending.append(float(duration))
+        pending.append((float(duration), cached))
     else:
-        _record_compile(UNATTRIBUTED, "", float(duration))
+        _record_compile(UNATTRIBUTED, "", float(duration), cached)
 
 
 def configure(enabled: bool = True, budget: int = 0,
@@ -286,12 +292,14 @@ def _fn_row(fn: str) -> Dict[str, Any]:
             row = st.fns.get(OTHER)
         if row is None:
             row = st.fns[fn] = {"cold": 0, "recompiles": 0,
+                                "cache_hits": 0,
                                 "total_s": 0.0, "max_s": 0.0,
                                 "last_sig": ""}
     return row
 
 
-def _record_compile(fn: str, sig: str, duration_s: float) -> None:
+def _record_compile(fn: str, sig: str, duration_s: float,
+                    cached: bool = False) -> None:
     st = _STATE
     now = time.time()
     with st.lock:
@@ -309,6 +317,7 @@ def _record_compile(fn: str, sig: str, duration_s: float) -> None:
         else:
             row["recompiles"] += 1
             st.recompiles += 1
+        row["cache_hits"] += int(cached)
         row["total_s"] += duration_s
         row["max_s"] = max(row["max_s"], duration_s)
         row["last_sig"] = sig
@@ -384,8 +393,6 @@ def monitored_jit(fn: Callable, *, name: str = "", **jit_kwargs):
     def wrapper(*args, **kwargs):
         if not _STATE.enabled:
             return compiled(*args, **kwargs)
-        if _LISTENER_MODE == "fallback":
-            return _call_fallback(label, wrapper, compiled, args, kwargs)
         prev_pending = getattr(_TLS, "pending", None)
         _TLS.pending = []
         try:
@@ -394,30 +401,13 @@ def monitored_jit(fn: Callable, *, name: str = "", **jit_kwargs):
             fired, _TLS.pending = _TLS.pending, prev_pending
             if fired:
                 sig = _abstract_sig(args, kwargs)
-                for duration in fired:
-                    _record_compile(label, sig, duration)
+                for duration, cached in fired:
+                    _record_compile(label, sig, duration, cached)
         return out
 
     wrapper.__name__ = label
     wrapper.__wrapped__ = compiled
     return wrapper
-
-
-def _call_fallback(label: str, wrapper, compiled, args, kwargs):
-    """No jax.monitoring: a new abstract signature for a wrapped fn IS a
-    compile; its duration reports as the call's wall time (upper bound,
-    flagged via listener_mode()=='fallback')."""
-    sig = _abstract_sig(args, kwargs)
-    seen = getattr(wrapper, "_sigs_seen", None)
-    if seen is None:
-        seen = wrapper._sigs_seen = set()
-    fresh = sig not in seen
-    t0 = time.perf_counter() if fresh else 0.0
-    out = compiled(*args, **kwargs)
-    if fresh:
-        seen.add(sig)
-        _record_compile(label, sig, time.perf_counter() - t0)
-    return out
 
 
 # --------------------------------------------------------------------- #
@@ -561,10 +551,12 @@ def merge_states(states: List[Dict[str, Any]],
             if fn not in fns and len(fns) >= budget and fn != OTHER:
                 fn = OTHER
             dst = fns.setdefault(fn, {"cold": 0, "recompiles": 0,
+                                      "cache_hits": 0,
                                       "total_s": 0.0, "max_s": 0.0,
                                       "last_sig": ""})
             dst["cold"] += int(row.get("cold", 0) or 0)
             dst["recompiles"] += int(row.get("recompiles", 0) or 0)
+            dst["cache_hits"] += int(row.get("cache_hits", 0) or 0)
             dst["total_s"] += float(row.get("total_s", 0.0) or 0.0)
             dst["max_s"] = max(dst["max_s"],
                                float(row.get("max_s", 0.0) or 0.0))
@@ -592,6 +584,7 @@ def compile_rows(state: Dict[str, Any]) -> List[Dict[str, Any]]:
                 row.get("recompiles", 0)),
             "cold": int(row.get("cold", 0)),
             "recompiles": int(row.get("recompiles", 0)),
+            "cache_hits": int(row.get("cache_hits", 0)),
             "total_s": round(float(row.get("total_s", 0.0)), 4),
             "max_s": round(float(row.get("max_s", 0.0)), 4),
             "last_sig": str(row.get("last_sig", "")),

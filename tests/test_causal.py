@@ -396,12 +396,12 @@ def test_critical_path_knobs_config_template_and_docs():
 
 
 def test_flash_attention_imports_cleanly():
-    # the API-rot satellite: pltpu.CompilerParams no longer exists; the
-    # module must import (plain import — ``import ... as`` resolves the
-    # ops package's custom_vjp ATTRIBUTE, not the module)
+    # the module must import on the installed jax (plain import —
+    # ``import ... as`` resolves the ops package's custom_vjp ATTRIBUTE,
+    # not the module)
     mod = importlib.import_module("metisfl_tpu.ops.flash_attention")
     from jax.experimental.pallas import tpu as pltpu
-    assert isinstance(mod._SEQ_PARAMS, pltpu.TPUCompilerParams)
+    assert isinstance(mod._SEQ_PARAMS, pltpu.CompilerParams)
     assert mod._SEQ_PARAMS.dimension_semantics == ("parallel", "parallel",
                                                    "arbitrary")
 

@@ -433,11 +433,21 @@ def test_device_monitor_ewma_and_mfu_math():
     s2 = monitor.observe(steps=4, ms_per_step=20.0, flops_per_step=5e11)
     assert s2["step_ms_ewma"] == pytest.approx(15.0)
     assert s2["mfu"] == pytest.approx(0.25)
-    # CPU/unknown chip: mfu degrades to 0, nothing raises
+    # CPU: there is no peak to divide by — no mfu sample at all, not a 0
     cold = tprofile.DeviceMonitor()
-    cold._peak_flops = 0.0
     out = cold.observe(steps=1, ms_per_step=1.0, flops_per_step=1e9)
-    assert out["mfu"] == 0.0
+    assert out["device_kind"] == "cpu"
+    assert "mfu" not in out
+
+
+def test_device_peak_flops_exact_kind_or_error():
+    assert tprofile.device_peak_flops("TPU v5 lite") == 197e12
+    assert tprofile.device_peak_flops("cpu") is None
+    # an accelerator the table does not know is an error, never a default
+    # (the old substring match handed any "v5..." the v5p's peak)
+    for kind in ("TPU v5", "TPU v7x", "tpu v5 lite", ""):
+        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+            tprofile.device_peak_flops(kind)
 
 
 def test_device_tracer_unique_dirs_and_exception_safe_stop(tmp_path,
@@ -652,7 +662,6 @@ def test_perf_trajectory_parses_driver_and_degraded_captures(tmp_path,
 def test_bench_emits_schema_version_and_final_marker(capsys, monkeypatch):
     import bench
 
-    monkeypatch.setattr(bench, "_printed", False)
     result = bench._result_from(
         {"ms_per_round_median": 123.0, "mfu": 0.21}, {"mfu": "x"}, 8)
     assert result["schema_version"] == bench.SCHEMA_VERSION == 2

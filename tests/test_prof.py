@@ -567,12 +567,12 @@ def test_bench_repeat_noisy_keys_median(monkeypatch):
     ])
     monkeypatch.setattr(
         bench, "_run_section",
-        lambda name, quick, timeout, errors, info, **kw: next(runs))
+        lambda name, quick, timeout, errors, **kw: next(runs))
     first = {"obs_expose_ms_10k_exact": 30.0, "obs_bytes": 99,
              "obs_big_ms": 800.0}
     details = dict(first)
     monkeypatch.setenv("METISFL_BENCH_REPEATS", "3")
-    bench._repeat_noisy_keys("obs", first, False, details, {})
+    bench._repeat_noisy_keys("obs", first, False, details)
     # the sub-threshold ms key became the median of 3 samples
     assert details["obs_expose_ms_10k_exact"] == 30.0
     assert details["repeats"] == {"obs_expose_ms_10k_exact": 3}
@@ -608,42 +608,6 @@ def test_prof_bench_keys_direction_classified():
     assert perf.metric_direction("prof_acquire_ns_timed") == -1
     # the overhead ratio is deliberately informational (noise of noise)
     assert perf.metric_direction("prof_overhead_pct") == 0
-
-
-def test_bench_partial_writer_lands_outside_repo_root(tmp_path,
-                                                      monkeypatch):
-    """Satellite regression: EXECUTE the partial writer path and pin
-    that the default target is not the repo root and is git-ignored.
-    (scripts/tpu_watch.py mutates bench._PARTIAL_PATH when imported, so
-    the default is restored explicitly before the write.)"""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_partial_test", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    default = bench._default_partial_path()
-    assert os.path.dirname(default) == os.path.join(REPO, "bench_results")
-    monkeypatch.setattr(bench, "_PARTIAL_PATH", default)
-    bench._persist_partials({"probe_key": 1.0}, {})
-    try:
-        assert os.path.exists(default)
-        with open(default) as fh:
-            assert json.load(fh)["details"]["probe_key"] == 1.0
-        rel = os.path.relpath(default, REPO)
-        assert not rel.startswith(".."), rel
-        rc = subprocess.run(["git", "check-ignore", "-q", rel],
-                            cwd=REPO).returncode
-        assert rc == 0, f"{rel} is not gitignored"
-        # the repo root itself stays clean
-        assert not os.path.exists(os.path.join(REPO, "bench_partial.json"))
-    finally:
-        for suffix in ("", ".tmp"):
-            try:
-                os.unlink(default + suffix)
-            except OSError:
-                pass
 
 
 # --------------------------------------------------------------------- #

@@ -660,38 +660,6 @@ def test_tree_close_is_idempotent_and_reusable():
 
 
 # --------------------------------------------------------------------- #
-# bench artifacts stay ignored (satellite)
-# --------------------------------------------------------------------- #
-
-def test_bench_partial_artifacts_are_gitignored():
-    """The bench run's crash-durable partials (and their staging files)
-    must be ignored at every path bench.py can write — the
-    ``bench_results/`` default (round 13: the writer moved out of the
-    repo root at the source; the actual writer path is EXECUTED by
-    tests/test_prof.py::test_bench_partial_writer_lands_outside_repo_root),
-    the legacy repo-root location, AND the scripts/tpu_watch.py
-    redirection (whose .tmp was the round-9 gap) — and the stray
-    committed copy must stay gone.
-
-    ``bench._PARTIAL_PATH`` is deliberately NOT read at runtime here:
-    importing scripts/tpu_watch.py (which other tests do) mutates it, so
-    the pin covers every known target explicitly."""
-    for path in ("bench_partial.json", "bench_partial.json.tmp",
-                 "bench_results/bench_partial.json",
-                 "bench_results/bench_partial.json.tmp",
-                 "scripts/tpu_watch_partial.json",
-                 "scripts/tpu_watch_partial.json.tmp"):
-        rc = subprocess.run(["git", "check-ignore", "-q", path],
-                            cwd=REPO).returncode
-        assert rc == 0, f"{path} is not gitignored"
-    tracked = subprocess.run(
-        ["git", "ls-files", "--", "bench_partial*",
-         "scripts/tpu_watch_partial*"],
-        cwd=REPO, capture_output=True, text=True).stdout.strip()
-    assert tracked == "", f"stray bench partials tracked: {tracked}"
-
-
-# --------------------------------------------------------------------- #
 # status render
 # --------------------------------------------------------------------- #
 
