@@ -271,6 +271,13 @@ class TaskResult(Message):
     # federation-wide. Empty when TrainParams.device_stats is false
     # (profile plane opted out) or the task completed zero steps.
     device_stats: Dict[str, Any] = field(default_factory=dict)
+    # The task's waterfall on the learner's clock (learner/learner.py):
+    # milliseconds per tile (telemetry/profile.py TASK_TILES), contiguous
+    # from the RunTask RPC's acceptance to the start of this report, and
+    # ``start``, that acceptance as ``time.time()``. Lands in
+    # ``RoundProfile.learners[lid]["task"]``. Empty from a learner that
+    # predates it.
+    task_tiles: Dict[str, float] = field(default_factory=dict)
 
 
 @dataclass

@@ -1111,11 +1111,18 @@ class Controller:
             # and prunes the series after — an unlocked inc here could
             # interleave and resurrect a departed learner's series
             _M_UPLINK.inc(len(result.model), learner=result.learner_id)
-            if self._profile is not None and result.device_stats:
-                # learner-shipped device utilization (step EWMA, MFU,
-                # HBM watermark) → per-learner gauges + the round profile
-                self._profile.note_device(result.learner_id,
-                                          result.device_stats)
+            if self._profile is not None:
+                if result.device_stats:
+                    # learner-shipped device utilization (step EWMA, MFU,
+                    # HBM watermark) → per-learner gauges + the round
+                    # profile
+                    self._profile.note_device(result.learner_id,
+                                              result.device_stats)
+                if result.task_tiles and not stale:
+                    # the learner's own task waterfall → the round
+                    # profile (a stale one belongs to an abandoned round)
+                    self._profile.note_task(result.learner_id,
+                                            result.task_tiles)
         _tevents.emit(_tevents.TaskCompleted, task_id=result.task_id,
                       learner_id=result.learner_id, round=result.round_id,
                       stale=stale, uplink_bytes=len(result.model))

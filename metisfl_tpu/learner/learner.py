@@ -61,10 +61,6 @@ _M_TRAIN_STEP_MS = _REG.histogram(
     _tel.M_LEARNER_STEP_MILLISECONDS, "Median per-optimizer-step time",
     buckets=(0.1, 0.25, 0.5, 1, 2.5, 5, 10, 25, 50, 100, 250, 500, 1000,
              5000))
-_M_JIT_COMPILE = _REG.histogram(
-    _tel.M_LEARNER_JIT_COMPILE_SECONDS,
-    "Estimated jit-compile overhead per train task (task wall-clock "
-    "minus steps x steady-state step time)")
 _M_TASKS = _REG.counter(
     _tel.M_LEARNER_TASKS_TOTAL, "Train tasks by outcome",
     ("outcome",))
@@ -534,27 +530,35 @@ class Learner:
         # in-process): the train executor thread has its own contextvars
         # context, so the parent link must travel explicitly
         trace_ctx = _ttrace.current_context()
+        # where the task's waterfall begins: the RPC is accepted here and
+        # the task may wait for the train thread (the ``queued`` tile)
+        accepted = (time.time(), time.perf_counter())
         with self._task_lock:
             if self._current_future is not None and not self._current_future.done():
                 self._cancel.set()
             self._current_future = self._executor.submit(
-                self._train_and_report, task, trace_ctx)
+                self._train_and_report, task, trace_ctx, accepted)
 
-    def _train_and_report(self, task: TrainTask,
-                          trace_ctx=None) -> None:
+    def _train_and_report(self, task: TrainTask, trace_ctx=None,
+                          accepted=None) -> None:
         self._cancel.clear()
+        if accepted is None:
+            accepted = (time.time(), time.perf_counter())
+        queued_ms = (time.perf_counter() - accepted[1]) * 1e3
         task_sp = _ttrace.span(
             "learner.train", parent=trace_ctx,
             attrs={"task_id": task.task_id, "round": task.round_id,
-                   "learner": self.learner_id})
+                   "learner": self.learner_id,
+                   "queued_ms": round(queued_ms, 3)})
         with task_sp, task_sp.activate():
-            self._run_train_task(task, task_sp)
+            self._run_train_task(task, task_sp, accepted, queued_ms)
         # the whole task — load + train + dump + report — matching the
         # metric's end-to-end contract (learner.train_steps has its own
-        # step/compile histograms)
+        # step histogram)
         _M_TRAIN_DURATION.observe(task_sp.duration_ms / 1e3)
 
-    def _run_train_task(self, task: TrainTask, task_sp) -> None:
+    def _run_train_task(self, task: TrainTask, task_sp, accepted,
+                        queued_ms: float) -> None:
         try:
             # on the serialized train thread, BEFORE paying for training:
             # a task from a restarted controller refreshes registration
@@ -629,7 +633,13 @@ class Learner:
                                                           with_wire=True)
                 else:
                     incoming = self._load_model(task.model)
-            self.model_ops.set_variables(incoming)
+            # the whole tree host -> device. No sync marks its end: a
+            # copy still in flight when this returns is waited for by what
+            # first needs the arrays (the engine's eager optimizer init
+            # before the first feed, which no tile claims: ``other``)
+            upload_sp = _ttrace.span("learner.upload")
+            with upload_sp:
+                self.model_ops.set_variables(incoming)
             grad_offset = None
             scaffold_c = None
             if task.scaffold or task.control:
@@ -645,24 +655,20 @@ class Learner:
             train_kwargs = ({"grad_offset": grad_offset}
                             if grad_offset is not None else {})
             train_sp = _ttrace.span("learner.train_steps")
-            with train_sp:
+            # activated: the engine's train.feed / train.steps /
+            # train.readback events (models/ops.py) parent under it
+            with train_sp, train_sp.activate():
                 out = self.model_ops.train(self.datasets["train"], params,
                                            cancel_event=self._cancel,
                                            **train_kwargs)
+                # attrs must land BEFORE the span ends: end() is what
+                # serializes the record to the sink
                 train_sp.set_attr("steps", out.completed_steps)
                 train_sp.set_attr("ms_per_step", round(out.ms_per_step, 3))
-                # steady-state step time x steps leaves (mostly) the
-                # one-off jit compile of the step/scan program. Attrs
-                # must land BEFORE the span ends: end() is what
-                # serializes the record to the sink.
-                compile_s = max(0.0, train_sp.duration_ms / 1e3
-                                - out.completed_steps * out.ms_per_step / 1e3)
-                train_sp.set_attr("jit_compile_s_est", round(compile_s, 3))
             if out.completed_steps > 0 and out.ms_per_step > 0:
                 # a zero-step task (instant cancel, empty dataset) has no
-                # step baseline — its wall-clock is not compile time
+                # step baseline
                 _M_TRAIN_STEP_MS.observe(out.ms_per_step)
-                _M_JIT_COMPILE.observe(compile_s)
             # chaos 'slow' fault (chaos/injector.py): stretch this task's
             # wall-clock by the armed factor — a slow SURVIVOR, the churn
             # case only straggler deadlines / quorum barriers can defend
@@ -683,7 +689,8 @@ class Learner:
             # refresh the snapshot evals and later merges read from —
             # under the task lock so _adopt_local_regex's fallback install
             # can never interleave with (and overwrite) this fresh snapshot
-            with self._task_lock:
+            snapshot_sp = _ttrace.span("learner.snapshot")
+            with snapshot_sp, self._task_lock:
                 self._snapshot_local()
             # round-scoped mask derivation (pairwise-masking secure agg)
             if self.secure_backend is not None and hasattr(
@@ -716,6 +723,22 @@ class Learner:
                         ship_dtype=params.ship_dtype, variables=ship_vars)
                 dump_sp.set_attr("bytes", len(model_bytes))
             task_sp.set_attr("uplink_bytes", len(model_bytes))
+            # the task's waterfall: contiguous tiles from the RPC's
+            # acceptance to here, on this process's clock. ``other`` is
+            # what no tile claims (scaffold, DP, secure masks, glue), so
+            # they sum to the elapsed time by construction; no device
+            # sync is added to find a tile's end.
+            tiles = {"queued": queued_ms,
+                     "load": load_sp.duration_ms,
+                     "upload": upload_sp.duration_ms,
+                     "feed": out.feed_ms, "steps": out.steps_ms,
+                     "readback": out.readback_ms,
+                     "snapshot": snapshot_sp.duration_ms,
+                     "encode": dump_sp.duration_ms}
+            task_ms = (time.perf_counter() - accepted[1]) * 1e3
+            tiles["other"] = task_ms - sum(tiles.values())
+            task_tiles = {k: round(v, 3) for k, v in tiles.items()}
+            task_tiles["start"] = round(accepted[0], 6)
             result = TaskResult(
                 task_id=task.task_id,
                 learner_id=self.learner_id,
@@ -732,8 +755,13 @@ class Learner:
                 epoch_metrics=out.epoch_metrics,
                 control_delta=control_delta,
                 device_stats=device_stats,
+                task_tiles=task_tiles,
             )
-            self._report_completion(result)
+            report_sp = _ttrace.span(
+                "learner.report", attrs={"task_ms": round(task_ms, 3),
+                                         "bytes": len(model_bytes)})
+            with report_sp:
+                self._report_completion(result)
             _M_TASKS.inc(outcome="completed")
             task_sp.set_attr("outcome", "completed")
         except Exception:

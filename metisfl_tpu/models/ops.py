@@ -33,6 +33,7 @@ from metisfl_tpu.models.dataset import ArrayDataset
 from metisfl_tpu.models.optimizers import make_optimizer
 from metisfl_tpu.telemetry import profile as _tprofile
 from metisfl_tpu.telemetry import runtime as _runtime
+from metisfl_tpu.telemetry import trace as _ttrace
 
 Pytree = Any
 
@@ -48,6 +49,14 @@ class TrainOutput:
     ms_per_step: float
     train_metrics: Dict[str, float]
     epoch_metrics: List[Dict[str, float]] = field(default_factory=list)
+    # where ``train`` spent its own time, in milliseconds on the host's
+    # clock (the learner's task waterfall, telemetry/profile.py): batches
+    # drawn, stacked and placed; program call to the host sync; the
+    # ``get_variables()`` read-back. An engine that does not time itself
+    # leaves them 0 and the learner counts that time as ``other``.
+    feed_ms: float = 0.0
+    steps_ms: float = 0.0
+    readback_ms: float = 0.0
 
 
 def softmax_cross_entropy_loss(logits, y):
@@ -384,6 +393,12 @@ class FlaxModelOps:
         step_times: List[float] = []
         completed = 0
         rng = self._rng
+        # the task waterfall's inner tiles (TrainOutput.feed_ms/steps_ms):
+        # seconds summed over chunks and steps, and where the first of
+        # each began on this clock
+        wall0, perf0 = time.time(), time.perf_counter()
+        feed_s = steps_s = 0.0
+        feed_at = steps_at = None
 
         place = (self._shard_batch if self.mesh is not None
                  else lambda arr, batch_axis=0: jnp.asarray(arr))
@@ -420,6 +435,7 @@ class FlaxModelOps:
                     # a single-chunk run has no steady-state chunk to trace
                     # (the remainder loop below still traces when it runs)
                     chunk_profiling = (chunk_idx == 1 and tracer.start())
+                    t_feed = time.perf_counter()
                     xs, ys = [], []
                     for _ in range(chunk):
                         x, y = next(stream)
@@ -430,19 +446,23 @@ class FlaxModelOps:
                     step_ids = jnp.arange(completed, completed + chunk,
                                           dtype=jnp.uint32)
                     t0 = time.perf_counter()
+                    feed_s += t0 - t_feed
+                    if feed_at is None:
+                        feed_at, steps_at = t_feed, t0
                     params, batch_stats, opt_state, rng, c_losses, c_accs = (
                         scan_compiled(params, batch_stats, opt_state,
                                       global_params, grad_offset, rng,
                                       step_ids, xs, ys))
                     c_losses = np.asarray(c_losses)
                     c_accs = np.asarray(c_accs)   # host sync, once per chunk
+                    chunk_s = time.perf_counter() - t0
+                    steps_s += chunk_s
                     if chunk_idx > 0 and not chunk_profiling:
-                        step_times.extend(
-                            [(time.perf_counter() - t0) / chunk] * chunk)
+                        step_times.extend([chunk_s / chunk] * chunk)
                     elif n_chunks == 1 or chunk_profiling:
                         # compile- or profiler-contaminated; used only if no
                         # clean sample lands anywhere in the run
-                        fallback_time = (time.perf_counter() - t0) / chunk
+                        fallback_time = chunk_s / chunk
                     if chunk_profiling:
                         tracer.stop()
                     for loss, acc in zip(c_losses, c_accs):
@@ -466,9 +486,15 @@ class FlaxModelOps:
                     break
                 if completed == profile_from:
                     tracer.start()  # no-op when already captured or inert
+                t_feed = time.perf_counter()
                 x, y = next(stream)
                 rng = jax.random.fold_in(rng, completed)
+                # one batch is placed inside the timed call, as it always
+                # was on this path: here ``feed`` is the draw alone
                 t0 = time.perf_counter()
+                feed_s += t0 - t_feed
+                if feed_at is None:
+                    feed_at, steps_at = t_feed, t0
                 params, batch_stats, opt_state, loss, acc = compiled(
                     params, batch_stats, opt_state, global_params,
                     grad_offset, place(x), place(y), rng)
@@ -485,6 +511,7 @@ class FlaxModelOps:
                 completed += 1
                 epoch_losses.append((loss, acc))
                 _flush_epoch()
+                steps_s += time.perf_counter() - t0
 
             if tracer.active:
                 jax.block_until_ready(loss)
@@ -493,6 +520,9 @@ class FlaxModelOps:
             # task's capture and leak the profiler session
             tracer.stop()
 
+        # the per-step path's last steps are still in flight: reading
+        # their losses is the sync, and so part of the steps
+        t_sync = time.perf_counter()
         _flush_epoch(force=True)
 
         new_vars = {"params": params}
@@ -504,8 +534,20 @@ class FlaxModelOps:
         if not step_times and fallback_time is not None:
             step_times = [fallback_time]
         ms_per_step = float(np.median(step_times) * 1e3) if step_times else 0.0
+        t_read = time.perf_counter()
+        steps_s += t_read - t_sync
+        variables = self.get_variables()
+        readback_s = time.perf_counter() - t_read
+        if feed_at is not None:
+            _ttrace.event("train.feed", feed_s,
+                          start=wall0 + feed_at - perf0)
+            _ttrace.event("train.steps", steps_s,
+                          start=wall0 + steps_at - perf0,
+                          attrs={"steps": completed})
+        _ttrace.event("train.readback", readback_s,
+                      start=wall0 + t_read - perf0)
         return TrainOutput(
-            variables=self.get_variables(),
+            variables=variables,
             completed_steps=completed,
             completed_batches=completed,
             completed_epochs=completed / steps_per_epoch,
@@ -515,6 +557,9 @@ class FlaxModelOps:
                 "accuracy": float(np.mean(accs)) if accs else float("nan"),
             },
             epoch_metrics=epoch_metrics,
+            feed_ms=feed_s * 1e3,
+            steps_ms=steps_s * 1e3,
+            readback_ms=readback_s * 1e3,
         )
 
     # -- inference ---------------------------------------------------------

@@ -335,6 +335,7 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
         ],
         compiler_params=None if interpret else _SEQ_PARAMS,
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out[:, :L].reshape(B, H, L, D), lse
 
@@ -387,6 +388,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
         compiler_params=None if interpret else _SEQ_PARAMS,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     # dK/dV accumulate over (group member × q block), member-major
@@ -427,6 +429,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
         ],
         compiler_params=None if interpret else _SEQ_PARAMS,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, gf, lse, delta)
 
     return (dq[:, :L].reshape(B, H, L, D),

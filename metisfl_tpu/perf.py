@@ -172,10 +172,28 @@ def _fmt_bytes(n: float) -> str:
     return f"{int(n)}B"
 
 
+def _bars(tiles: Dict[str, float], order: Tuple[str, ...], wall: float,
+          width: int) -> List[str]:
+    """One bar line per tile of ``order`` that ``tiles`` holds: duration,
+    share of ``wall``, and a bar scaled to the longest tile."""
+    present = [(name, float(tiles[name])) for name in order
+               if name in tiles]
+    longest = max((ms for _, ms in present), default=0.0)
+    lines = []
+    for name, ms in present:
+        bar = "#" * (int(round(width * ms / longest)) if longest > 0 else 0)
+        share = (ms / wall * 100) if wall > 0 else 0.0
+        lines.append(f"  {name:<13} {_fmt_ms(ms):>9} {share:5.1f}%  {bar}")
+    return lines
+
+
 def render_waterfall(profiles: List[dict], width: int = 40,
                      want_round: Optional[int] = None) -> str:
-    """The phase waterfall (one bar block per round) plus the per-learner
-    attribution table for each profiled round."""
+    """The phase waterfall (one bar block per round), each learner's own
+    task waterfall under it, and the per-learner attribution table for
+    each profiled round."""
+    from metisfl_tpu.telemetry.profile import PHASES, TASK_TILES
+
     lines: List[str] = []
     for prof in profiles:
         round_no = prof.get("round", 0)
@@ -186,18 +204,18 @@ def render_waterfall(profiles: List[dict], width: int = 40,
             f"round {round_no}  wall {_fmt_ms(wall)}  coverage "
             f"{float(prof.get('coverage', 0.0)) * 100:.0f}%"
             + ("  [jax trace armed]" if prof.get("trace_armed") else ""))
-        phases = prof.get("phases") or {}
-        longest = max((float(v) for v in phases.values()), default=0.0)
-        for name in ("dispatch", "wait_uplinks", "select", "aggregate",
-                     "close"):
-            if name not in phases:
+        lines.extend(_bars(prof.get("phases") or {}, PHASES, wall, width))
+        learners = prof.get("learners") or {}
+        for lid in sorted(learners):
+            # the learner's own account of its train task, on its clock:
+            # tiles from the RPC's acceptance to the start of its report
+            task = learners[lid].get("task") or {}
+            if not task:
                 continue
-            ms = float(phases[name])
-            bar = "#" * (int(round(width * ms / longest))
-                         if longest > 0 else 0)
-            share = (ms / wall * 100) if wall > 0 else 0.0
-            lines.append(f"  {name:<13} {_fmt_ms(ms):>9} {share:5.1f}%  "
-                         f"{bar}")
+            task_ms = sum(float(task[t]) for t in TASK_TILES if t in task)
+            lines.append(f"  task {lid}  wall {_fmt_ms(task_ms)}")
+            lines.extend("  " + line
+                         for line in _bars(task, TASK_TILES, task_ms, width))
         store = prof.get("store") or {}
         if store:
             lines.append(
@@ -208,7 +226,6 @@ def render_waterfall(profiles: List[dict], width: int = 40,
         if serving:
             lines.append(f"  serving: queue_depth="
                          f"{serving.get('queue_depth', 0)}")
-        learners = prof.get("learners") or {}
         if learners:
             lines.append(f"  {'learner':<24} {'uplink':>9} {'downlink':>9} "
                          f"{'codec':>8} {'insert':>8} {'step_ms':>8} "

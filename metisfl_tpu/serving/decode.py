@@ -63,12 +63,21 @@ _M_DECODE_TPS = _REG.gauge(
 
 PAD_ID = 0
 
+# what the decode loop keeps of its own time, per tick: seconds inside
+# ``SlotDecoder.step`` (call to host tokens), seconds inside ``prefill``,
+# the rest of the tick on the host (admission under the lock, token
+# hand-out, retirement), seconds parked with no queue and no active slot
+# (in neither side of a host share), and counts.
+LOOP_SUMS = ("step_s", "prefill_s", "host_s", "parked_s", "ticks",
+             "steps", "prefills", "admitted", "retired")
+LOOP_EVENT_EVERY_S = 1.0
+
 
 class _GenPending:
     """One queued generation request + the future its caller blocks on."""
 
     __slots__ = ("prompt", "max_new", "eos_id", "future", "enqueued_at",
-                 "admitted_step", "trace_ctx")
+                 "admitted_step", "trace_ctx", "wait_ms", "prefill_ms")
 
     def __init__(self, prompt: np.ndarray, max_new: int,
                  eos_id: Optional[int]):
@@ -78,6 +87,8 @@ class _GenPending:
         self.future: "futures.Future" = futures.Future()
         self.enqueued_at = time.perf_counter()
         self.admitted_step = -1          # step index at admission (test pin)
+        self.wait_ms = 0.0               # enqueue -> the start of its prefill
+        self.prefill_ms = 0.0
         # the submitter's span context: the decode loop retires slots on
         # its own thread, where contextvars are empty — the causal link
         # (serving.generate → decode.slot) rides on the request record
@@ -136,12 +147,22 @@ class ContinuousBatcher:
         self._cv = threading.Condition(_prof.lock("serving.decode"))
         self._slots: List[Optional[_Slot]] = [None] * self.slots
         self._closed = False
-        self.steps = 0                   # decode-step counter (test pin)
         self.tokens_emitted = 0
         self._tps_ewma = 0.0
+        # the loop's own account of its time (``LOOP_SUMS``): running
+        # totals the worker alone writes, read whole by describe() and as
+        # differences by the once-a-second ``decode.loop`` event
+        self._sums = {k: 0.0 if k.endswith("_s") else 0 for k in LOOP_SUMS}
+        self._emitted = dict(self._sums)
+        self._emitted_at = time.perf_counter()
         self._worker = threading.Thread(target=self._loop, daemon=True,
                                         name=f"decode-{channel}")
         self._worker.start()
+
+    @property
+    def steps(self) -> int:
+        """Decode steps taken so far (test pin; one of the loop's sums)."""
+        return self._sums["steps"]
 
     # -- request side --------------------------------------------------- #
 
@@ -212,20 +233,30 @@ class ContinuousBatcher:
                 attrs={"channel": self.channel,
                        "admitted_step": req.admitted_step,
                        "retired_step": self.steps,
-                       "tokens": len(slot.tokens)})
+                       "tokens": len(slot.tokens),
+                       "wait_ms": round(req.wait_ms, 3),
+                       "prefill_ms": round(req.prefill_ms, 3)})
+        self._sums["retired"] += 1
         if not req.future.done():
             req.future.set_result((out, slot.version))
 
     def _loop(self) -> None:
+        sums = self._sums
         while True:
+            tick_at = time.perf_counter()
+            parked = 0.0
             with self._cv:
                 while (not self._queue
                        and all(s is None for s in self._slots)
                        and self._pending_pair is None
                        and not self._closed):
+                    t0 = time.perf_counter()
                     self._cv.wait(0.1)
+                    parked += time.perf_counter() - t0
                 if (self._closed and not self._queue
                         and all(s is None for s in self._slots)):
+                    if sums["ticks"] > self._emitted["ticks"]:
+                        self._emit_loop(time.perf_counter())
                     return
                 if (self._pending_pair is not None
                         and all(s is None for s in self._slots)):
@@ -234,7 +265,16 @@ class ContinuousBatcher:
                     self._pending_pair = None
                 admitted = self._admit_locked()
             try:
-                self._tick(admitted)
+                called_s = self._tick(admitted)
+                # a tick boundary: what of it was neither parked nor
+                # inside prefill or step is the host's
+                now = time.perf_counter()
+                sums["ticks"] += 1
+                sums["admitted"] += len(admitted)
+                sums["parked_s"] += parked
+                sums["host_s"] += now - tick_at - parked - called_s
+                if now - self._emitted_at >= LOOP_EVENT_EVERY_S:
+                    self._emit_loop(now)
             except Exception as exc:  # noqa: BLE001 - worker must survive
                 # one poisoned tick (bad prompt dtype, an OOM'd step)
                 # fails ITS requests only — a dead worker would hang
@@ -250,13 +290,35 @@ class ContinuousBatcher:
                                 slot.req.future.set_exception(exc)
                             self._slots[idx] = None
 
-    def _tick(self, admitted: List[_GenPending]) -> None:
+    def _emit_loop(self, now: float) -> None:
+        """The ``decode.loop`` summary event: the sums' change since the
+        last one, at most once a second, from the worker thread."""
+        attrs = {k: round(self._sums[k] - self._emitted[k], 6)
+                 for k in LOOP_SUMS}
+        attrs["channel"] = self.channel
+        _ttrace.event("decode.loop", now - self._emitted_at, parent=None,
+                      attrs=attrs)
+        self._emitted = dict(self._sums)
+        self._emitted_at = now
+
+    def _tick(self, admitted: List[_GenPending]) -> float:
+        """One iteration of the loop; returns the seconds it spent inside
+        the decoder's ``prefill`` and ``step`` calls."""
         version, variables = self._pair
+        sums = self._sums
+        called_s = 0.0
         # 1. prefill admissions between decode steps (step granularity:
         #    the running batch did NOT have to finish first)
         for req in admitted:
             idx = next(i for i, s in enumerate(self._slots) if s is None)
+            t0 = time.perf_counter()
             first = self._decoder.prefill(variables, idx, req.prompt)
+            prefill_s = time.perf_counter() - t0
+            req.wait_ms = (t0 - req.enqueued_at) * 1e3
+            req.prefill_ms = prefill_s * 1e3
+            sums["prefill_s"] += prefill_s
+            sums["prefills"] += 1
+            called_s += prefill_s
             req.admitted_step = self.steps
             slot = _Slot(req, first, int(req.prompt.size), version)
             self.tokens_emitted += 1
@@ -270,7 +332,7 @@ class ContinuousBatcher:
                   if s is not None]
         _M_DECODE_SLOTS.set(len(active), channel=self.channel)
         if not active:
-            return
+            return called_s
         # 2. one decode step for the whole in-flight batch (one program;
         #    free lanes carry zeros and are never read)
         t0 = time.perf_counter()
@@ -278,9 +340,13 @@ class ContinuousBatcher:
         poss = np.zeros((self.slots,), np.int32)
         for i, s in active:
             toks[i], poss[i] = s.last_tok, s.position
+        t_call = time.perf_counter()
         nxt = self._decoder.step(variables, toks, poss)
-        self.steps += 1
-        step_s = max(time.perf_counter() - t0, 1e-9)
+        now = time.perf_counter()
+        sums["step_s"] += now - t_call
+        sums["steps"] += 1
+        called_s += now - t_call
+        step_s = max(now - t0, 1e-9)
         self._tps_ewma = (0.8 * self._tps_ewma
                           + 0.2 * (len(active) / step_s))
         _M_DECODE_TPS.set(round(self._tps_ewma, 3), channel=self.channel)
@@ -295,6 +361,7 @@ class ContinuousBatcher:
                     or (s.req.eos_id is not None and tok == s.req.eos_id))
             if done:
                 self._retire(i, s)
+        return called_s
 
     # -- status --------------------------------------------------------- #
 
@@ -315,7 +382,10 @@ class ContinuousBatcher:
                     "tokens_emitted": self.tokens_emitted,
                     "tokens_per_sec": round(self._tps_ewma, 3),
                     "version": self._pair[0],
-                    "swap_pending": self._pending_pair is not None}
+                    "swap_pending": self._pending_pair is not None,
+                    # the loop's account of its own time, as totals
+                    "loop": {k: round(v, 6)
+                             for k, v in self._sums.items()}}
 
     def close(self) -> None:
         """Drain: queued + in-flight generations still finish, then the

@@ -107,7 +107,6 @@ M_LEARNER_HBM_PEAK_BYTES = "learner_hbm_peak_bytes"
 # learner runtime (learner/learner.py)
 M_LEARNER_TRAIN_DURATION_SECONDS = "learner_train_duration_seconds"
 M_LEARNER_STEP_MILLISECONDS = "learner_step_milliseconds"
-M_LEARNER_JIT_COMPILE_SECONDS = "learner_jit_compile_seconds"
 M_LEARNER_TASKS_TOTAL = "learner_tasks_total"
 M_LEARNER_EVAL_DURATION_SECONDS = "learner_eval_duration_seconds"
 M_LEARNER_REATTACH_TOTAL = "learner_reattach_total"

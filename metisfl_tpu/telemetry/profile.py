@@ -62,6 +62,12 @@ PHASES = ("dispatch", "wait_uplinks", "select", "aggregate", "close")
 # boundary marks, in waterfall order (each ends the named phase)
 _MARKS = ("dispatch_end", "wait_end", "select_end", "aggregate_end")
 
+# the learner's own waterfall of one train task, in its order on the
+# learner's clock (learner/learner.py cuts the tiles; they ride
+# ``TaskResult.task_tiles`` into ``RoundProfile.learners[lid]["task"]``)
+TASK_TILES = ("queued", "load", "upload", "feed", "steps", "readback",
+              "snapshot", "encode", "other")
+
 _REG = _tmetrics.registry()
 _M_DOWNLINK = _REG.counter(
     _tel.M_DOWNLINK_BYTES_TOTAL,
@@ -264,7 +270,11 @@ class RoundProfile:
     # ``phases`` so its coverage invariant holds
     extras: Dict[str, float] = field(default_factory=dict)
     # learner → {uplink_bytes, downlink_bytes, codec_encode_s,
-    #            codec_decode_s, insert_ms, device{...}}
+    #            codec_decode_s, insert_ms, device{...}, task{...}};
+    # ``task`` is the learner's own waterfall of this round's train task
+    # (TASK_TILES in ms, tiling its clock from the RPC's acceptance to
+    # the start of its report, plus ``start`` as time.time()); absent
+    # from a learner that ships none
     learners: Dict[str, Dict[str, Any]] = field(default_factory=dict)
     totals: Dict[str, float] = field(default_factory=dict)
     serving: Dict[str, Any] = field(default_factory=dict)
@@ -327,6 +337,9 @@ class ProfileCollector:
         # latest device stats per learner (persists across rounds — a
         # learner not sampled this round keeps its last observation)
         self._device: Dict[str, Dict[str, Any]] = {}
+        # this round's task waterfall per learner (reset by
+        # assemble_round: a tile belongs to the round that ran the task)
+        self._task: Dict[str, Dict[str, float]] = {}
         # cumulative codec-attribution snapshot at the last round close
         # (comm/codec.py keeps the process totals; per-round = delta)
         self._codec_snapshot: Dict[Any, float] = {}
@@ -382,6 +395,19 @@ class ProfileCollector:
             logger.warning("unusable device stats from %s: %r",
                            learner_id, stats)
 
+    def note_task(self, learner_id: str, tiles: Dict[str, float]) -> None:
+        """The learner-shipped task waterfall (``TaskResult.task_tiles``)
+        for the in-flight round; the newest report of a learner wins."""
+        try:
+            task = {str(k): float(v) for k, v in tiles.items()}
+        except (AttributeError, TypeError, ValueError):
+            # never validated on the wire, like the device stats
+            logger.warning("unusable task tiles from %s: %r",
+                           learner_id, tiles)
+            return
+        with self._lock:
+            self._task[learner_id] = task
+
     def note_store_select(self, ms: float) -> None:
         with self._lock:
             self._select_ms += float(ms)
@@ -419,6 +445,7 @@ class ProfileCollector:
             self._downlink.pop(learner_id, None)
             self._insert_ms.pop(learner_id, None)
             self._device.pop(learner_id, None)
+            self._task.pop(learner_id, None)
             # the codec process totals are pruned by
             # prune_attribution_series; without dropping the matching
             # snapshot keys too, a leave→rejoin between round closes
@@ -447,6 +474,7 @@ class ProfileCollector:
             select_ms, self._select_ms = self._select_ms, 0.0
             extra, self._phase_extra = self._phase_extra, {}
             marks, self._marks = self._marks, {}
+            task, self._task = self._task, {}
             device = {lid: dict(s) for lid, s in self._device.items()}
             codec_round = {
                 key: total - self._codec_snapshot.get(key, 0.0)
@@ -497,6 +525,8 @@ class ProfileCollector:
                 entry["codec_decode_s"] = round(dec, 6)
             if lid in device:
                 entry["device"] = device[lid]
+            if lid in task:
+                entry["task"] = task[lid]
             learners[lid] = entry
         profile = RoundProfile(
             round=int(getattr(meta, "global_iteration", 0)),

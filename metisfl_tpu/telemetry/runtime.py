@@ -382,10 +382,24 @@ def monitored_jit(fn: Callable, *, name: str = "", **jit_kwargs):
     calls (nothing compiling) pay one attribute check plus a
     thread-local set/restore; with the plane disabled, the check alone.
     """
+    import functools
+
     import jax
 
-    compiled = jax.jit(fn, **jit_kwargs)
     label = name or getattr(fn, "__name__", "jit_fn")
+    if name:
+        # the label is also the program's name on the device: XLA calls
+        # the module ``jit_<function name>``, so ``decode.step`` reads
+        # ``jit_decode_step`` in a profiler trace, whatever the closure
+        # behind it is called (``wraps`` keeps the signature jit reads)
+        inner = fn
+
+        @functools.wraps(inner)
+        def fn(*args, **kwargs):
+            return inner(*args, **kwargs)
+
+        fn.__name__ = fn.__qualname__ = name.replace(".", "_")
+    compiled = jax.jit(fn, **jit_kwargs)
     # lazy arming: a process that jits through us observes its own
     # compiles even before any collector pull (no-op when opted out)
     ensure_started()

@@ -460,9 +460,12 @@ def span(name: str, parent: Any = _USE_CURRENT,
 
 
 def event(name: str, duration_s: float, parent: Any = _USE_CURRENT,
-          attrs: Optional[Dict[str, Any]] = None) -> None:
+          attrs: Optional[Dict[str, Any]] = None,
+          start: Optional[float] = None) -> None:
     """Record an already-measured interval as a completed span (for call
-    sites that timed themselves, e.g. the codec hot path)."""
+    sites that timed themselves, e.g. the codec hot path). ``start`` is
+    the interval's wall-clock start (``time.time()``) where it did not
+    end just now: a sum of several pieces stamps where the first began."""
     if not _TRACER.enabled:
         return
     if parent is _USE_CURRENT:
@@ -470,7 +473,7 @@ def event(name: str, duration_s: float, parent: Any = _USE_CURRENT,
     elif isinstance(parent, (Span, _NullSpan)):
         parent = parent.context()
     sp = Span(_TRACER, name, parent, attrs)
-    sp.start = time.time() - duration_s
+    sp.start = time.time() - duration_s if start is None else start
     sp._duration_ms = duration_s * 1e3
     _TRACER._record(sp)
 

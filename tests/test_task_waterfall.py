@@ -1,0 +1,383 @@
+"""The learner's task and the decode loop account for their own time: the
+tiled task waterfall under ``RoundProfile.learners[lid]["task"]``, the
+``decode.slot`` / ``decode.loop`` accounting, programs named by their
+compile label, and the benchmark's readers of all three on hand-made
+contexts (tier-1 never runs ``benchmark/tests``)."""
+
+import json
+import time
+
+import numpy as np
+import pytest
+
+from metisfl_tpu.comm.messages import JoinRequest, TaskResult, TrainParams
+from metisfl_tpu.config import (
+    AggregationConfig,
+    EvalConfig,
+    FederationConfig,
+    TerminationConfig,
+)
+from metisfl_tpu.telemetry import profile as tprofile
+from metisfl_tpu.telemetry import trace as ttrace
+
+
+@pytest.fixture()
+def span_ring():
+    """Finished spans in memory (the ring is off outside the fabric)."""
+    ttrace.configure(enabled=True, service="test", dir="")
+    ttrace.configure_ring(8192)
+    _, cursor, _ = ttrace.spans_since(0)
+    yield lambda: ttrace.spans_since(cursor)[0]
+    ttrace.configure_ring(0)
+
+
+# --------------------------------------------------------------------- #
+# the learner's task waterfall
+# --------------------------------------------------------------------- #
+
+def test_task_tiles_sum_to_the_task_and_land_in_the_round_profile(
+        span_ring):
+    from metisfl_tpu.driver import InProcessFederation
+    from metisfl_tpu.models import ArrayDataset, FlaxModelOps
+    from metisfl_tpu.models.zoo import MLP
+
+    rng = np.random.default_rng(3)
+    w = rng.standard_normal((6, 3)).astype(np.float32)
+    config = FederationConfig(
+        protocol="synchronous",
+        aggregation=AggregationConfig(rule="fedavg", scaler="participants"),
+        train=TrainParams(batch_size=8, local_steps=4, scan_chunk=2,
+                          learning_rate=0.05),
+        eval=EvalConfig(every_n_rounds=0),
+        termination=TerminationConfig(federation_rounds=2))
+    fed = InProcessFederation(config)
+    template = None
+    for i in range(2):
+        x = rng.standard_normal((48, 6)).astype(np.float32)
+        y = np.argmax(x @ w, axis=-1).astype(np.int32)
+        engine = FlaxModelOps(MLP(features=(8,), num_outputs=3), x[:2])
+        if template is None:
+            template = engine.get_variables()
+        else:
+            engine.set_variables(template)
+        fed.add_learner(engine, ArrayDataset(x, y, seed=i))
+    fed.seed_model(template)
+    try:
+        fed.start()
+        assert fed.wait_for_rounds(2, timeout_s=120)
+        metas = fed.statistics()["round_metadata"][:2]
+    finally:
+        fed.shutdown()
+    spans = span_ring()
+    trains = {(s["attrs"]["round"], s["attrs"]["learner"]): s
+              for s in spans if s["name"] == "learner.train"}
+    by_parent = {}
+    for s in spans:
+        by_parent.setdefault(s["parent"], []).append(s)
+    seen = 0
+    for meta in metas:
+        learners = meta["profile"]["learners"]
+        assert len(learners) == 2
+        for lid, entry in learners.items():
+            task = entry["task"]
+            assert set(task) == set(tprofile.TASK_TILES) | {"start"}
+            tiles = {k: task[k] for k in tprofile.TASK_TILES}
+            assert all(v >= 0.0 for k, v in tiles.items()
+                       if k != "other"), tiles
+            assert tiles["steps"] > 0 and tiles["readback"] > 0
+            train = trains.get((meta["global_iteration"], lid))
+            if train is None:
+                continue        # the profile's round numbering moved on
+            seen += 1
+            children = {s["name"]: s for s in by_parent[train["span"]]}
+            report = children["learner.report"]
+            # by construction: the tiles are the task's wall time from the
+            # RPC's acceptance to the start of the report
+            assert sum(tiles.values()) == pytest.approx(
+                report["attrs"]["task_ms"], abs=1.0)
+            assert tiles["queued"] == pytest.approx(
+                train["attrs"]["queued_ms"], abs=0.01)
+            assert task["start"] <= train["start"]
+            # and on the spans' own clock (another thread may run between
+            # the two stamps: a looser bound)
+            assert sum(tiles.values()) == pytest.approx(
+                tiles["queued"]
+                + (report["start"] - train["start"]) * 1e3, abs=50.0)
+            # each tile is its span
+            for tile, name in (("load", "learner.load_model"),
+                               ("upload", "learner.upload"),
+                               ("snapshot", "learner.snapshot"),
+                               ("encode", "learner.dump_model")):
+                assert tiles[tile] == pytest.approx(
+                    children[name]["dur_ms"], abs=0.01), tile
+            inner = {s["name"]: s for s in by_parent[
+                children["learner.train_steps"]["span"]]}
+            for tile in ("feed", "steps", "readback"):
+                assert tiles[tile] == pytest.approx(
+                    inner["train." + tile]["dur_ms"], abs=0.01), tile
+            assert inner["train.steps"]["attrs"]["steps"] == 4
+            assert "jit_compile_s_est" not in children[
+                "learner.train_steps"]["attrs"]
+    assert seen >= 2
+
+
+class _SilentProxy:
+    def __init__(self, record):
+        self.learner_id = record.learner_id
+
+    def run_task(self, task):
+        pass
+
+    def evaluate(self, task, callback):
+        pass
+
+    def shutdown(self):
+        pass
+
+
+def test_result_without_tiles_decodes_and_yields_no_task_key():
+    from metisfl_tpu.comm.codec import dumps
+    from metisfl_tpu.config import ProfileConfig, TelemetryConfig
+    from metisfl_tpu.controller.core import Controller
+    from metisfl_tpu.tensor.pytree import pack_model
+
+    model = {"w": np.ones((3,), np.float32)}
+    # what an older learner puts on the wire: no ``task_tiles`` at all
+    old = TaskResult(task_id="t", learner_id="L").to_dict()
+    del old["task_tiles"]
+    assert TaskResult.from_wire(dumps(old)).task_tiles == {}
+
+    config = FederationConfig(
+        protocol="synchronous",
+        aggregation=AggregationConfig(rule="fedavg", scaler="participants"),
+        train=TrainParams(batch_size=4, local_steps=1),
+        eval=EvalConfig(every_n_rounds=0),
+        telemetry=TelemetryConfig(profile=ProfileConfig(enabled=True)))
+    ctrl = Controller(config, proxy_factory=_SilentProxy)
+    try:
+        ctrl.set_community_model(pack_model(model))
+        for i in range(2):
+            ctrl.join(JoinRequest(hostname="h", port=7700 + i,
+                                  num_train_examples=10))
+        lids = sorted(ctrl.active_learners())
+        with ctrl._lock:
+            tokens = {lid: ctrl._learners[lid].auth_token for lid in lids}
+        shipped = {"start": 12.5, "queued": 0.1, "steps": 7.0, "other": 0.4}
+        for lid, tiles in zip(lids, ({}, shipped)):
+            wire = TaskResult(
+                task_id=f"t_{lid}", learner_id=lid, auth_token=tokens[lid],
+                model=pack_model(model), round_id=0, completed_batches=1,
+                train_metrics={"loss": 0.5}, task_tiles=tiles).to_dict()
+            if not tiles:
+                del wire["task_tiles"]
+            assert ctrl.task_completed(TaskResult.from_wire(dumps(wire)))
+        deadline = time.time() + 30.0
+        while ctrl.global_iteration < 1 and time.time() < deadline:
+            time.sleep(0.02)
+        profile = ctrl.get_statistics()["round_metadata"][0]["profile"]
+    finally:
+        ctrl.shutdown()
+    assert "task" not in profile["learners"][lids[0]]
+    assert profile["learners"][lids[1]]["task"] == shipped
+
+
+def test_perf_round_view_prints_each_learners_task_waterfall():
+    from metisfl_tpu import perf
+
+    prof = {"round": 3, "wall_ms": 100.0, "coverage": 1.0,
+            "phases": {"dispatch": 10.0, "wait_uplinks": 90.0},
+            "learners": {
+                "L0": {"uplink_bytes": 1, "downlink_bytes": 1,
+                       "task": {"start": 1.0, "queued": 1.0, "load": 4.0,
+                                "steps": 60.0, "readback": 15.0}},
+                "L1": {"uplink_bytes": 1, "downlink_bytes": 1}}}
+    screen = perf.render_waterfall([prof]).splitlines()
+    at = next(i for i, line in enumerate(screen)
+              if line.startswith("  task L0"))
+    assert "wall 80.0ms" in screen[at]
+    names = [line.split()[0] for line in screen[at + 1: at + 5]]
+    assert names == ["queued", "load", "steps", "readback"]
+    steps = screen[at + 3]
+    assert "60.0ms" in steps and "75.0%" in steps and "#" * 40 in steps
+    assert not any(line.startswith("  task L1") for line in screen)
+
+
+# --------------------------------------------------------------------- #
+# the decode loop
+# --------------------------------------------------------------------- #
+
+def _lm_ops():
+    import jax.numpy as jnp
+
+    from metisfl_tpu.models import FlaxModelOps
+    from metisfl_tpu.models.zoo import LlamaLite
+
+    module = LlamaLite(vocab_size=32, dim=16, depth=1, heads=2,
+                       dtype=jnp.float32)
+    return FlaxModelOps(module, np.zeros((1, 4), np.int32))
+
+
+def test_decode_slot_and_loop_account_for_their_time(span_ring,
+                                                     monkeypatch):
+    from metisfl_tpu.serving import decode
+
+    monkeypatch.setattr(decode, "LOOP_EVENT_EVERY_S", 0.01)
+    ops = _lm_ops()
+    engine = decode.ContinuousBatcher(ops, 1, ops.get_variables(),
+                                      slots=2, max_len=32)
+    try:
+        before = engine.describe()
+        assert set(before) == {
+            "slots", "max_len", "queued", "active", "steps",
+            "tokens_emitted", "tokens_per_sec", "version", "swap_pending",
+            "loop"}
+        assert set(before["loop"]) == set(decode.LOOP_SUMS)
+        # a root span stands in for the gateway's serving.generate: the
+        # slot event parents on the submitter's context
+        with ttrace.span("serving.generate") as sp, sp.activate():
+            futures = [engine.submit(np.arange(1, 4 + i, dtype=np.int32),
+                                     6 + i) for i in range(4)]
+        for fut in futures:
+            fut.result(timeout=120.0)
+    finally:
+        engine.close()      # the worker's last partial event is flushed
+    after = engine.describe()
+    spans = span_ring()
+    slots = [s for s in spans if s["name"] == "decode.slot"]
+    assert len(slots) == 4
+    for s in slots:
+        attrs = s["attrs"]
+        assert attrs["wait_ms"] >= 0.0 and attrs["prefill_ms"] > 0.0
+        assert attrs["wait_ms"] + attrs["prefill_ms"] <= s["dur_ms"] + 0.01
+    # four requests on two slots: two of them waited for a retirement
+    assert sum(1 for s in slots
+               if s["attrs"]["wait_ms"] > s["attrs"]["prefill_ms"]) >= 2
+    loops = [s["attrs"] for s in spans if s["name"] == "decode.loop"]
+    assert loops
+    total = {k: sum(a[k] for a in loops) for k in decode.LOOP_SUMS}
+    assert total["steps"] == after["steps"] - before["steps"] > 0
+    assert total["prefills"] == total["admitted"] == total["retired"] == 4
+    for key in decode.LOOP_SUMS:
+        assert total[key] == pytest.approx(
+            after["loop"][key] - before["loop"][key], abs=1e-4), key
+    assert total["step_s"] > 0 and total["prefill_s"] > 0
+    assert total["host_s"] >= 0 and total["parked_s"] >= 0
+
+
+# --------------------------------------------------------------------- #
+# programs named by layer
+# --------------------------------------------------------------------- #
+
+def test_monitored_jit_names_the_program_by_its_label():
+    import jax.numpy as jnp
+
+    from metisfl_tpu.telemetry import runtime
+
+    def run(state, x, *, scale=1.0):
+        return state + x * scale
+
+    fn = runtime.monitored_jit(run, name="a.b", donate_argnums=(0,),
+                               static_argnames=("scale",))
+    lowered = fn.__wrapped__.lower(jnp.ones(3), jnp.ones(3), scale=2.0)
+    assert "module @jit_a_b " in lowered.as_text()
+    np.testing.assert_allclose(fn(jnp.ones(3), jnp.ones(3), scale=2.0), 3.0)
+    assert run.__name__ == "run"        # the caller's function is not renamed
+    # without a label the function's own name stands
+    plain = runtime.monitored_jit(run)
+    assert "module @jit_run " in plain.__wrapped__.lower(
+        jnp.ones(3), jnp.ones(3)).as_text()
+
+
+# --------------------------------------------------------------------- #
+# the benchmark's readers, on hand-made contexts
+# --------------------------------------------------------------------- #
+
+def _round(wait_ms, task):
+    entry = {"device": {"ms_per_step": 10.0}}
+    if task is not None:
+        entry["task"] = task
+    return {"profile": {"phases": {"wait_uplinks": wait_ms},
+                        "learners": {"L0": entry}}}
+
+
+def _task(scale):
+    return {"start": 1.0, "queued": 1.0, "load": 10.0 * scale,
+            "upload": 20.0 * scale, "feed": 2.0 * scale,
+            "steps": 80.0, "readback": 30.0 * scale, "snapshot": 0.0,
+            "encode": 4.0 * scale, "other": 1.0}
+
+
+def _round_ctx():
+    return {"learner": "L0",
+            "traffic": {"shape": {"local_steps": 8}},
+            "rounds": [_round(200.0, _task(1.0)), _round(280.0, _task(2.0)),
+                       _round(999.0, None)]}
+
+
+def _serve_ctx(tmp_path):
+    records = [
+        {"name": "decode.slot", "start": 100.0, "dur_ms": 2000.0,
+         "attrs": {"wait_ms": 900.0, "prefill_ms": 30.0}},
+        {"name": "decode.slot", "start": 101.0, "dur_ms": 2000.0,
+         "attrs": {"wait_ms": 1100.0, "prefill_ms": 50.0}},
+        # ended after the window, and one from before this PR's program
+        {"name": "decode.slot", "start": 140.0, "dur_ms": 20000.0,
+         "attrs": {"wait_ms": 5.0, "prefill_ms": 5.0}},
+        {"name": "decode.slot", "start": 102.0, "dur_ms": 1000.0,
+         "attrs": {"tokens": 3}},
+        {"name": "decode.loop", "start": 100.0, "dur_ms": 1000.0,
+         "attrs": {"step_s": 0.6, "prefill_s": 0.1, "host_s": 0.3,
+                   "parked_s": 5.0, "steps": 30}},
+        {"name": "decode.loop", "start": 101.0, "dur_ms": 1000.0,
+         "attrs": {"step_s": 0.7, "prefill_s": 0.2, "host_s": 0.1,
+                   "parked_s": 0.0, "steps": 32}},
+        {"name": "decode.loop", "start": 10.0, "dur_ms": 1000.0,
+         "attrs": {"step_s": 0.0, "prefill_s": 0.0, "host_s": 9.0}},
+    ]
+    sink = tmp_path / "serving-1.jsonl"
+    sink.write_text("torn line\n" + "".join(json.dumps(r) + "\n"
+                                            for r in records))
+    return {"telemetry_dir": str(tmp_path), "window": (99.0, 150.0)}
+
+
+READERS = [
+    ("load_ms", "round", 15.0),
+    ("upload_ms", "round", 30.0),
+    ("feed_ms", "round", 3.0),
+    ("readback_ms", "round", 45.0),
+    ("encode_ms", "round", 6.0),
+    # wait_uplinks less the tiles' sum: 200 - 148 and 280 - 214
+    ("report_ms", "round", 59.0),
+    ("admit_wait_ms", "serve", 1000.0),
+    ("prefill_ms", "serve", 40.0),
+    ("decode_host_share", "serve", 20.0),
+]
+
+
+@pytest.mark.parametrize("name,kind,value", READERS,
+                         ids=[r[0] for r in READERS])
+def test_benchmark_reader_reads_a_value_and_nothing_from_nothing(
+        name, kind, value, tmp_path):
+    from benchmark.lib import spec
+
+    reader = spec.metric_reader(name)
+    ctx = _round_ctx() if kind == "round" else _serve_ctx(tmp_path)
+    assert reader.read(ctx) == pytest.approx(value)
+    # a program without the waterfall or the events (the parent commit)
+    empty = tmp_path / "empty"
+    empty.mkdir()
+    for nothing in ({}, {"learner": "L0", "rounds": [_round(5.0, None)],
+                         "telemetry_dir": str(empty),
+                         "window": (0.0, 1e12)}):
+        assert reader.read(nothing) is None
+
+
+def test_every_new_reader_is_declared_in_the_benchmark():
+    from benchmark.lib import spec
+
+    declared = {m["name"]: m for m in spec.benchmark()["per_layer"]}
+    for name, kind, _ in READERS:
+        entry = declared[name]
+        assert entry["better"] == "lower"
+        cells = entry["workloads"]
+        assert all(("round" in c) == (kind == "round") for c in cells), name
