@@ -165,7 +165,9 @@ class TrainParams(Message):
     # ship_tensor_regex="lora_" with FlaxModelOps(trainable_regex="lora_")
     # turns an 8B-param federation into an adapter-sized one, MBs instead
     # of GBs both directions). Non-matching tensors are effectively
-    # frozen by the transport regardless of the optimizer mask.
+    # frozen by the transport regardless of the optimizer mask; one the
+    # engine's mask freezes too is placed on the learner's device once
+    # and kept there from task to task (Learner._resident_names).
     # Composes with secure aggregation (the subset is identical across
     # parties, so the uniform-shape masking/HE payload contract holds —
     # and encrypting adapters instead of the full model is what makes
@@ -274,9 +276,10 @@ class TaskResult(Message):
     # The task's waterfall on the learner's clock (learner/learner.py):
     # milliseconds per tile (telemetry/profile.py TASK_TILES), contiguous
     # from the RunTask RPC's acceptance to the start of this report, and
-    # ``start``, that acceptance as ``time.time()``. Lands in
-    # ``RoundProfile.learners[lid]["task"]``. Empty from a learner that
-    # predates it.
+    # ``start``, that acceptance as ``time.time()``; beside them what
+    # crossed host <-> device (profile.py TASK_BYTES). Lands in
+    # ``RoundProfile.learners[lid]["task"]`` and ``["task_bytes"]``.
+    # Empty from a learner that predates it.
     task_tiles: Dict[str, float] = field(default_factory=dict)
 
 

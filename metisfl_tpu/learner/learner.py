@@ -16,12 +16,21 @@ cancel-on-new-task, run evaluations, ship results back. Redesigned:
 - Secure aggregation: when an HE backend is configured the learner encrypts
   outgoing weights and decrypts incoming community models (the controller
   never sees plaintext), mirroring model_ops.py:24-60 / ckks hookpoints.
+- Weights move by value, but no farther than they must: under
+  ``ship_tensor_regex`` a leaf that is unshipped AND frozen by the engine's
+  own mask is placed on the device once and stays there; every later train
+  task decodes, places, reads back and encodes the other leaves alone
+  (``Learner._resident_names``, ``_vouched``). A full-model federation has
+  no such leaf and moves the whole tree, as does any task the learner
+  cannot vouch for. Evaluations and inferences keep their explicit whole
+  trees (a concurrent train donates the engine's slot).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import logging
+import re
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -48,6 +57,7 @@ from metisfl_tpu.telemetry import trace as _ttrace
 from metisfl_tpu.tensor.spec import resolve_ship_dtype
 from metisfl_tpu.tensor.pytree import (
     ModelBlob,
+    NamedTensors,
     named_tensors_to_pytree,
     pytree_to_named_tensors,
 )
@@ -130,8 +140,10 @@ class Learner:
         self._task_lock = threading.Lock()
         self._current_future = None
         self._shutdown = threading.Event()
-        # reference treedef for wire ↔ pytree (captured at construction)
+        # reference treedef for wire ↔ pytree (captured at construction),
+        # and the same host arrays by wire name, in tree order
         self._treedef_like = model_ops.get_variables()
+        self._template = pytree_to_named_tensors(self._treedef_like)
         # SCAFFOLD client control variate c_i (params-shaped, f32; zeros
         # until the first scaffold task). In-memory only: a restarted
         # learner restarts its variate at zero, which SCAFFOLD tolerates.
@@ -165,6 +177,18 @@ class Learner:
         # every learner holds the identical frozen base.
         self._ship_regex: str = ""
         self._warned_unfrozen = False
+        # The frozen base stays on the device. A leaf the round can
+        # neither change nor ship (_resident_names) holds, once a task
+        # has placed it, the construction-time value the backfill would
+        # place again: the next task places, reads back, snapshots and
+        # encodes the other leaves alone. This is what the last train
+        # that returned vouches for — (ship regex, local regex, those
+        # leaves' names, the engine's variables_epoch) — and None before
+        # the first task and after a train that raised (its inputs were
+        # donated). A task whose key differs (a regex or the freeze mask
+        # changed, the tree was assigned from outside) places the whole
+        # tree from the construction-time values and vouches anew.
+        self._vouched: Optional[tuple] = None
         # device-utilization capture (telemetry/profile.py DeviceMonitor):
         # lazily constructed on the first train task whose params carry
         # device_stats=true — the opted-out hot path is one attribute
@@ -316,18 +340,21 @@ class Learner:
     # model wire I/O (+ optional HE)
     # ------------------------------------------------------------------ #
 
-    def _load_model(self, blob_bytes: bytes, with_wire: bool = False):
+    def _load_model(self, blob_bytes: bytes, with_wire: bool = False,
+                    resident: frozenset = frozenset()):
         """Decode (and decrypt) a model blob → variables pytree, restored
         to the engine's own training dtypes (a community model may arrive
         in a narrower wire dtype — TrainParams.ship_dtype). With
+        ``resident`` (leaves the engine kept on the device,
+        ``_resident_names``) the result is the OTHER leaves alone, as
+        named tensors in tree order: nothing is backfilled for a leaf
+        that will not be placed. With
         ``with_wire`` also returns the exact wire-dtype tensors by name:
         the top-k sparsifier must difference against what the controller
         densifies against (its exact f32 community model), not the
         engine-dtype cast — with bf16 training dtypes the cast would bake
         the base weights' rounding into every shipped coordinate as a
         systematic error the error-feedback residual never sees."""
-        import jax
-
         blob = ModelBlob.from_bytes(blob_bytes)
         if blob.opaque:
             if self.secure_backend is None:
@@ -340,15 +367,28 @@ class Learner:
                               .reshape(spec.shape)))
         else:
             named = blob.tensors
-        named = self._merge_local(named)
-        named = self._merge_frozen(named)
-        tree = named_tensors_to_pytree(named, self._treedef_like)
-        tree = jax.tree.map(
-            lambda a, t: a if a.dtype == t.dtype else np.asarray(a, t.dtype),
-            tree, self._treedef_like)
+        named = self._merge_frozen(self._merge_local(named), resident)
+
+        def restore(a, t):
+            return a if a.dtype == t.dtype else np.asarray(a, t.dtype)
+
+        if resident:
+            by_name = dict(named)
+            want = [(n, t) for n, t in self._template if n not in resident]
+            missing = [n for n, _ in want if n not in by_name]
+            if missing or len(by_name) != len(named):
+                raise KeyError("model blob is missing tensors "
+                               f"{missing[:5]} or names one twice")
+            loaded = [(n, restore(by_name[n], t)) for n, t in want]
+        else:
+            import jax
+
+            loaded = jax.tree.map(
+                restore, named_tensors_to_pytree(named, self._treedef_like),
+                self._treedef_like)
         if with_wire:
-            return tree, {n: np.asarray(a) for n, a in named}
-        return tree
+            return loaded, {n: np.asarray(a) for n, a in named}
+        return loaded
 
     def _merge_local(self, named):
         """FedBN merge (Li et al., ICLR 2021): tensors the federation
@@ -390,11 +430,9 @@ class Learner:
             if fut is None or fut.done():
                 self._snapshot_local()
                 return
-        import re
-
         values = {
             name: np.array(arr)
-            for name, arr in pytree_to_named_tensors(self._treedef_like)
+            for name, arr in self._template
             if re.search(self._local_regex, name)
         }
         with self._task_lock:
@@ -415,39 +453,62 @@ class Learner:
             self._local_values = {}
             self._snapshot_regex = ""
             return
-        import re
-
         self._local_values = {
             name: np.array(arr)
-            for name, arr in pytree_to_named_tensors(
-                self.model_ops.get_variables())
-            if re.search(self._local_regex, name)
+            for name, arr in self._engine_leaves(
+                {n for n, _ in self._template
+                 if re.search(self._local_regex, n)})
         }
         self._snapshot_regex = self._local_regex
 
-    def _merge_frozen(self, named):
+    def _engine_leaves(self, names) -> NamedTensors:
+        """Host copies of the engine's leaves of those names; an engine
+        that reads named leaves (FlaxModelOps) reads back no others."""
+        if hasattr(self.model_ops, "place_variables"):
+            return self.model_ops.get_variables(names)
+        return [(n, a) for n, a in pytree_to_named_tensors(
+            self.model_ops.get_variables()) if n in names]
+
+    def _merge_frozen(self, named, resident: frozenset = frozenset()):
         """Ship-only-trainable backfill: community blobs carry only the
         federated subset; fill non-matching names from the
-        construction-time initial values. Strictly gated on the ship
-        regex — and only NON-matching names backfill, so a corrupt blob
-        missing a federated tensor still fails loudly downstream."""
+        construction-time initial values (but for ``resident`` ones,
+        which hold those values on the device already). Strictly gated on
+        the ship regex — and only NON-matching names backfill, so a
+        corrupt blob missing a federated tensor still fails loudly
+        downstream."""
         if not self._ship_regex:
             return named
-        import re
-
-        have = {n for n, _ in named}
+        have = {n for n, _ in named} | resident
         out = list(named)
-        for name, arr in pytree_to_named_tensors(self._treedef_like):
+        for name, arr in self._template:
             if name not in have and not re.search(self._ship_regex, name):
                 out.append((name, arr))
         return out
+
+    def _resident_names(self) -> frozenset:
+        """The leaves a round can neither change nor ship, so that their
+        value on the device stays the construction-time one: not matched
+        by the ship regex, under the engine's own freeze mask (in no
+        collection the step mutates: ``frozen_names``), and not local.
+        Empty with no ship regex (a full-model federation: the whole-tree
+        path) and with an engine that cannot place named leaves
+        (multi-host ``LeaderOps`` broadcasts the whole blob). An unshipped
+        leaf that is NOT frozen is never resident: it is reset on every
+        receipt, as ever."""
+        if not self._ship_regex or not hasattr(self.model_ops,
+                                               "place_variables"):
+            return frozenset()
+        return frozenset(
+            n for n in self.model_ops.frozen_names()
+            if not re.search(self._ship_regex, n)
+            and not (self._local_regex
+                     and re.search(self._local_regex, n)))
 
     def _keep_ship(self, named):
         """Uplink filter: only ship_tensor_regex matches federate."""
         if not self._ship_regex:
             return named
-        import re
-
         kept = [(n, a) for n, a in named
                 if re.search(self._ship_regex, n)]
         if not kept:
@@ -460,8 +521,6 @@ class Learner:
         """Uplink filter: local tensors never ship."""
         if not self._local_regex:
             return named
-        import re
-
         kept = [(n, a) for n, a in named
                 if not re.search(self._local_regex, n)]
         if not kept:
@@ -470,12 +529,19 @@ class Learner:
                 "tensor — nothing would ever be aggregated")
         return kept
 
-    def _dump_model(self, ship_dtype: str = "",
-                    variables=None) -> bytes:
+    def _shipped(self, variables=None) -> NamedTensors:
+        """What federates of ``variables``: a whole tree, or the named
+        leaves an engine read back (``train(..., read=names)``); by
+        default the engine's whole tree."""
         if variables is None:
             variables = self.model_ops.get_variables()
-        named = self._keep_ship(
-            self._drop_local(pytree_to_named_tensors(variables)))
+        if not isinstance(variables, list):
+            variables = pytree_to_named_tensors(variables)
+        return self._keep_ship(self._drop_local(variables))
+
+    def _dump_model(self, ship_dtype: str = "",
+                    variables=None) -> bytes:
+        named = self._shipped(variables)
         if self.secure_backend is not None:
             from metisfl_tpu.tensor.spec import TensorSpec, wire_dtype_of, TensorKind
             t0 = time.perf_counter()
@@ -501,7 +567,7 @@ class Learner:
                 named = narrow_named(named, resolve_ship_dtype(ship_dtype))
         return ModelBlob(tensors=named).to_bytes()
 
-    def _dump_sparse(self, wire_ref, ship_vars, denom: int) -> bytes:
+    def _dump_sparse(self, wire_ref, variables, denom: int) -> bytes:
         """Top-k sparsified update vs the round's dispatched model, with
         error-feedback residuals carried across rounds (tensor/sparse.py);
         ~denom/2x less uplink than the dense f32 blob. ``wire_ref`` is the
@@ -510,12 +576,9 @@ class Learner:
         difference must be taken against the same bytes."""
         from metisfl_tpu.tensor.sparse import sparsify_update
 
-        variables = (ship_vars if ship_vars is not None
-                     else self.model_ops.get_variables())
-        named = self._keep_ship(
-            self._drop_local(pytree_to_named_tensors(variables)))
         return ModelBlob(tensors=sparsify_update(
-            named, wire_ref, denom, self._ef_residual)).to_bytes()
+            self._shipped(variables), wire_ref, denom,
+            self._ef_residual)).to_bytes()
 
     # ------------------------------------------------------------------ #
     # task execution
@@ -580,13 +643,12 @@ class Learner:
                 # round stalls to its deadline): a regex that localizes
                 # every tensor means nothing would ever aggregate.
                 # _drop_local raises on exactly that condition.
-                self._drop_local(
-                    pytree_to_named_tensors(self._treedef_like))
+                self._drop_local(self._template)
             self._ship_regex = params.ship_tensor_regex
             if self._ship_regex:
                 # same fail-fast: a subset regex matching nothing means
                 # nothing would ever aggregate
-                self._keep_ship(pytree_to_named_tensors(self._treedef_like))
+                self._keep_ship(self._template)
                 # probe through wrappers (multi-host LeaderOps exposes the
                 # real engine as .inner) so a correctly-frozen multi-host
                 # federation is not nagged about a nonexistent problem
@@ -624,22 +686,44 @@ class Learner:
                         self.learner_id or f"port_{self.port}"))
             topk_denom = (parse_topk(params.ship_dtype)
                           if params.ship_dtype else None)
+            # the frozen base the engine keeps on the device: ``kept`` is
+            # what of it the last train vouches for (see _vouched), and so
+            # is not decoded, backfilled or placed by this task. SCAFFOLD's
+            # variate and client-level DP want the whole tree on the host,
+            # before and after: those tasks place and read all of it.
+            resident = self._resident_names()
+            key = (self._ship_regex, self._local_regex, resident)
+            whole = bool(task.scaffold or task.control
+                         or params.dp_clip_norm > 0.0)
+            kept = frozenset()
+            if resident and not whole and self._vouched == key + (
+                    self.model_ops.variables_epoch,):
+                kept = resident
+            self._vouched = None
             wire_ref = None
             load_sp = _ttrace.span("learner.load_model",
                                    attrs={"bytes": len(task.model)})
             with load_sp:
                 if topk_denom is not None and self.secure_backend is None:
-                    incoming, wire_ref = self._load_model(task.model,
-                                                          with_wire=True)
+                    incoming, wire_ref = self._load_model(
+                        task.model, with_wire=True, resident=kept)
                 else:
-                    incoming = self._load_model(task.model)
-            # the whole tree host -> device. No sync marks its end: a
-            # copy still in flight when this returns is waited for by what
-            # first needs the arrays (the engine's eager optimizer init
-            # before the first feed, which no tile claims: ``other``)
-            upload_sp = _ttrace.span("learner.upload")
+                    incoming = self._load_model(task.model, resident=kept)
+            # host -> device: the whole tree, or the leaves not kept. No
+            # sync marks its end: a copy still in flight when this returns
+            # is waited for by what first needs the arrays (the engine's
+            # eager optimizer init, which no tile claims: ``other``, or
+            # the first program call: ``steps``)
+            kept_bytes = sum(a.nbytes for n, a in self._template if n in kept)
+            placed_bytes = sum(a.nbytes for _, a in self._template) - kept_bytes
+            upload_sp = _ttrace.span(
+                "learner.upload", attrs={"bytes": placed_bytes,
+                                         "kept_bytes": kept_bytes})
             with upload_sp:
-                self.model_ops.set_variables(incoming)
+                if kept:
+                    self.model_ops.place_variables(incoming)
+                else:
+                    self.model_ops.set_variables(incoming)
             grad_offset = None
             scaffold_c = None
             if task.scaffold or task.control:
@@ -654,6 +738,11 @@ class Learner:
             # is rejected at config time)
             train_kwargs = ({"grad_offset": grad_offset}
                             if grad_offset is not None else {})
+            if resident and not whole:
+                # read back what ships and no more (``kept`` or placed
+                # anew, the base is not wanted on the host)
+                train_kwargs["read"] = {
+                    n for n, _ in self._shipped(self._template)}
             train_sp = _ttrace.span("learner.train_steps")
             # activated: the engine's train.feed / train.steps /
             # train.readback events (models/ops.py) parent under it
@@ -665,6 +754,10 @@ class Learner:
                 # serializes the record to the sink
                 train_sp.set_attr("steps", out.completed_steps)
                 train_sp.set_attr("ms_per_step", round(out.ms_per_step, 3))
+            if resident:
+                # the train returned: the base is on the device, placed
+                # from the construction-time values and frozen since
+                self._vouched = key + (self.model_ops.variables_epoch,)
             if out.completed_steps > 0 and out.ms_per_step > 0:
                 # a zero-step task (instant cancel, empty dataset) has no
                 # step baseline
@@ -705,7 +798,9 @@ class Learner:
             if scaffold_c is not None:
                 control_delta = self._scaffold_update(
                     incoming, params, out.completed_steps, scaffold_c)
-            ship_vars = None
+            # what ships: the leaves ``train`` read back for it, or (None)
+            # the engine's whole tree, read where it is dumped
+            ship_vars = out.variables if "read" in train_kwargs else None
             if params.dp_clip_norm > 0.0:
                 # client-level DP: clip + noise the update BEFORE any
                 # encryption/masking or wire narrowing (secure/dp.py)
@@ -739,6 +834,10 @@ class Learner:
             tiles["other"] = task_ms - sum(tiles.values())
             task_tiles = {k: round(v, 3) for k, v in tiles.items()}
             task_tiles["start"] = round(accepted[0], 6)
+            # beside the tiles, what crossed host <-> device for them
+            task_tiles.update(placed_bytes=placed_bytes,
+                              kept_bytes=kept_bytes,
+                              read_bytes=out.readback_bytes)
             result = TaskResult(
                 task_id=task.task_id,
                 learner_id=self.learner_id,

@@ -34,6 +34,7 @@ from metisfl_tpu.models.optimizers import make_optimizer
 from metisfl_tpu.telemetry import profile as _tprofile
 from metisfl_tpu.telemetry import runtime as _runtime
 from metisfl_tpu.telemetry import trace as _ttrace
+from metisfl_tpu.tensor.pytree import NamedTensors, _key_to_name
 
 Pytree = Any
 
@@ -42,7 +43,9 @@ logger = logging.getLogger("metisfl_tpu.models")
 
 @dataclass
 class TrainOutput:
-    variables: Pytree
+    # what ``train`` read back: the whole tree, or the named leaves the
+    # caller asked for (``train(..., read=names)``)
+    variables: Pytree | NamedTensors
     completed_steps: int
     completed_batches: int
     completed_epochs: float
@@ -52,11 +55,12 @@ class TrainOutput:
     # where ``train`` spent its own time, in milliseconds on the host's
     # clock (the learner's task waterfall, telemetry/profile.py): batches
     # drawn, stacked and placed; program call to the host sync; the
-    # ``get_variables()`` read-back. An engine that does not time itself
+    # read-back of ``variables``. An engine that does not time itself
     # leaves them 0 and the learner counts that time as ``other``.
     feed_ms: float = 0.0
     steps_ms: float = 0.0
     readback_ms: float = 0.0
+    readback_bytes: int = 0
 
 
 def softmax_cross_entropy_loss(logits, y):
@@ -112,6 +116,17 @@ class FlaxModelOps:
 
     ``module.apply`` convention: zoo modules accept an optional ``train``
     kwarg (dropout/batchnorm mode); plain modules without it work too.
+
+    ``variables`` is the whole tree the engine holds, on the device once
+    placed. Besides the whole-tree ``set_variables`` / ``get_variables()``
+    a caller can place and read named leaves alone
+    (``place_variables(named)``, ``get_variables(names)``,
+    ``train(..., read=names)``): the leaves it does not name stay the
+    device arrays they are. ``frozen_names()`` says which leaves no step
+    can change, so a caller that placed them once need not place them
+    again (the learner's ship-only rounds, learner/learner.py);
+    ``variables_epoch`` moves whenever the tree is assigned whole, which
+    is when such a caller has to start over.
     """
 
     def __init__(
@@ -137,6 +152,7 @@ class FlaxModelOps:
         self.mesh = mesh
         self.partition_rules = list(partition_rules or [])
         self._trainable_regex = trainable_regex
+        self.variables_epoch = 0
         if variables is not None:
             self.variables = variables
         else:
@@ -220,14 +236,72 @@ class FlaxModelOps:
         return 6.0 * self.param_count() * max(1, int(batch_size))
 
     # -- weights I/O -------------------------------------------------------
-    def get_variables(self) -> Pytree:
-        return jax.device_get(self.variables)
+    @property
+    def variables(self) -> Pytree:
+        return self._variables
+
+    @variables.setter
+    def variables(self, tree: Pytree) -> None:
+        # the whole tree assigned (``set_variables``, or a caller's own
+        # ``ops.variables = ...``): whoever placed leaves once and counted
+        # on their staying has to place them again. ``train`` and
+        # ``place_variables`` write ``_variables`` and leave the epoch.
+        self._variables = tree
+        self.variables_epoch += 1
+
+    def _named_leaves(self):
+        flat, treedef = jax.tree_util.tree_flatten_with_path(self._variables)
+        return [(_key_to_name(path), leaf) for path, leaf in flat], treedef
+
+    def frozen_names(self) -> frozenset:
+        """Wire names of the leaves no train step can change: the params
+        the freeze mask (``trainable_regex``) labels ``freeze``. Empty
+        without a mask; ``batch_stats`` is never among them (the step
+        mutates it)."""
+        if not self._trainable_regex:
+            return frozenset()
+        import re
+        # the mask matches names below ``params`` (_make_step)
+        return frozenset(
+            name for name, _ in self._named_leaves()[0]
+            if name.startswith("params/") and not re.search(
+                self._trainable_regex, name[len("params/"):]))
+
+    def get_variables(self, names=None) -> Pytree | NamedTensors:
+        """The whole tree on the host; with ``names`` those leaves alone,
+        as named tensors in tree order (nothing else is read back)."""
+        if names is None:
+            return jax.device_get(self._variables)
+        picked = [(n, leaf) for n, leaf in self._named_leaves()[0]
+                  if n in names]
+        host = jax.device_get([leaf for _, leaf in picked])
+        return [(n, a) for (n, _), a in zip(picked, host)]
 
     def set_variables(self, variables: Pytree) -> None:
         if self.mesh is not None:
             self.variables = self._shard(variables)
         else:
             self.variables = jax.tree.map(jnp.asarray, variables)
+
+    def place_variables(self, named: NamedTensors) -> None:
+        """Replace the named leaves of the tree the engine holds; every
+        other leaf stays the device array it is (no second copy of the
+        tree, nothing placed twice)."""
+        new = dict(named)
+        leaves, treedef = self._named_leaves()
+        unknown = sorted(set(new) - {n for n, _ in leaves})
+        if unknown:
+            raise KeyError(f"no such leaves in the model: {unknown[:5]}")
+        if self.mesh is not None:
+            from metisfl_tpu.parallel.sharding import tree_shardings
+            shardings = jax.tree.leaves(tree_shardings(
+                self._variables, self.mesh, self.partition_rules))
+            placed = [jax.device_put(new[n], shardings[i]) if n in new
+                      else leaf for i, (n, leaf) in enumerate(leaves)]
+        else:
+            placed = [jnp.asarray(new[n]) if n in new else leaf
+                      for n, leaf in leaves]
+        self._variables = jax.tree_util.tree_unflatten(treedef, placed)
 
     # -- training ----------------------------------------------------------
     def _cfg_key(self, params_cfg: TrainParams) -> tuple:
@@ -249,8 +323,6 @@ class FlaxModelOps:
                             params_cfg.optimizer_kwargs)
         if self._trainable_regex:
             import re as _re
-
-            from metisfl_tpu.tensor.pytree import _key_to_name
 
             regex = self._trainable_regex
 
@@ -365,10 +437,12 @@ class FlaxModelOps:
         return self._step_cache[key]
 
     def train(self, dataset: ArrayDataset, params_cfg: TrainParams,
-              cancel_event=None, grad_offset=None) -> TrainOutput:
+              cancel_event=None, grad_offset=None, read=None) -> TrainOutput:
         """``grad_offset``: optional params-shaped tree ADDED to every
         step's gradients (SCAFFOLD control-variate correction c - c_i;
-        None = uncorrected — identical compiled program)."""
+        None = uncorrected — identical compiled program). ``read``: the
+        names of the leaves to read back into ``TrainOutput.variables``
+        (``get_variables(names)``); None reads the whole tree."""
         steps_per_epoch = max(1, len(dataset) // max(1, params_cfg.batch_size))
         if params_cfg.local_steps > 0:
             total_steps = params_cfg.local_steps
@@ -377,8 +451,8 @@ class FlaxModelOps:
                 params_cfg.local_epochs * steps_per_epoch)))
 
         compiled, tx, _ = self._make_step(params_cfg)
-        params = self.variables["params"]
-        batch_stats = self.variables.get("batch_stats", {})
+        params = self._variables["params"]
+        batch_stats = self._variables.get("batch_stats", {})
         # FedProx anchors to a non-donated copy of the round-start params;
         # without FedProx an empty tree avoids aliasing the donated params.
         global_params = (jax.tree.map(jnp.copy, params)
@@ -528,7 +602,9 @@ class FlaxModelOps:
         new_vars = {"params": params}
         if self._has_batch_stats:
             new_vars["batch_stats"] = batch_stats
-        self.variables = new_vars
+        # the engine's own write: the leaves the step cannot change are
+        # what they were, so the epoch stays
+        self._variables = new_vars
         self._rng = rng
 
         if not step_times and fallback_time is not None:
@@ -536,8 +612,11 @@ class FlaxModelOps:
         ms_per_step = float(np.median(step_times) * 1e3) if step_times else 0.0
         t_read = time.perf_counter()
         steps_s += t_read - t_sync
-        variables = self.get_variables()
+        variables = self.get_variables(read)
         readback_s = time.perf_counter() - t_read
+        # (the names of a named read are leaves too, of no bytes)
+        read_bytes = sum(getattr(leaf, "nbytes", 0)
+                         for leaf in jax.tree.leaves(variables))
         if feed_at is not None:
             _ttrace.event("train.feed", feed_s,
                           start=wall0 + feed_at - perf0)
@@ -545,7 +624,8 @@ class FlaxModelOps:
                           start=wall0 + steps_at - perf0,
                           attrs={"steps": completed})
         _ttrace.event("train.readback", readback_s,
-                      start=wall0 + t_read - perf0)
+                      start=wall0 + t_read - perf0,
+                      attrs={"bytes": read_bytes})
         return TrainOutput(
             variables=variables,
             completed_steps=completed,
@@ -560,6 +640,7 @@ class FlaxModelOps:
             feed_ms=feed_s * 1e3,
             steps_ms=steps_s * 1e3,
             readback_ms=readback_s * 1e3,
+            readback_bytes=read_bytes,
         )
 
     # -- inference ---------------------------------------------------------

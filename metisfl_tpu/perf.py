@@ -165,6 +165,8 @@ def _fmt_ms(ms: float) -> str:
 
 
 def _fmt_bytes(n: float) -> str:
+    if n >= 1e9:
+        return f"{n / 1e9:.2f}GB"
     if n >= 1e6:
         return f"{n / 1e6:.2f}MB"
     if n >= 1e3:
@@ -192,7 +194,8 @@ def render_waterfall(profiles: List[dict], width: int = 40,
     """The phase waterfall (one bar block per round), each learner's own
     task waterfall under it, and the per-learner attribution table for
     each profiled round."""
-    from metisfl_tpu.telemetry.profile import PHASES, TASK_TILES
+    from metisfl_tpu.telemetry.profile import (PHASES, TASK_BYTES,
+                                               TASK_TILES)
 
     lines: List[str] = []
     for prof in profiles:
@@ -216,6 +219,13 @@ def render_waterfall(profiles: List[dict], width: int = 40,
             lines.append(f"  task {lid}  wall {_fmt_ms(task_ms)}")
             lines.extend("  " + line
                          for line in _bars(task, TASK_TILES, task_ms, width))
+            sizes = learners[lid].get("task_bytes") or {}
+            if sizes:
+                # what upload placed and kept on the device (the frozen
+                # base of a ship-only round), what readback read
+                lines.append("    host<->device  " + "  ".join(
+                    f"{k[:-len('_bytes')]} {_fmt_bytes(sizes[k])}"
+                    for k in TASK_BYTES if k in sizes))
         store = prof.get("store") or {}
         if store:
             lines.append(

@@ -67,6 +67,12 @@ _MARKS = ("dispatch_end", "wait_end", "select_end", "aggregate_end")
 # ``TaskResult.task_tiles`` into ``RoundProfile.learners[lid]["task"]``)
 TASK_TILES = ("queued", "load", "upload", "feed", "steps", "readback",
               "snapshot", "encode", "other")
+# what crossed host <-> device for that task, beside the tiles in
+# ``task_tiles`` and under ``RoundProfile.learners[lid]["task_bytes"]``:
+# placed by ``upload``, kept on the device from the task before (the
+# frozen base of a ship-only round; 0 when the whole tree was placed),
+# read back by ``readback``
+TASK_BYTES = ("placed_bytes", "kept_bytes", "read_bytes")
 
 _REG = _tmetrics.registry()
 _M_DOWNLINK = _REG.counter(
@@ -270,11 +276,13 @@ class RoundProfile:
     # ``phases`` so its coverage invariant holds
     extras: Dict[str, float] = field(default_factory=dict)
     # learner → {uplink_bytes, downlink_bytes, codec_encode_s,
-    #            codec_decode_s, insert_ms, device{...}, task{...}};
+    #            codec_decode_s, insert_ms, device{...}, task{...},
+    #            task_bytes{...}};
     # ``task`` is the learner's own waterfall of this round's train task
     # (TASK_TILES in ms, tiling its clock from the RPC's acceptance to
-    # the start of its report, plus ``start`` as time.time()); absent
-    # from a learner that ships none
+    # the start of its report, plus ``start`` as time.time()) and
+    # ``task_bytes`` its TASK_BYTES; absent from a learner that ships
+    # none
     learners: Dict[str, Dict[str, Any]] = field(default_factory=dict)
     totals: Dict[str, float] = field(default_factory=dict)
     serving: Dict[str, Any] = field(default_factory=dict)
@@ -526,7 +534,14 @@ class ProfileCollector:
             if lid in device:
                 entry["device"] = device[lid]
             if lid in task:
-                entry["task"] = task[lid]
+                # the tiles (and ``start``) apart from the byte counts:
+                # readers sum ``task`` to the task's wall time
+                entry["task"] = {k: v for k, v in task[lid].items()
+                                 if k not in TASK_BYTES}
+                sizes = {k: int(task[lid][k]) for k in TASK_BYTES
+                         if k in task[lid]}
+                if sizes:
+                    entry["task_bytes"] = sizes
             learners[lid] = entry
         profile = RoundProfile(
             round=int(getattr(meta, "global_iteration", 0)),

@@ -194,3 +194,93 @@ def test_profiler_runs_when_scan_chunk_exceeds_steps(tmp_path):
     assert out.completed_steps == 3
     traces = glob.glob(str(tmp_path) + "/**/*.xplane.pb", recursive=True)
     assert traces, "no profiler trace captured on the fallback path"
+
+
+# --------------------------------------------------------------------- #
+# named leaves: placed and read alone, the rest stays on the device
+# --------------------------------------------------------------------- #
+
+HEAD_NAMES = {"params/Dense_1/bias", "params/Dense_1/kernel"}
+BASE_NAMES = {"params/Dense_0/bias", "params/Dense_0/kernel"}
+
+
+def _frozen_engine(**kwargs):
+    ds = _toy_classification(seed=21)
+    engine = FlaxModelOps(MLP(features=(16,), num_outputs=3), ds.x[:2],
+                          trainable_regex="Dense_1", **kwargs)
+    engine.set_variables(engine.get_variables())    # device arrays
+    return engine, ds
+
+
+def test_frozen_names_are_the_masked_params():
+    engine, _ = _frozen_engine()
+    assert engine.frozen_names() == BASE_NAMES
+    plain, _ = _frozen_engine()
+    plain._trainable_regex = ""
+    assert plain.frozen_names() == frozenset()
+
+
+def test_place_variables_keeps_the_other_device_arrays():
+    engine, _ = _frozen_engine()
+    before = engine.variables["params"]
+    epoch = engine.variables_epoch
+    head = [(n, a + 1.0) for n, a in engine.get_variables(HEAD_NAMES)]
+    engine.place_variables(head)
+    after = engine.variables["params"]
+    for leaf in ("bias", "kernel"):
+        assert after["Dense_0"][leaf] is before["Dense_0"][leaf]
+        assert after["Dense_1"][leaf] is not before["Dense_1"][leaf]
+    assert engine.get_variables(HEAD_NAMES)[1][0] == "params/Dense_1/kernel"
+    np.testing.assert_array_equal(engine.get_variables(HEAD_NAMES)[1][1],
+                                  head[1][1])
+    # the engine's own writes leave the epoch; a whole-tree assignment
+    # moves it
+    assert engine.variables_epoch == epoch
+    engine.variables = engine.variables
+    assert engine.variables_epoch == epoch + 1
+    engine.set_variables(engine.get_variables())
+    assert engine.variables_epoch == epoch + 2
+    with pytest.raises(KeyError, match="no_such_leaf"):
+        engine.place_variables([("params/no_such_leaf", head[0][1])])
+
+
+def test_train_reads_back_the_named_leaves_alone():
+    engine, ds = _frozen_engine()
+    twin, _ = _frozen_engine()
+    cfg = TrainParams(batch_size=16, local_steps=4, learning_rate=0.1)
+    base = dict(engine.get_variables(BASE_NAMES))
+    head = dict(engine.get_variables(HEAD_NAMES))
+    epoch = engine.variables_epoch
+    out = engine.train(ds, cfg, read=HEAD_NAMES)
+    whole = twin.train(ds, cfg)
+    assert [n for n, _ in out.variables] == sorted(HEAD_NAMES)
+    assert out.readback_bytes == sum(a.nbytes for _, a in out.variables)
+    assert out.readback_ms > 0 and engine.variables_epoch == epoch
+    from metisfl_tpu.tensor.pytree import pytree_to_named_tensors
+    named = dict(pytree_to_named_tensors(whole.variables))
+    assert whole.readback_bytes == sum(a.nbytes for a in named.values())
+    for n, a in out.variables:
+        np.testing.assert_array_equal(a, named[n])
+        assert not np.array_equal(a, head[n])       # it trained
+    # the mask held: the base on the device is what it was
+    for n, a in engine.get_variables(BASE_NAMES):
+        np.testing.assert_array_equal(a, base[n])
+
+
+def test_place_variables_on_a_mesh_keeps_the_rules_sharding():
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    engine, _ = _frozen_engine(
+        mesh=mesh, partition_rules=[("Dense_1/kernel", P("tp", None))])
+    before = engine.variables["params"]
+    spec = before["Dense_1"]["kernel"].sharding.spec
+    assert spec == P("tp", None)
+    head = [(n, a * 2.0) for n, a in engine.get_variables(HEAD_NAMES)]
+    engine.place_variables(head)
+    after = engine.variables["params"]
+    assert after["Dense_0"]["kernel"] is before["Dense_0"]["kernel"]
+    assert after["Dense_1"]["kernel"].sharding.spec == spec
+    np.testing.assert_array_equal(np.asarray(after["Dense_1"]["kernel"]),
+                                  head[1][1])

@@ -9,6 +9,13 @@ collapsed under ~100 MB full-model RPCs and hacked around it with a
 stub-per-request workaround (reference
 metisfl/controller/core/controller.cc:594-604); an 8.8B-param bf16 blob
 (~17.6 GB) would exceed gRPC's ~2 GiB framing outright.
+
+On the learner's side a leaf that is unshipped AND frozen by the engine's
+own mask (``trainable_regex``) is placed on the device once and stays
+there: later tasks place, read back and encode the shipped leaves alone
+(``Learner._resident_names``; the second half of this file). An unshipped
+leaf the engine does NOT freeze is still reset from the construction-time
+values on every receipt.
 """
 
 import numpy as np
@@ -92,15 +99,35 @@ def _run(fed, rounds=3):
         fed.shutdown()
 
 
+# the final community model's test loss over the seed template's: the
+# seeded runs read 0.53 after three rounds and 0.48 after a fourth (the
+# federation may close one more before it stops), an untrained head 1.0
+LOSS_FALLS_TO = 0.75
+
+
+def _loss_ratio(fed, template):
+    """Test loss of the community model the federation ended on over that
+    of the seed template, both through learner 0's merge (decode, backfill
+    of the frozen base) and engine. Which round a community evaluation
+    happened to judge moves with the threads' scheduling (the accuracy of
+    ``evals[-1]`` read 0.62 to 0.9 from one seed); the model the federation
+    ended on does not."""
+    learner = fed.learners[0]
+    test = learner.datasets["test"]
+    merged = learner._load_model(fed.controller.community_model_bytes())
+    return (learner.model_ops.evaluate(test, 64, variables=merged)["loss"]
+            / learner.model_ops.evaluate(test, 64,
+                                         variables=template)["loss"])
+
+
 def test_head_only_federation_learns_and_wire_is_subset_sized():
     """Only the output layer federates; the federation still learns the
     linearly-separable task (shared random features + aggregated linear
     head), and every wire hop carries only the subset."""
-    fed, template, base = _build()
+    fed, template, _ = _build()
     controller = fed.controller
-    stats, acc = _run(fed)
-    assert acc > base + LEARN_MARGIN, (
-        f"head-only federation failed to learn: {acc} (baseline {base})")
+    stats, _ = _run(fed)
+    assert _loss_ratio(fed, template) < LOSS_FALLS_TO
 
     named = pytree_to_named_tensors(template)
     full_bytes = _named_bytes(named)
@@ -140,21 +167,17 @@ def test_frozen_base_resets_each_round():
 def test_topk_composes_with_ship_regex():
     """Top-k sparse uplink over the shipped subset: the controller
     densifies against its subset community model."""
-    fed, _, base = _build(ship_dtype="topk2")
-    _, acc = _run(fed)
-    assert acc > base + LEARN_MARGIN, (
-        f"topk x ship-only federation failed to learn: {acc} "
-        f"(baseline {base})")
+    fed, template, _ = _build(ship_dtype="topk2")
+    _run(fed)
+    assert _loss_ratio(fed, template) < LOSS_FALLS_TO
 
 
 def test_fednova_composes_with_ship_regex():
     """Stateful server rules track the SUBSET tree consistently (seeded
     filtered, aggregated filtered)."""
-    fed, _, base = _build(rule="fednova")
-    _, acc = _run(fed)
-    assert acc > base + LEARN_MARGIN, (
-        f"fednova x ship-only federation failed to learn: {acc} "
-        f"(baseline {base})")
+    fed, template, _ = _build(rule="fednova")
+    _run(fed)
+    assert _loss_ratio(fed, template) < LOSS_FALLS_TO
 
 
 def test_async_protocol_composes_with_ship_regex():
@@ -471,3 +494,199 @@ def test_ckks_secure_composes_with_ship_regex():
         assert acc["accuracy"] > 0.7, acc  # see masking test note
     finally:
         fed.shutdown()
+
+
+# --------------------------------------------------------------------- #
+# the frozen base stays on the device (Learner._resident_names)
+# --------------------------------------------------------------------- #
+
+class _Sink:
+    """A controller that keeps what the learner reports."""
+
+    def __init__(self):
+        self.results = []
+
+    def task_completed(self, result):
+        self.results.append(result)
+        return True
+
+
+def _lone_learner(trainable=HEAD):
+    """One learner on the seeded task, its engine frozen outside
+    ``trainable`` (the LoRA posture: mask = ship regex) or not at all."""
+    from metisfl_tpu.learner.learner import Learner
+
+    shards, test = _shards(1)
+    engine = FlaxModelOps(MLP(features=(16,), num_outputs=3),
+                          shards[0].x[:2], rng_seed=0,
+                          trainable_regex=trainable)
+    return Learner(engine, shards[0], controller=_Sink(), test_dataset=test)
+
+
+def _head_blob(learner):
+    return ModelBlob(tensors=[(n, a) for n, a in learner._template
+                              if "Dense_1" in n]).to_bytes()
+
+
+def _task(learner, round_id, model=None, ship=HEAD, **kw):
+    """One train task run to its report on this thread; returns the
+    TaskResult, or None where the task failed."""
+    from metisfl_tpu.comm.messages import TrainTask
+
+    task_kw = {k: kw.pop(k) for k in ("scaffold",) if k in kw}
+    sink = learner.controller
+    seen = len(sink.results)
+    learner._train_and_report(TrainTask(
+        task_id=f"t{round_id}", round_id=round_id,
+        model=model or (sink.results[-1].model if sink.results
+                        else _head_blob(learner)),
+        params=TrainParams(batch_size=16, local_steps=4, learning_rate=0.2,
+                           ship_tensor_regex=ship, **kw), **task_kw))
+    return sink.results[-1] if len(sink.results) > seen else None
+
+
+def _whole_tree(learner):
+    """Residency forced off: the whole tree assigned from outside."""
+    learner.model_ops.variables = learner.model_ops.variables
+
+
+def test_resident_base_ships_the_same_bytes_as_whole_tree_rounds():
+    """Three rounds of a frozen, ship-only learner, each fed the blob the
+    last one shipped: byte for byte the blobs of the same rounds with the
+    whole tree placed and read back every time."""
+    resident, control = _lone_learner(), _lone_learner()
+    for r in range(3):
+        _whole_tree(control)
+        a, b = _task(resident, r), _task(control, r)
+        assert a.model == b.model, f"round {r}"
+        assert b.task_tiles["kept_bytes"] == 0
+        assert (a.task_tiles["kept_bytes"] > 0) == (r > 0)
+    full = _named_bytes(resident._template)
+    head = _named_bytes([(n, x) for n, x in resident._template
+                         if "Dense_1" in n])
+    tiles = resident.controller.results[-1].task_tiles
+    assert tiles["placed_bytes"] == tiles["read_bytes"] == head
+    assert tiles["kept_bytes"] == full - head
+    first = resident.controller.results[0].task_tiles
+    assert (first["placed_bytes"], first["read_bytes"]) == (full, head)
+    assert ModelBlob.from_bytes(a.model).tensors[0][1].dtype == np.float32
+
+
+def test_unfrozen_base_trains_locally_and_is_reset_on_every_receipt():
+    """No freeze mask: nothing is resident. The base moves under local
+    training and the next task starts from the construction-time values
+    again, placed whole."""
+    learner = _lone_learner(trainable="")
+    engine = learner.model_ops
+    base = {n: a for n, a in learner._template if "Dense_1" not in n}
+    at_train = []
+    real = engine.train
+
+    def spy(*args, **kwargs):
+        at_train.append(dict(engine.get_variables(set(base))))
+        return real(*args, **kwargs)
+
+    engine.train = spy
+    for r in range(3):
+        result = _task(learner, r)
+        assert result.task_tiles["kept_bytes"] == 0
+        moved = dict(engine.get_variables(set(base)))
+        assert any(not np.array_equal(moved[n], base[n]) for n in base)
+    for seen in at_train:
+        for n in base:
+            np.testing.assert_array_equal(seen[n], base[n])
+
+
+def _raise_in_train(learner):
+    """The next train donates its inputs for two steps, then raises."""
+    ds = learner.datasets["train"]
+    feed = ds.infinite_batches
+
+    def failing(*args, **kwargs):
+        ds.infinite_batches = feed
+        for i, batch in enumerate(feed(*args, **kwargs)):
+            if i == 2:
+                raise RuntimeError("planted")
+            yield batch
+
+    ds.infinite_batches = failing
+
+
+# kind -> (what happens before task 2, that task's keywords, which of the
+# tasks 0..4 keep the base on the device)
+FALLBACKS = {
+    "train_raises": (_raise_in_train, {}, [False, True, None, False, True]),
+    "assigned_from_outside": (_whole_tree, {},
+                              [False, True, False, True, True]),
+    "ship_regex_changed": (None, {"ship": HEAD + "/"},
+                           [False, True, False, True, True]),
+    "scaffold": (None, {"scaffold": True}, [False, True, False, True, True]),
+    "dp_clip_norm": (None, {"dp_clip_norm": 0.05},
+                     [False, True, False, True, True]),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(FALLBACKS))
+def test_resident_base_falls_back_to_the_whole_tree(kind):
+    """Where the learner cannot vouch for the base on the device, or the
+    task wants the whole tree on the host, the task places all of it from
+    the construction-time values; the task after is resident again. Every
+    blob equals that of a learner that never keeps anything."""
+    before, kw, keeps = FALLBACKS[kind]
+    resident, control = _lone_learner(), _lone_learner()
+    for r in range(5):
+        step_kw = dict(kw) if r == 2 or (
+            r > 2 and kind == "ship_regex_changed") else {}
+        if r == 2 and before is not None:
+            before(resident)
+            before(control)
+        _whole_tree(control)
+        a, b = _task(resident, r, **step_kw), _task(control, r, **step_kw)
+        if keeps[r] is None:
+            assert a is None and b is None      # the train raised
+            continue
+        assert a.model == b.model, f"round {r}"
+        assert a.control_delta == b.control_delta
+        assert (a.task_tiles["kept_bytes"] > 0) == keeps[r], f"round {r}"
+        assert b.task_tiles["kept_bytes"] == 0
+    if kind == "scaffold":
+        assert resident.controller.results[2].control_delta
+    if kind == "dp_clip_norm":
+        # the clipped update is what shipped
+        sent = dict(ModelBlob.from_bytes(
+            resident.controller.results[1].model).tensors)
+        got = dict(ModelBlob.from_bytes(
+            resident.controller.results[2].model).tensors)
+        norm = np.sqrt(sum(float(np.sum((got[n] - sent[n]) ** 2))
+                           for n in got))
+        assert norm == pytest.approx(0.05, rel=1e-3)
+
+
+class _WholeTreeOnly:
+    """The surface multi-host ``LeaderOps`` offers the learner: the real
+    engine behind ``inner``, whole-tree calls and no others."""
+
+    def __init__(self, inner):
+        self.inner = inner
+
+    def get_variables(self):
+        return self.inner.get_variables()
+
+    def set_variables(self, variables):
+        self.inner.set_variables(variables)
+
+    def train(self, dataset, params_cfg, cancel_event=None):
+        return self.inner.train(dataset, params_cfg,
+                                cancel_event=cancel_event)
+
+
+def test_an_engine_that_cannot_place_named_leaves_moves_the_whole_tree():
+    resident, wrapped = _lone_learner(), _lone_learner()
+    wrapped.model_ops = _WholeTreeOnly(wrapped.model_ops)
+    full = _named_bytes(resident._template)
+    for r in range(3):
+        a, b = _task(resident, r), _task(wrapped, r)
+        assert a.model == b.model, f"round {r}"
+        assert b.task_tiles["kept_bytes"] == 0
+        assert b.task_tiles["placed_bytes"] == full
+        assert b.task_tiles["read_bytes"] == full

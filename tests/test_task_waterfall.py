@@ -62,6 +62,8 @@ def test_task_tiles_sum_to_the_task_and_land_in_the_round_profile(
             engine.set_variables(template)
         fed.add_learner(engine, ArrayDataset(x, y, seed=i))
     fed.seed_model(template)
+    import jax
+    full = sum(leaf.nbytes for leaf in jax.tree.leaves(template))
     try:
         fed.start()
         assert fed.wait_for_rounds(2, timeout_s=120)
@@ -116,9 +118,74 @@ def test_task_tiles_sum_to_the_task_and_land_in_the_round_profile(
                 assert tiles[tile] == pytest.approx(
                     inner["train." + tile]["dur_ms"], abs=0.01), tile
             assert inner["train.steps"]["attrs"]["steps"] == 4
+            # a full-model task: the whole tree placed and read back,
+            # nothing kept on the device
+            assert entry["task_bytes"] == {
+                "placed_bytes": full, "kept_bytes": 0, "read_bytes": full}
+            assert children["learner.upload"]["attrs"] == {
+                "bytes": full, "kept_bytes": 0}
+            assert inner["train.readback"]["attrs"] == {"bytes": full}
             assert "jit_compile_s_est" not in children[
                 "learner.train_steps"]["attrs"]
     assert seen >= 2
+
+
+def test_a_resident_task_keeps_its_base_arrays_and_all_nine_tiles(
+        span_ring):
+    """A frozen, ship-only learner's second task: the base on the device
+    is the very arrays the first task left, ``learner.upload`` and
+    ``train.readback`` count the shipped leaves alone, and the waterfall
+    has its nine tiles summing to the task's wall time, as on the
+    whole-tree task before it."""
+    from tests.test_shiponly import _lone_learner, _named_bytes, _task
+
+    learner = _lone_learner()
+    engine = learner.model_ops
+    head = _named_bytes([(n, a) for n, a in learner._template
+                         if "Dense_1" in n])
+    full = _named_bytes(learner._template)
+    at_train = []
+    real = engine.train
+
+    def spy(*args, **kwargs):
+        at_train.append(dict(engine.variables["params"]["Dense_0"]))
+        return real(*args, **kwargs)
+
+    engine.train = spy
+    left = []
+    for r in range(2):
+        result = _task(learner, r)
+        assert set(result.task_tiles) == (set(tprofile.TASK_TILES)
+                                         | set(tprofile.TASK_BYTES)
+                                         | {"start"})
+        left.append(dict(engine.variables["params"]["Dense_0"]))
+    # what task 0 left is what task 1 trained on: not placed again
+    assert all(at_train[1][k] is left[0][k] for k in left[0])
+    assert all(at_train[0][k] is not left[0][k] for k in left[0])
+    spans = span_ring()
+    by_parent = {}
+    for s in spans:
+        by_parent.setdefault(s["parent"], []).append(s)
+    trains = sorted((s for s in spans if s["name"] == "learner.train"),
+                    key=lambda s: s["attrs"]["round"])
+    assert len(trains) == 2
+    for r, (train, result) in enumerate(zip(trains,
+                                            learner.controller.results)):
+        children = {s["name"]: s for s in by_parent[train["span"]]}
+        tiles = {k: result.task_tiles[k] for k in tprofile.TASK_TILES}
+        assert sum(tiles.values()) == pytest.approx(
+            children["learner.report"]["attrs"]["task_ms"], abs=1.0)
+        assert tiles["upload"] == pytest.approx(
+            children["learner.upload"]["dur_ms"], abs=0.01)
+        assert tiles["upload"] > 0 and tiles["readback"] > 0
+        inner = {s["name"]: s for s in by_parent[
+            children["learner.train_steps"]["span"]]}
+        assert tiles["readback"] == pytest.approx(
+            inner["train.readback"]["dur_ms"], abs=0.01)
+        assert inner["train.readback"]["attrs"] == {"bytes": head}
+        assert children["learner.upload"]["attrs"] == (
+            {"bytes": head, "kept_bytes": full - head} if r
+            else {"bytes": full, "kept_bytes": 0})
 
 
 class _SilentProxy:
@@ -163,7 +230,8 @@ def test_result_without_tiles_decodes_and_yields_no_task_key():
         with ctrl._lock:
             tokens = {lid: ctrl._learners[lid].auth_token for lid in lids}
         shipped = {"start": 12.5, "queued": 0.1, "steps": 7.0, "other": 0.4}
-        for lid, tiles in zip(lids, ({}, shipped)):
+        sizes = {"placed_bytes": 64, "kept_bytes": 4096, "read_bytes": 64}
+        for lid, tiles in zip(lids, ({}, {**shipped, **sizes})):
             wire = TaskResult(
                 task_id=f"t_{lid}", learner_id=lid, auth_token=tokens[lid],
                 model=pack_model(model), round_id=0, completed_batches=1,
@@ -178,7 +246,10 @@ def test_result_without_tiles_decodes_and_yields_no_task_key():
     finally:
         ctrl.shutdown()
     assert "task" not in profile["learners"][lids[0]]
+    # the tiles apart from the byte counts: readers sum ``task``
     assert profile["learners"][lids[1]]["task"] == shipped
+    assert profile["learners"][lids[1]]["task_bytes"] == sizes
+    assert "task_bytes" not in profile["learners"][lids[0]]
 
 
 def test_perf_round_view_prints_each_learners_task_waterfall():
@@ -189,7 +260,10 @@ def test_perf_round_view_prints_each_learners_task_waterfall():
             "learners": {
                 "L0": {"uplink_bytes": 1, "downlink_bytes": 1,
                        "task": {"start": 1.0, "queued": 1.0, "load": 4.0,
-                                "steps": 60.0, "readback": 15.0}},
+                                "steps": 60.0, "readback": 15.0},
+                       "task_bytes": {"placed_bytes": 7_300_000,
+                                      "kept_bytes": 5_530_000_000,
+                                      "read_bytes": 7_300_000}},
                 "L1": {"uplink_bytes": 1, "downlink_bytes": 1}}}
     screen = perf.render_waterfall([prof]).splitlines()
     at = next(i for i, line in enumerate(screen)
@@ -199,6 +273,9 @@ def test_perf_round_view_prints_each_learners_task_waterfall():
     assert names == ["queued", "load", "steps", "readback"]
     steps = screen[at + 3]
     assert "60.0ms" in steps and "75.0%" in steps and "#" * 40 in steps
+    assert screen[at + 5].split() == [
+        "host<->device", "placed", "7.30MB", "kept", "5.53GB", "read",
+        "7.30MB"]
     assert not any(line.startswith("  task L1") for line in screen)
 
 
