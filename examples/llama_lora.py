@@ -9,6 +9,11 @@ inserts the all-reduces), and FedAvg merges the rounds.
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/llama_lora.py --dim 64 --rounds 2
+
+``--hybrid`` swaps the model for the attention / state-space hybrid
+(``JambaLite``: Mamba mixers with one attention block a period, adapters on
+``in_proj``/``out_proj`` and ``wq``/``wv``); the federation, the shipped
+subset and the decode at the end are the same code.
 """
 
 from __future__ import annotations
@@ -30,6 +35,9 @@ def main() -> int:
     parser.add_argument("--vocab", type=int, default=256)
     parser.add_argument("--seq-len", type=int, default=32)
     parser.add_argument("--lora-rank", type=int, default=8)
+    parser.add_argument("--hybrid", action="store_true",
+                        help="JambaLite (Mamba mixers, an attention block "
+                             "every second layer) in LlamaLite's place")
     parser.add_argument("--scan-chunk", type=int, default=1,
                         help="fuse this many local steps into one compiled "
                              "scan program (dispatch amortization on TPU)")
@@ -48,7 +56,8 @@ def main() -> int:
                                     FederationConfig, TerminationConfig)
     from metisfl_tpu.driver import InProcessFederation
     from metisfl_tpu.models import ArrayDataset, FlaxModelOps
-    from metisfl_tpu.models.zoo import TRANSFORMER_RULES, LlamaLite
+    from metisfl_tpu.models.zoo import (TRANSFORMER_RULES, JambaLite,
+                                        LlamaLite)
     from metisfl_tpu.parallel.mesh import MeshConfig, build_mesh
 
     mesh = build_mesh(MeshConfig(("dp", "tp"), (args.dp, args.tp)))
@@ -68,9 +77,15 @@ def main() -> int:
             state = np.asarray(nxt)
         return ArrayDataset(toks[:, :-1], toks[:, 1:], seed=seed)
 
-    module = LlamaLite(vocab_size=args.vocab, dim=args.dim,
-                       depth=args.depth, heads=args.heads,
-                       lora_rank=args.lora_rank)
+    if args.hybrid:
+        module = JambaLite(vocab_size=args.vocab, dim=args.dim,
+                           depth=args.depth, heads=args.heads, kv_heads=1,
+                           attn_period=2, attn_offset=1, d_state=8,
+                           lora_rank=args.lora_rank)
+    else:
+        module = LlamaLite(vocab_size=args.vocab, dim=args.dim,
+                           depth=args.depth, heads=args.heads,
+                           lora_rank=args.lora_rank)
     config = FederationConfig(
         aggregation=AggregationConfig(scaler="participants"),
         # ship-only-trainable: just the LoRA adapters cross the wire, and

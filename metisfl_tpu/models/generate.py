@@ -1,4 +1,4 @@
-"""Autoregressive decoding with a static-shape KV cache (TPU-native).
+"""Autoregressive decoding with static-shape per-block state (TPU-native).
 
 The reference's third learner task type is inference
 (reference metisfl/learner/learner.py:311-330); for the causal-LM family
@@ -22,6 +22,11 @@ Works with any :class:`~metisfl_tpu.models.zoo.LlamaLite` configuration
 (LoRA, GQA, MoE, bf16) on the same trained parameters — the cache mode
 reuses the module's own projections, so there is no separate "inference
 model" to convert to.
+
+The module says what each block's decode state is (``init_cache``): a
+``(K, V)`` pair for an attention block, ``(conv_state, ssm_state)`` for a
+Mamba block of :class:`~metisfl_tpu.models.zoo.JambaLite`. Everything here
+carries that state as a pytree and looks inside none of it.
 """
 
 from __future__ import annotations
@@ -37,14 +42,19 @@ Pytree = Any
 
 
 def init_cache(module, batch: int, max_len: int):
-    """Zeroed per-block KV caches for ``module`` (a zoo ``LlamaLite``)."""
-    kv_heads = module.kv_heads or module.heads
-    head_dim = module.dim // module.heads
-    dtype = module.dtype or jnp.float32
-    shape = (batch, kv_heads, max_len, head_dim)
-    return tuple(
-        (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-        for _ in range(module.depth))
+    """Zeroed per-block decode state of ``module``, as the module itself
+    lays it out (``LlamaLite.init_cache``, ``JambaLite.init_cache``)."""
+    return module.init_cache(batch, max_len)
+
+
+def cache_bytes_by_kind(module, caches) -> dict:
+    """Bytes of ``caches`` (one entry a block) by what the module says
+    each entry is: ``kv`` grows with ``max_len``, ``state`` does not."""
+    out: dict = {}
+    for kind, entry in zip(module.cache_kinds(), caches):
+        out[kind] = out.get(kind, 0) + sum(
+            int(leaf.nbytes) for leaf in jax.tree.leaves(entry))
+    return out
 
 
 def _sampler(temperature: float, top_k: int, top_p: float = 0.0):
@@ -196,15 +206,18 @@ class SlotDecoder:
       position (``vmap`` over the slot axis carries the per-slot
       position the module's scalar ``position`` argument cannot).
 
-    The caches are allocated once at fixed slot shapes
-    ``(slots, 1, kv_heads, max_len, head_dim)`` per block, so however
-    requests come and go the step stays one compiled program. A retiring
-    slot needs no cleanup: attention masks every cache position beyond
-    the occupant's frontier to ``finfo.min`` (exactly-zero softmax
-    weight), and a new occupant's prefill + sequential decode writes
-    overwrite every position before it becomes attendable — which is
-    also why the outputs are bit-identical to a solo :func:`generate`
-    call at the same ``max_len`` (tests/test_fleet.py pins it).
+    The caches are allocated once at fixed slot shapes (a slot axis in
+    front of the module's own batch-1 state: ``(slots, 1, kv_heads,
+    max_len, head_dim)`` per attention block), so however requests come
+    and go the step stays one compiled program. A retiring slot needs no
+    cleanup: attention masks every cache position beyond the occupant's
+    frontier to ``finfo.min`` (exactly-zero softmax weight), and a new
+    occupant's prefill + sequential decode writes overwrite every position
+    before it becomes attendable — which is also why the outputs are
+    bit-identical to a solo :func:`generate` call at the same ``max_len``
+    (tests/test_fleet.py pins it). Recurrent state has no frontier to hide
+    behind: a Mamba block starts from zero at position 0 whatever the slot
+    held (``MambaMixer``), which is the same guarantee by other means.
 
     Greedy only: a shared in-flight batch samples per-slot rng streams,
     which would no longer be comparable to any single-request call;
@@ -218,15 +231,11 @@ class SlotDecoder:
         self.module = module
         self.slots = int(slots)
         self.max_len = int(max_len)
-        kv_heads = module.kv_heads or module.heads
-        head_dim = module.dim // module.heads
-        dtype = module.dtype or jnp.float32
-        shape = (self.slots, 1, kv_heads, self.max_len, head_dim)
-        # per block: (K, V), slot-major with each slot a batch-1 cache —
-        # exactly the shape one solo generate(B=1) call sees
-        self.caches = tuple(
-            (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(module.depth))
+        # slot-major, each slot the batch-1 state one solo generate(B=1)
+        # call sees
+        self.caches = jax.tree.map(
+            lambda a: jnp.zeros((self.slots,) + a.shape, a.dtype),
+            init_cache(module, 1, self.max_len))
         self._prefill_fns: dict = {}
         self._step_fn = None
 
@@ -245,18 +254,14 @@ class SlotDecoder:
             module = self.module
 
             def run(variables, caches, prompt, slot):
-                sub = tuple(
-                    (jax.lax.dynamic_index_in_dim(ck, slot, 0,
-                                                  keepdims=False),
-                     jax.lax.dynamic_index_in_dim(cv, slot, 0,
-                                                  keepdims=False))
-                    for ck, cv in caches)
+                sub = jax.tree.map(
+                    lambda c: jax.lax.dynamic_index_in_dim(
+                        c, slot, 0, keepdims=False), caches)
                 logits, sub = module.apply(variables, prompt, caches=sub,
                                            position=0)
-                caches = tuple(
-                    (jax.lax.dynamic_update_index_in_dim(ck, sk, slot, 0),
-                     jax.lax.dynamic_update_index_in_dim(cv, sv, slot, 0))
-                    for (ck, cv), (sk, sv) in zip(caches, sub))
+                caches = jax.tree.map(
+                    lambda c, s: jax.lax.dynamic_update_index_in_dim(
+                        c, s, slot, 0), caches, sub)
                 tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 return caches, tok[0]
 

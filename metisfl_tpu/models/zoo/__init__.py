@@ -3,7 +3,8 @@
 TPU-native counterparts of the reference's example model zoo
 (reference examples/keras/models/*.py, examples/pytorch/models/mlp.py):
 small federated workloads (MLP, CNNs, LSTM) plus the scale-ladder models
-from BASELINE.md (ResNet-20, ViT, BERT, Llama+LoRA).
+from BASELINE.md (ResNet-20, ViT, BERT, Llama+LoRA) and the attention /
+state-space hybrid JambaLite (Mamba mixer + MQA attention, tied head).
 """
 
 from metisfl_tpu.models.zoo.mlp import MLP, HousingMLP
@@ -13,8 +14,10 @@ from metisfl_tpu.models.zoo.rnn import LSTMClassifier
 from metisfl_tpu.models.zoo.transformer import (
     TRANSFORMER_RULES,
     BertLite,
+    JambaLite,
     LlamaLite,
     LoRADense,
+    MambaMixer,
     MoEMLP,
     ViTLite,
 )
@@ -22,6 +25,7 @@ from metisfl_tpu.models.zoo.transformer import (
 __all__ = [
     "MLP", "HousingMLP", "FashionMnistCNN", "Cifar10CNN", "ResNet20",
     "BrainAge3DCNN", "LSTMClassifier",
-    "ViTLite", "BertLite", "LlamaLite", "LoRADense", "MoEMLP",
+    "ViTLite", "BertLite", "LlamaLite", "JambaLite", "MambaMixer",
+    "LoRADense", "MoEMLP",
     "TRANSFORMER_RULES",
 ]

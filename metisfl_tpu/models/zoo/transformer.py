@@ -1,4 +1,5 @@
-"""Transformer family: ViT-lite, BERT-lite, Llama-lite (+LoRA).
+"""Transformer family: ViT-lite, BERT-lite, Llama-lite (+LoRA), and the
+attention/state-space hybrid Jamba-lite.
 
 The BASELINE.md scale ladder (ViT-B/16 semi-sync, BERT async + secure,
 Llama-3-8B-LoRA with in-learner sharding) needs transformer workloads the
@@ -37,6 +38,13 @@ TRANSFORMER_RULES = [
     (r"experts_w2", P("ep", "tp", None)),
     (r"(wq|wk|wv|gate|up|fc1)(/base)?/kernel", P(None, "tp")),
     (r"(wo|down|fc2)(/base)?/kernel", P("tp", None)),
+    # Mamba mixer: d_inner is the sharded axis throughout (column-parallel
+    # in, row-parallel out; every per-channel tensor follows its channels),
+    # so the scan itself needs no collective; x_proj's output (dt, B, C) is
+    # the one all-reduce
+    (r"(in_proj|dt_proj)(/base)?/kernel|mamba/conv_kernel", P(None, "tp")),
+    (r"(out_proj|x_proj)(/base)?/kernel|mamba/A_log", P("tp", None)),
+    (r"mamba/(conv_bias|D)$|dt_proj/bias", P("tp")),
     (r"lora_b", P(None, "tp")),
     (r"embed/embedding", P("tp", None)),
     (r"lm_head/kernel", P(None, "tp")),
@@ -407,8 +415,132 @@ class EncoderBlock(nn.Module):
         return x
 
 
+def _dt_bias_init(key, shape, dtype=jnp.float32, lo: float = 1e-3,
+                  hi: float = 1e-1):
+    """The family's seeding of ``dt_proj``'s bias: softplus(bias) is
+    log-uniform in [lo, hi], so a fresh recurrence neither forgets at once
+    nor never."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                 * float(np.log(hi / lo)) + float(np.log(lo)))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+class MambaMixer(nn.Module):
+    """Mamba-1 mixer as the Jamba family's modelling code has it::
+
+        [x, z] = in_proj(u)                        d -> 2 d_inner, no bias
+        x = silu(conv1d_causal_depthwise(x))       width d_conv, with bias
+        [dt, B, C] = x_proj(x)                     d_inner -> R + 2 N
+        dt, B, C = RMSNorm(dt), RMSNorm(B), RMSNorm(C)
+        delta = softplus(dt_proj(dt))              R -> d_inner, with bias
+        S_t = exp(delta_t (x) A) . S_{t-1} + (delta_t . x_t) (x) B_t
+        y_t = S_t . C_t + D . x_t,   A = -exp(A_log)
+        out = out_proj(y . silu(z))                d_inner -> d, no bias
+
+    ``in_proj`` and ``out_proj`` compute in ``dtype`` (the MXU's); the
+    convolution, ``x_proj``, the three norms, ``dt_proj``, ``delta``,
+    ``exp(delta A)``, the state and the gate are float32 whatever ``dtype``
+    is: the recurrence compounds its rounding over the whole sequence. ``lora_rank`` puts adapters on ``in_proj``
+    and ``out_proj``.
+
+    ``cache`` is ``(conv_state (B, d_inner, d_conv - 1), ssm_state (B,
+    d_inner, N))``, float32: the last inputs of the convolution and the
+    recurrence's state after the positions seen so far. A call at
+    ``position`` 0 starts from zero state whatever the cache held (a slot
+    reused after another occupant needs no cleanup), a call of one token is
+    one step of the recurrence, and either returns the cache after its last
+    position."""
+
+    dim: int
+    d_state: int = 16
+    d_conv: int = 4
+    expand: int = 2
+    dt_rank: int = 0            # 0 -> ceil(dim / 16), the family's default
+    eps: float = 1e-6
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    dtype: Any = None
+    # the scan routes on the backend and the length (ops.selective_scan);
+    # True runs its kernels in Pallas's interpreter instead (CPU tests)
+    scan_interpret: bool = False
+
+    @property
+    def d_inner(self) -> int:
+        return self.expand * self.dim
+
+    def init_cache(self, batch: int):
+        return (jnp.zeros((batch, self.d_inner, self.d_conv - 1),
+                          jnp.float32),
+                jnp.zeros((batch, self.d_inner, self.d_state), jnp.float32))
+
+    @nn.compact
+    def __call__(self, u, cache=None, position=None):
+        from metisfl_tpu.ops import selective_scan as scan_ops
+        f32 = jnp.float32
+        B, L, _ = u.shape
+        d_inner, N, K = self.d_inner, self.d_state, self.d_conv
+        R = self.dt_rank or -(-self.dim // 16)
+        dt_ = self.dtype or f32
+
+        xz = LoRADense(2 * d_inner, rank=self.lora_rank,
+                       alpha=self.lora_alpha, use_bias=False,
+                       dtype=self.dtype, name="in_proj")(u)
+        x, z = xz[..., :d_inner].astype(f32), xz[..., d_inner:].astype(f32)
+
+        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                            (K, d_inner))
+        conv_b = self.param("conv_bias", nn.initializers.zeros, (d_inner,))
+        if cache is None:
+            past = jnp.zeros((B, K - 1, d_inner), f32)
+        else:
+            # a call at position 0 opens a sequence: nothing came before
+            live = (jnp.asarray(position, jnp.int32) != 0).astype(f32)
+            past = jnp.swapaxes(cache[0], 1, 2) * live
+            state = cache[1] * live
+        window = jnp.concatenate([past, x], axis=1)       # (B, K-1+L, D)
+        x = conv_b + sum(window[:, k:k + L] * conv_w[k] for k in range(K))
+        x = nn.silu(x)
+
+        # the two thin projections (0.4% of a block's FLOPs) stay float32
+        # at ``highest``: their outputs steer a recurrence that compounds
+        # their rounding. With bfloat16 operands the round's worst-leaf
+        # ``change_gap`` read 0.00113 and 0.00238 on two seeds, in float32
+        # 0.00069 and 0.00164 (PERF.md section 6, PR 27)
+        thin = dict(dtype=f32, precision=jax.lax.Precision.HIGHEST)
+        dbc = nn.Dense(R + 2 * N, use_bias=False, name="x_proj", **thin)(x)
+        norm = lambda v, name: nn.RMSNorm(                   # noqa: E731
+            epsilon=self.eps, dtype=f32, name=name)(v)
+        dt = norm(dbc[..., :R], "dt_norm")
+        b = norm(dbc[..., R:R + N], "b_norm")
+        c = norm(dbc[..., R + N:], "c_norm")
+        delta = jax.nn.softplus(nn.Dense(
+            d_inner, bias_init=_dt_bias_init, name="dt_proj", **thin)(dt))
+        a = -jnp.exp(self.param(
+            "A_log", lambda key, shape: jnp.log(jnp.broadcast_to(
+                jnp.arange(1, N + 1, dtype=f32), shape)), (d_inner, N)))
+        skip = self.param("D", nn.initializers.ones, (d_inner,))
+
+        if cache is None:
+            y = scan_ops.selective_scan(x, delta, a, b, c,
+                                        interpret=self.scan_interpret)
+        elif L == 1:
+            y, state = scan_ops.scan_step(state, x[:, 0], delta[:, 0], a,
+                                          b[:, 0], c[:, 0])
+            y = y[:, None]
+        else:
+            y, state = scan_ops.scan_chunked(x, delta, a, b, c, state=state)
+        y = (y + skip * x) * nn.silu(z)
+        out = LoRADense(self.dim, rank=self.lora_rank, alpha=self.lora_alpha,
+                        use_bias=False, dtype=self.dtype,
+                        name="out_proj")(y.astype(dt_))
+        if cache is None:
+            return out
+        return out, (jnp.swapaxes(window[:, L:], 1, 2), state)
+
+
 class DecoderBlock(nn.Module):
-    """Pre-RMSNorm causal block (Llama style) with rotary + SwiGLU."""
+    """Pre-RMSNorm causal block: a mixer (attention, Llama style with
+    rotary by default, or the Mamba mixer) and a SwiGLU or MoE FFN."""
 
     dim: int
     heads: int
@@ -423,31 +555,45 @@ class DecoderBlock(nn.Module):
     moe_top_k: int = 1          # experts per token (1 = Switch, 2 = GShard)
     dtype: Any = None
     kv_heads: int = 0           # grouped-query attention; 0 = MHA
+    ffn_dim: int = 0            # FFN width; 0 = mlp_ratio x dim
+    eps: float = 1e-6           # RMSNorm epsilon
+    rotary: bool = True         # the attention mixer's position embedding
+    # a MambaMixer (unbound, as the model builds it) in the attention
+    # mixer's place; flax adopts it under the field's name, "mamba"
+    mamba: Any = None
 
     @nn.compact
     def __call__(self, x, train: bool = False, cache=None, position=None):
-        attn = Attention(self.dim, self.heads, causal=True, rotary=True,
-                         lora_rank=self.lora_rank, sp_mesh=self.sp_mesh,
-                         sp_strategy=self.sp_strategy,
-                         sp_block_kernels=self.sp_block_kernels,
-                         use_flash=self.use_flash, dtype=self.dtype,
-                         kv_heads=self.kv_heads,
-                         name="attn")
-        normed = nn.RMSNorm(dtype=self.dtype)(x)
-        if cache is not None:
-            a, cache = attn(normed, train=train, cache=cache,
-                            position=position)
+        normed = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype)(x)
+        if self.mamba is not None:
+            with jax.named_scope("mamba_mixer"):
+                a = (self.mamba(normed) if cache is None
+                     else self.mamba(normed, cache=cache, position=position))
         else:
-            a = attn(normed, train=train)
+            attn = Attention(self.dim, self.heads, causal=True,
+                             rotary=self.rotary,
+                             lora_rank=self.lora_rank, sp_mesh=self.sp_mesh,
+                             sp_strategy=self.sp_strategy,
+                             sp_block_kernels=self.sp_block_kernels,
+                             use_flash=self.use_flash, dtype=self.dtype,
+                             kv_heads=self.kv_heads,
+                             name="attn")
+            with jax.named_scope("attention_mixer"):
+                a = (attn(normed, train=train) if cache is None
+                     else attn(normed, train=train, cache=cache,
+                               position=position))
+        if cache is not None:
+            a, cache = a
         x = x + a
+        hidden = self.ffn_dim or self.mlp_ratio * self.dim
         if self.moe_experts > 0:
-            ffn = MoEMLP(self.dim, self.mlp_ratio * self.dim,
+            ffn = MoEMLP(self.dim, hidden,
                          num_experts=self.moe_experts, top_k=self.moe_top_k,
                          dtype=self.dtype, name="moe")
         else:
-            ffn = SwiGLU(self.dim, self.mlp_ratio * self.dim,
-                         dtype=self.dtype, name="mlp")
-        x = x + ffn(nn.RMSNorm(dtype=self.dtype)(x))
+            ffn = SwiGLU(self.dim, hidden, dtype=self.dtype, name="mlp")
+        with jax.named_scope("mlp"):
+            x = x + ffn(nn.RMSNorm(epsilon=self.eps, dtype=self.dtype)(x))
         return x if cache is None else (x, cache)
 
 
@@ -580,3 +726,107 @@ class LlamaLite(nn.Module):
         logits = nn.Dense(self.vocab_size, use_bias=False,
                           name="lm_head")(x.astype(jnp.float32))
         return logits if caches is None else (logits, tuple(new_caches))
+
+    def init_cache(self, batch: int, max_len: int):
+        """Zeroed decode state, one entry a block: ``(K, V)``, each
+        (batch, kv_heads, max_len, head_dim) in the compute dtype."""
+        return tuple(_kv_cache(batch, self.kv_heads or self.heads, max_len,
+                               self.dim // self.heads, self.dtype)
+                     for _ in range(self.depth))
+
+    def cache_kinds(self):
+        """What each block's cache entry is: ``"kv"`` (grows with the
+        sequence, masked past the frontier) or ``"state"`` (fixed size,
+        overwritten every position)."""
+        return ("kv",) * self.depth
+
+
+def _kv_cache(batch: int, kv_heads: int, max_len: int, head_dim: int, dtype):
+    shape = (batch, kv_heads, max_len, head_dim)
+    dtype = dtype or jnp.float32
+    return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+
+class JambaLite(nn.Module):
+    """Decoder-only hybrid of the Jamba family: block ``l`` mixes by
+    attention where ``(l - attn_offset) % attn_period == 0`` and by the
+    Mamba mixer elsewhere; every block has a dense SwiGLU FFN (the
+    family's one-expert case). Attention is causal GQA/MQA with no rotary
+    and no position embedding of any kind (the recurrence carries order);
+    the head is tied to the embedding. ``lora_rank > 0`` adds adapters on
+    ``in_proj``/``out_proj`` (Mamba) and ``wq``/``wv`` (attention); train
+    with ``FlaxModelOps(trainable_regex="lora_")`` to freeze the base.
+
+    Decoding: ``init_cache`` gives ``(K, V)`` for an attention block and
+    ``(conv_state, ssm_state)`` for a Mamba block; ``models.generate`` and
+    ``serving.decode`` carry either as a pytree."""
+
+    vocab_size: int = 8192
+    dim: int = 64
+    depth: int = 4
+    heads: int = 4
+    kv_heads: int = 0
+    ffn_dim: int = 0            # 0 = 4 x dim
+    attn_period: int = 2
+    attn_offset: int = 1
+    d_state: int = 16
+    d_conv: int = 4
+    expand: int = 2
+    dt_rank: int = 0            # 0 = ceil(dim / 16)
+    eps: float = 1e-6
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    use_flash: Any = False
+    remat: bool = False
+    dtype: Any = None
+    scan_interpret: bool = False    # see MambaMixer
+
+    def is_attention(self, layer: int) -> bool:
+        return (layer - self.attn_offset) % self.attn_period == 0
+
+    def _mamba(self) -> MambaMixer:
+        return MambaMixer(self.dim, d_state=self.d_state, d_conv=self.d_conv,
+                          expand=self.expand, dt_rank=self.dt_rank,
+                          eps=self.eps, lora_rank=self.lora_rank,
+                          lora_alpha=self.lora_alpha, dtype=self.dtype,
+                          scan_interpret=self.scan_interpret,
+                          parent=None)      # the block adopts it
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, caches=None,
+                 position=None):
+        embed = nn.Embed(self.vocab_size, self.dim, dtype=self.dtype,
+                         name="embed")
+        x = embed(tokens)
+        block_cls = (nn.remat(DecoderBlock, static_argnums=(2,))
+                     if self.remat and caches is None else DecoderBlock)
+        new_caches = []
+        for i in range(self.depth):
+            block = block_cls(
+                self.dim, self.heads, lora_rank=self.lora_rank,
+                use_flash=self.use_flash, dtype=self.dtype,
+                kv_heads=self.kv_heads, ffn_dim=self.ffn_dim, eps=self.eps,
+                rotary=False,
+                mamba=None if self.is_attention(i) else self._mamba(),
+                name=f"block_{i}")
+            if caches is not None:
+                x, c = block(x, train, cache=caches[i], position=position)
+                new_caches.append(c)
+            else:
+                x = block(x, train)
+        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype)(x)
+        # tied head, logits in fp32 as LlamaLite's
+        logits = jnp.dot(x.astype(jnp.float32),
+                         embed.embedding.astype(jnp.float32).T)
+        return logits if caches is None else (logits, tuple(new_caches))
+
+    def init_cache(self, batch: int, max_len: int):
+        return tuple(
+            _kv_cache(batch, self.kv_heads or self.heads, max_len,
+                      self.dim // self.heads, self.dtype)
+            if self.is_attention(i) else self._mamba().init_cache(batch)
+            for i in range(self.depth))
+
+    def cache_kinds(self):
+        return tuple("kv" if self.is_attention(i) else "state"
+                     for i in range(self.depth))

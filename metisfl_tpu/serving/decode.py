@@ -36,7 +36,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from metisfl_tpu import telemetry as _tel
-from metisfl_tpu.models.generate import SlotDecoder
+from metisfl_tpu.models.generate import SlotDecoder, cache_bytes_by_kind
 from metisfl_tpu.telemetry import metrics as _tmetrics
 from metisfl_tpu.telemetry import prof as _prof
 from metisfl_tpu.telemetry import trace as _ttrace
@@ -60,6 +60,12 @@ _M_DECODE_TPS = _REG.gauge(
     _tel.M_SERVING_DECODE_TOKENS_PER_SEC,
     "EWMA decode throughput (tokens/s across all active slots), per "
     "channel", ("channel",))
+
+_M_DECODE_CACHE = _REG.gauge(
+    _tel.M_SERVING_DECODE_CACHE_BYTES,
+    "Device bytes the decode slots' state holds, per channel and kind "
+    "(kv: keys and values, grows with max_len; state: recurrent state of "
+    "state-space blocks, fixed)", ("channel", "kind"))
 
 PAD_ID = 0
 
@@ -129,15 +135,19 @@ class ContinuousBatcher:
         self.max_len = int(max_len)
         module = model_ops.module
         if not all(hasattr(module, a)
-                   for a in ("heads", "dim", "depth", "kv_heads")):
+                   for a in ("init_cache", "cache_kinds")):
             # fail with the real story, not an AttributeError from deep
             # inside cache allocation, when the federation's model is a
             # classifier rather than a causal LM
             raise TypeError(
-                "serving decode needs a KV-cache causal-LM module "
-                "(the models.zoo LlamaLite family); "
-                f"{type(module).__name__} has no cache geometry")
+                "serving decode needs a causal-LM module that lays out "
+                "its own decode state (models.zoo LlamaLite, JambaLite); "
+                f"{type(module).__name__} has no init_cache")
         self._decoder = SlotDecoder(module, self.slots, self.max_len)
+        self._cache_bytes = cache_bytes_by_kind(module,
+                                                self._decoder.caches)
+        for kind, nbytes in self._cache_bytes.items():
+            _M_DECODE_CACHE.set(nbytes, channel=channel, kind=kind)
         self._pair = (int(version), variables)
         self._pending_pair: Optional[tuple] = None
         self._queue: deque = deque()
@@ -383,6 +393,8 @@ class ContinuousBatcher:
                     "tokens_per_sec": round(self._tps_ewma, 3),
                     "version": self._pair[0],
                     "swap_pending": self._pending_pair is not None,
+                    # the slots' device state by kind (kv, state)
+                    "cache_bytes": dict(self._cache_bytes),
                     # the loop's account of its own time, as totals
                     "loop": {k: round(v, 6)
                              for k, v in self._sums.items()}}
@@ -397,3 +409,5 @@ class ContinuousBatcher:
         _M_DECODE_QUEUE.remove(channel=self.channel)
         _M_DECODE_SLOTS.remove(channel=self.channel)
         _M_DECODE_TPS.remove(channel=self.channel)
+        for kind in self._cache_bytes:
+            _M_DECODE_CACHE.remove(channel=self.channel, kind=kind)

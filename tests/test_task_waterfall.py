@@ -307,7 +307,8 @@ def test_decode_slot_and_loop_account_for_their_time(span_ring,
         assert set(before) == {
             "slots", "max_len", "queued", "active", "steps",
             "tokens_emitted", "tokens_per_sec", "version", "swap_pending",
-            "loop"}
+            "cache_bytes", "loop"}
+        assert set(before["cache_bytes"]) == {"kv"}     # an all-attention LM
         assert set(before["loop"]) == set(decode.LOOP_SUMS)
         # a root span stands in for the gateway's serving.generate: the
         # slot event parents on the submitter's context
