@@ -31,10 +31,12 @@ carries that state as a pytree and looks inside none of it.
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any, Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.extend import core as jex_core
 
 from metisfl_tpu.telemetry import runtime as _runtime
 
@@ -187,6 +189,120 @@ _COMPILED: dict = {}
 _COMPILED_MAX = 32
 
 
+# --------------------------------------------------------------------- #
+# which leaves a served tree may hold in the compute type
+# --------------------------------------------------------------------- #
+
+# primitives that merely call an inner jaxpr with their own inputs, in
+# their own order, and the parameter that holds it. Anything with loop,
+# branch or custom-rule semantics (scan, while, cond, custom_vjp_call, a
+# Pallas call) is not among them: a leaf that enters one stays as it is.
+_CALLS = {"jit": "jaxpr", "pjit": "jaxpr", "remat2": "jaxpr",
+          "checkpoint": "jaxpr", "closed_call": "call_jaxpr",
+          "core_call": "call_jaxpr"}
+
+_TRACED_TOKENS = 4      # any count > 1: the module sees a prompt
+
+
+def _consumers(jaxpr, tables: dict) -> dict:
+    """variable -> [(equation, operand index)] of ``jaxpr``; an output of
+    the jaxpr counts as a consumer with no equation."""
+    table = tables.get(id(jaxpr))
+    if table is None:
+        table = tables[id(jaxpr)] = {}
+        for eqn in jaxpr.eqns:
+            for i, var in enumerate(eqn.invars):
+                if isinstance(var, jex_core.Var):     # not a literal
+                    table.setdefault(var, []).append((eqn, i))
+        for var in jaxpr.outvars:
+            if isinstance(var, jex_core.Var):
+                table.setdefault(var, []).append((None, 0))
+    return table
+
+
+def _converted_to(jaxpr, var, tables: dict) -> Optional[set]:
+    """The types ``var`` is converted to where every consumer of it in
+    ``jaxpr`` is a ``convert_element_type`` (followed through ``_CALLS``);
+    None where anything else consumes it. Empty: nothing consumes it."""
+    found: set = set()
+    for eqn, i in _consumers(jaxpr, tables).get(var, ()):
+        name = eqn.primitive.name if eqn is not None else ""
+        if name == "convert_element_type":
+            found.add(np.dtype(eqn.params["new_dtype"]))
+            continue
+        if name not in _CALLS:
+            return None
+        inner = eqn.params[_CALLS[name]]
+        inner = getattr(inner, "jaxpr", inner)        # a ClosedJaxpr's own
+        if len(inner.invars) != len(eqn.invars):
+            return None
+        below = _converted_to(inner, inner.invars[i], tables)
+        if below is None:
+            return None
+        found |= below
+    return found
+
+
+def cast_once_dtypes(program: Callable, variables: Pytree,
+                     *args) -> List[Optional[np.dtype]]:
+    """For each leaf of ``variables`` (in ``jax.tree.leaves`` order) the
+    type a server of ``program(variables, *args)`` may hold it in, or None
+    where it has to stay as it is.
+
+    A module that computes in bfloat16 over float32 parameters converts a
+    parameter on every call (``nn.Dense(dtype=...)``, ``nn.Embed``,
+    ``LoRADense``). While the weights do not change, the converted values
+    do not either, so whoever serves them can convert once and hand the
+    program the result: its own convert is then the identity and every
+    product sees the operands it saw before, to the last bit. That holds
+    only for a leaf the program uses through that convert and nothing else
+    (``lm_head`` multiplies in float32, ``JambaLite`` transposes its tied
+    embedding in float32, a norm reshapes its ``scale`` first), so the
+    answer is read from the program and not from a name: ONE abstract
+    trace (``jax.make_jaxpr`` over shapes; nothing runs, nothing is
+    placed), in which a leaf is named iff something consumes it, it is no
+    output, and every consumer is a ``convert_element_type`` to one and the
+    same floating type narrower than its own. ``variables`` and ``args``
+    may be arrays or ``jax.ShapeDtypeStruct``s.
+    """
+    struct = lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype)  # noqa: E731
+    spec, args = jax.tree.map(struct, (variables, args))
+    leaves = jax.tree.leaves(spec)
+    jaxpr = jax.make_jaxpr(program)(spec, *args).jaxpr
+    tables: dict = {}
+    out: List[Optional[np.dtype]] = []
+    for leaf, var in zip(leaves, jaxpr.invars):
+        # None (consumed otherwise) and empty (not consumed) both keep it
+        types = _converted_to(jaxpr, var, tables) or set()
+        dtype = types.pop() if len(types) == 1 else None
+        narrower = (dtype is not None
+                    and jnp.issubdtype(leaf.dtype, jnp.floating)
+                    and jnp.issubdtype(dtype, jnp.floating)
+                    and dtype.itemsize < np.dtype(leaf.dtype).itemsize)
+        out.append(dtype if narrower else None)
+    return out
+
+
+def decode_call(module) -> Tuple[Callable, tuple]:
+    """``(program, args)`` of the one call every decode program of
+    ``module`` is: ``module.apply(v, tokens, caches=caches,
+    position=position)`` over the state the module lays out itself.
+    :func:`generate`'s and :class:`SlotDecoder`'s prefill are this call at
+    a prompt's length and position 0, their step at one token and a traced
+    position; what the call does with a parameter depends on neither, so
+    :func:`cast_once_dtypes` reads all of them from this one, at
+    ``_TRACED_TOKENS`` tokens and a traced position (tests/
+    test_serving_cast.py holds the four choices equal leaf for leaf)."""
+    def program(variables, tokens, caches, position):
+        return module.apply(variables, tokens, caches=caches,
+                            position=position)
+
+    caches = jax.eval_shape(
+        lambda: init_cache(module, 1, 2 * _TRACED_TOKENS))
+    return program, (jax.ShapeDtypeStruct((1, _TRACED_TOKENS), jnp.int32),
+                     caches, jax.ShapeDtypeStruct((), jnp.int32))
+
+
 class SlotDecoder:
     """Fixed-slot KV-cache decode programs for continuous batching (Orca,
     Yu et al. OSDI 2022 — iteration-level scheduling over an in-flight
@@ -218,6 +334,18 @@ class SlotDecoder:
     (tests/test_fleet.py pins it). Recurrent state has no frontier to hide
     behind: a Mamba block starts from zero at position 0 whatever the slot
     held (``MambaMixer``), which is the same guarantee by other means.
+
+    ``variables`` is whatever tree the caller serves. The gateway hands
+    in the module's own casts made once (serving/gateway.py
+    ``_load_variables``): a leaf that prefill and step use only through a
+    ``convert_element_type`` to the compute type arrives already in it, so
+    both programs convert nothing and read half the weight bytes a token;
+    a leaf they use in float32 (``lm_head``, norm scales, ``JambaLite``'s
+    tied embedding, thin projections, recurrence parameters and
+    convolution) arrives in float32. Tokens are those of the float32 tree
+    to the last bit (:func:`cast_once_dtypes`, read from
+    :func:`decode_call` by one abstract trace a gateway; one cast program
+    an install).
 
     Greedy only: a shared in-flight batch samples per-slot rng streams,
     which would no longer be comparable to any single-request call;

@@ -153,6 +153,11 @@ class FlaxModelOps:
         self.partition_rules = list(partition_rules or [])
         self._trainable_regex = trainable_regex
         self.variables_epoch = 0
+        # shape and type of an input, for whoever traces the forward
+        # without running it (serving/gateway.py)
+        self.sample_spec = jax.ShapeDtypeStruct(
+            np.shape(sample_input),
+            jax.dtypes.canonicalize_dtype(np.result_type(sample_input)))
         if variables is not None:
             self.variables = variables
         else:

@@ -120,7 +120,9 @@ class ContinuousBatcher:
 
     ``model_ops`` supplies the flax module (the gateway's engine);
     ``(version, variables)`` is the channel's installed pair at
-    construction. One worker thread owns the decode loop: each
+    construction: the tree as the gateway holds it, cast once at install
+    to what the programs use (serving/gateway.py ``_load_variables``).
+    One worker thread owns the decode loop: each
     iteration admits queued prompts into free slots (prefill), then
     advances every active slot one token through the single jitted step
     program. Per-request ``max_new_tokens`` retire sequences

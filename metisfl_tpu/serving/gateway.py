@@ -23,6 +23,7 @@ Design notes:
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -228,6 +229,33 @@ class MicroBatcher:
 
 
 # --------------------------------------------------------------------- #
+# the install's one cast program
+# --------------------------------------------------------------------- #
+
+@functools.lru_cache(maxsize=None)
+def _cast_program():
+    import jax
+
+    return jax.jit(
+        lambda arrays, types: [a.astype(t) for a, t in zip(arrays, types)],
+        static_argnums=1)
+
+
+def _cast(leaves: list, dtypes: list) -> list:
+    """``leaves`` (device arrays) with each one that ``dtypes`` names held
+    in that type instead: ONE jitted call over all of them, not awaited.
+    Its float32 inputs are referred to from here alone, so they leave the
+    device as the program ends."""
+    chosen = [i for i, dtype in enumerate(dtypes) if dtype is not None]
+    if chosen:
+        narrow = _cast_program()([leaves[i] for i in chosen],
+                                 tuple(dtypes[i] for i in chosen))
+        for i, arr in zip(chosen, narrow):
+            leaves[i] = arr
+    return leaves
+
+
+# --------------------------------------------------------------------- #
 # registry sources (where the gateway learns about promoted versions)
 # --------------------------------------------------------------------- #
 
@@ -274,6 +302,17 @@ class ServingGateway:
         # channel -> (version id, variables pytree)
         self._models: Dict[str, Tuple[int, Any]] = {}
         self._treedef_like = model_ops.get_variables()
+        # per leaf the type this gateway holds it in, or None for the
+        # engine's own (``_cast_dtypes``): read from the served program by
+        # one abstract trace at the first install. Installs and the check
+        # of a decoding module's plain forward (``_check_forward``) take
+        # turns under ``_install_lock``; until that check a channel's blob
+        # is kept (on the host, whole), so that a leaf can be had back in
+        # the engine's type
+        self._cast_once: Optional[list] = None
+        self._forward_checked = False
+        self._install_lock = threading.Lock()
+        self._blobs: Dict[str, Tuple[int, bytes]] = {}
         self._batchers: Dict[str, MicroBatcher] = {}
         # continuous-batching decode engines (serving/decode.py), one per
         # channel, created lazily on the first Generate for that channel
@@ -295,12 +334,87 @@ class ServingGateway:
 
     # -- model install / hot-swap ------------------------------------- #
 
+    def _traced_dtypes(self, decode: bool) -> list:
+        """ONE abstract trace of a program this gateway serves (the decode
+        call, or the engine's plain forward) and what it lets each leaf be
+        held in (``models.generate.cast_once_dtypes``)."""
+        from metisfl_tpu.models.generate import (cast_once_dtypes,
+                                                 decode_call)
+        ops = self.model_ops
+        if decode:
+            program, args = decode_call(ops.module)
+        else:
+            def program(variables, x):
+                return ops._apply(variables, x, train=False)
+            args = (ops.sample_spec,)
+        return cast_once_dtypes(program, self._treedef_like, *args)
+
+    def _cast_dtypes(self) -> list:
+        """Which leaves this gateway serves in the module's compute type.
+        It depends on the module alone, so it is read once, at the first
+        install, and a hot-swap pays no trace. A module that lays out a
+        decode state (``init_cache``) is read from its decode call, which
+        prefill and step both are; its plain forward (``predict`` on an
+        LM) is read when the first ``predict`` asks for it
+        (``_check_forward``). Any other module is served through its
+        forward alone, and that is the trace."""
+        if self._cast_once is None:
+            decodes = hasattr(self.model_ops.module, "init_cache")
+            self._cast_once = self._traced_dtypes(decode=decodes)
+            self._forward_checked = not decodes
+        return self._cast_once
+
+    def _check_forward(self) -> None:
+        """Before the first ``predict`` of a decoding module is answered:
+        trace its plain forward too. A leaf the decode call let the
+        gateway cast and the forward uses otherwise (or casts to another
+        type) goes back to the engine's type, and every channel is
+        installed again from the blob kept for this. Where the two agree,
+        as they do for both LMs of the zoo, nothing happens."""
+        if self._forward_checked:
+            return
+        with self._install_lock:
+            if self._forward_checked:
+                return
+            forward = self._traced_dtypes(decode=False)
+            agreed = [dt if dt == fw else None
+                      for dt, fw in zip(self._cast_dtypes(), forward)]
+            if agreed != self._cast_once:
+                self._cast_once = agreed
+                for channel, (version, blob) in list(self._blobs.items()):
+                    self._install_locked(channel, version, blob)
+            self._forward_checked = True
+            self._blobs.clear()
+
     def _load_variables(self, blob_bytes: bytes):
-        """Community blob -> engine-dtype variables. Under
-        ship_tensor_regex the blob carries only the federated subset —
+        """Community blob -> the variables to serve, on the device. Under
+        ship_tensor_regex the blob carries only the federated subset:
         backfill the frozen base from the construction-time tree (the
-        learner's _merge_frozen contract)."""
+        learner's _merge_frozen contract), then conform every leaf to the
+        engine's type.
+
+        The tree that is served is the module's own casts made once. A
+        module that computes in bfloat16 over float32 parameters converts
+        each kernel on every call; between installs the weights do not
+        change, so the gateway makes that conversion here, on the device,
+        for exactly the leaves the served programs use through it and
+        nothing else (``_cast_dtypes``), and holds the result in place of
+        the float32 leaf. The programs' converts are then the identity:
+        every product sees the operands it saw before, each token reads
+        half the weight bytes, and replies are those of the float32 tree
+        to the last bit. A leaf a program uses in float32 stays float32
+        (``lm_head`` multiplies the logits in float32 by design; norm
+        scales; ``JambaLite``'s tied embedding, its mixers' thin
+        projections, recurrence parameters and convolution); a module that
+        computes in float32 has no such leaf and is served as before.
+
+        What it costs: the tree is placed first (host -> device copies
+        that need no GIL), the one trace of a gateway's life runs beneath
+        them, then the chosen leaves are cast (``_cast``) and nothing is
+        awaited: a request that arrives first waits on the device, not
+        here."""
         import jax
+        import jax.numpy as jnp
 
         named = list(ModelBlob.from_bytes(blob_bytes).tensors)
         if self._ship_regex:
@@ -318,24 +432,59 @@ class ServingGateway:
         # device-convert ONCE at install: the engine's per-call
         # `jnp.asarray` then no-ops, instead of re-uploading the whole
         # model host->device on every executed micro-batch
-        import jax.numpy as jnp
-        return jax.tree.map(jnp.asarray, tree)
+        leaves, treedef = jax.tree.flatten(tree)
+        placed = [jnp.asarray(leaf) for leaf in leaves]   # copies fly
+        dtypes = self._cast_dtypes()          # ... beneath the trace
+        return jax.tree.unflatten(treedef, _cast(placed, dtypes))
+
+    def _held(self, variables) -> Dict[str, int]:
+        """What a served tree holds: bytes and leaves in the module's
+        compute type (cast once at install, ``_load_variables``) and kept
+        in the engine's."""
+        import jax
+
+        held = dict.fromkeys(("cast_bytes", "kept_bytes", "cast_leaves",
+                              "kept_leaves"), 0)
+        for arr, like in zip(jax.tree.leaves(variables),
+                             jax.tree.leaves(self._treedef_like)):
+            kind = "kept" if arr.dtype == like.dtype else "cast"
+            held[kind + "_bytes"] += int(arr.nbytes)
+            held[kind + "_leaves"] += 1
+        return held
 
     def install(self, channel: str, version: int, blob: bytes) -> None:
-        """Atomically hot-swap ``channel`` to ``version``. Decoding (the
-        slow part) happens OUTSIDE the lock; in-flight batches keep the
-        pair they already captured, so zero requests drop across the
-        swap."""
-        variables = self._load_variables(blob)
-        with self._lock:
-            previous = self._models.get(channel, (0, None))[0]
-            self._models[channel] = (int(version), variables)
-            decoder = self._decoders.get(channel)
-        if decoder is not None:
-            # the decode loop's zero-drop swap: in-flight generations
-            # finish on the pair they captured, queued ones drain onto
-            # this one (serving/decode.py)
-            decoder.swap(int(version), variables)
+        """Atomically hot-swap ``channel`` to ``version``. Decoding,
+        placing and the cast to the served types (the slow part) happen
+        OUTSIDE the gateway's lock; in-flight batches keep the pair they
+        already captured, so zero requests drop across the swap.
+
+        Span ``serving.install`` runs from the blob in hand to the swap;
+        its attrs and ``describe()["weights"][channel]`` say what the
+        channel then holds (``_held``). An install costs one placement of
+        the tree and one cast program; a gateway's first also the one
+        abstract trace that chooses the leaves (``_cast_dtypes``)."""
+        with self._install_lock:
+            self._install_locked(channel, version, blob)
+
+    def _install_locked(self, channel: str, version: int,
+                        blob: bytes) -> None:
+        with _ttrace.span("serving.install",
+                          attrs={"channel": channel,
+                                 "version": int(version)}) as sp:
+            variables = self._load_variables(blob)
+            for key, value in self._held(variables).items():
+                sp.set_attr(key, value)
+            with self._lock:
+                previous = self._models.get(channel, (0, None))[0]
+                self._models[channel] = (int(version), variables)
+                decoder = self._decoders.get(channel)
+                if not self._forward_checked:
+                    self._blobs[channel] = (int(version), blob)
+            if decoder is not None:
+                # the decode loop's zero-drop swap: in-flight generations
+                # finish on the pair they captured, queued ones drain onto
+                # this one (serving/decode.py)
+                decoder.swap(int(version), variables)
         _M_VERSION.set(int(version), channel=channel)
         if previous != version:
             _M_SWAPS.inc(channel=channel)
@@ -345,9 +494,12 @@ class ServingGateway:
                         channel, version, previous)
 
     def uninstall(self, channel: str) -> None:
-        with self._lock:
+        # after any install under way: ``_check_forward`` must not bring
+        # a channel back from a blob that was on its way out
+        with self._install_lock, self._lock:
             gone = self._models.pop(channel, None)
             decoder = self._decoders.pop(channel, None)
+            self._blobs.pop(channel, None)
         if decoder is not None:
             # drain: queued/in-flight generations on the departing
             # channel still finish on their captured pair
@@ -478,6 +630,7 @@ class ServingGateway:
         if entry is None:
             raise RuntimeError("no model installed (registry has no "
                                "stable version yet)")
+        self._check_forward()
         # the batcher worker runs on its own thread: the span brackets
         # submit→result on THIS thread, which is the request's true wait
         with _ttrace.span("serving.predict", attrs={"channel": channel}):
@@ -567,11 +720,14 @@ class ServingGateway:
 
     def describe(self) -> Dict[str, Any]:
         with self._lock:
-            installed = {ch: v for ch, (v, _) in self._models.items()}
+            models = dict(self._models)
             requests = self._requests
             decoders = dict(self._decoders)
         out = {
-            "installed": installed,
+            "installed": {ch: v for ch, (v, _) in models.items()},
+            # per channel: bytes and leaves held in the module's compute
+            # type (cast once at install) and kept in the engine's
+            "weights": {ch: self._held(v) for ch, (_, v) in models.items()},
             "canary_percent": float(self.config.canary_percent),
             "max_batch": int(self.config.max_batch),
             "max_wait_ms": float(self.config.max_wait_ms),
