@@ -58,8 +58,8 @@ def is_host_tree(tree) -> bool:
     Fold locale policy: models that arrived over the wire (gRPC transport)
     are host numpy and fold on host BLAS — FedAvg is a ~1 FLOP/byte streaming
     op, so shipping N models over PCIe to reduce them on the device
-    wastes exactly the bandwidth the reference's north star budgets
-    (BASELINE.md ≤2 s @ 64 learners). Device-resident trees (co-located
+    wastes exactly the bandwidth the seed's target budgets (BASELINE.md:
+    ≤2 s a round at 64 learners). Device-resident trees (co-located
     learner output, pod mode) fold on device; cross-learner pod aggregation
     is the psum in :mod:`metisfl_tpu.parallel.collectives`."""
     leaves = jax.tree.leaves(tree)
@@ -194,7 +194,8 @@ def _native_fold(a, arrs, scales):
 
     Streams each model once with no staging copy (the numpy path pays a
     full ``np.stack`` pass before its GEMV) — this is the controller's
-    cross-host aggregation hot loop (BASELINE.md headline metric)."""
+    cross-host aggregation hot loop (``fold_ms`` of the benchmark's round
+    cells)."""
     import ctypes
 
     lib = _get_hostfold()

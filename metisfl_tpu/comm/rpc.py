@@ -543,8 +543,8 @@ class RpcClient:
         only by the FINAL outcome: a unary attempt refused oversize
         retries transparently over the chunked stream, and the wrapper
         stays pending until that retry settles — the caller never sees a
-        failure for a call that then succeeds (the ADVICE r5 double
-        signal). Callbacks fire exactly once either way."""
+        failure for a call that then succeeds (a double signal).
+        Callbacks fire exactly once either way."""
         # capture the span context HERE, on the caller's thread: grpc
         # completion callbacks and the stream pool run in their own
         # (empty) contextvars contexts, so an oversize retry issued from

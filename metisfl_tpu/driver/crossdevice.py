@@ -11,7 +11,7 @@ telemetry) and replaces each learner with a virtual client: a seeded
 softmax-regression shard trained with plain numpy in a small worker
 pool. A 1024-client federation under 30% per-round dropout runs in
 seconds with bounded RSS, which is what lets churn tolerance sit in
-tier-1 CI (``scripts/chaos_smoke.sh``) next to the bench gate.
+tier-1 CI (``scripts/chaos_smoke.sh``).
 
 Fault model per dispatched task (all draws from the scenario seed):
 

@@ -941,7 +941,7 @@ class Learner:
         with eval_sp, eval_sp.activate():
             self._check_controller_epoch(task.controller_epoch)
             self._adopt_local_regex(task.local_tensor_regex)
-            # Unconditional, mirroring the train path (ADVICE r5):
+            # Unconditional, mirroring the train path:
             # never-trained learners get the regex from the task (backfill
             # reads the immutable construction tree — no snapshot needed),
             # and a task WITHOUT one clears any stale regex from an

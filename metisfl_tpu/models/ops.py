@@ -236,8 +236,8 @@ class FlaxModelOps:
         dense-layer approximation 6·params·batch (2 forward + 4 backward
         matmul FLOPs per parameter per example). The MFU numerator for
         the performance observatory's achieved-utilization gauge —
-        an estimate, like bench.py's analytic ``_lm_step_flops``, not an
-        XLA cost-model readout."""
+        an estimate per example, not per token, and not an XLA cost-model
+        readout (the benchmark counts in ``benchmark/lib/flops.py``)."""
         return 6.0 * self.param_count() * max(1, int(batch_size))
 
     # -- weights I/O -------------------------------------------------------

@@ -22,9 +22,11 @@ DMAs too (~half the HBM traffic).
 Where it wins: the kernel's value is O(L·D) memory (the (L, L) score
 matrix never materializes), which is what makes long sequences fit at all;
 on raw speed XLA's fused dense attention is competitive at moderate L
-(measured on v5e at seq 2048: dense 74.2 ms vs flash 77.6 ms fwd —
-bench_results/tpu_v5e_round3b.json), with the kernel's causal block skip
-paying off as L grows past the score-matrix memory wall. Use
+(dense against flash at 2,048 is not measured on today's code), with the
+kernel's causal block skip paying off as L grows past the score-matrix
+memory wall. At 4,096, in the learner's step of the cell
+``internlm2-1.8b.lora-round``, the kernels run at 36.57 % of their roofline
+(``flash_roofline``; PERF_LEDGER.jsonl, PR 29). Use
 :func:`attention` to route between the two on sequence length instead of
 hand-picking.
 
@@ -479,8 +481,9 @@ flash_attention.defvjp(_fwd, _bwd)
 # Flash-vs-dense crossover (sequence length). Below it XLA's fused dense
 # attention is at least as fast and compiles quicker; at/above it the dense
 # path's (L, L) score matrix starts to dominate memory and the kernel's
-# causal block skip pays off. Seeded from the v5e round-3 capture (dense
-# still ahead at 2048); the bench's block sweep re-measures every round.
+# causal block skip pays off. The crossover itself is not measured on
+# today's code: the benchmark's cells run the kernel at 4,096 and no cell
+# at 2,048 (ROADMAP.md).
 FLASH_MIN_SEQ = 4096
 
 

@@ -10,18 +10,6 @@ The reading half of the performance observatory (telemetry/profile.py):
       python -m metisfl_tpu.perf <workdir>
       python -m metisfl_tpu.perf experiment.json --round 3 --top 10
 
-- **--compare A.json B.json** — diff two bench captures key-by-key with
-  direction-aware relative-threshold regression flags and a CI-friendly
-  exit code (1 = regression detected, 0 = clean)::
-
-      python -m metisfl_tpu.perf --compare BENCH_r08.json BENCH_r09.json
-
-- **--trajectory <dir-or-files>** — the same diff across a whole series
-  of captures (consecutive pairs), e.g. the repo's ``BENCH_r0*.json``
-  driver captures. Degraded captures parse via the single-line
-  ``METISFL_BENCH`` marker bench.py appends (and older full-JSON tail
-  lines); unparseable ones are reported and skipped, never fatal.
-
 - **--flame <source>** — render a continuous-profiling capture
   (telemetry/prof.py) as collapsed folded stacks on stdout (the format
   speedscope and FlameGraph's ``flamegraph.pl`` ingest directly) plus a
@@ -50,26 +38,8 @@ The reading half of the performance observatory (telemetry/profile.py):
       python -m metisfl_tpu.perf --compile-report <workdir>/runtime-fleet.json
       python -m metisfl_tpu.perf --compile-report <workdir>
 
-Bench noise floor: captures may carry a ``details.repeats`` map
-(``{key: K}`` — bench.py re-measured ms-scale keys median-of-K on hosts
-whose run-to-run spread exceeds the gate). The comparison rows carry
-the per-key ``repeats`` field and the renderer marks them ``xK`` so a
-gated median is distinguishable from a single shot.
-
-Host provenance: a capture may declare the machine it ran on (a
-``host`` string in the result / ``parsed`` payload; bench.py stamps it
-from ``METISFL_BENCH_HOST`` or ``platform.node()``). A pair is **gated**
-(regressions fail the build) only when both captures name the same
-host, or neither names one (the pre-provenance record): absolute
-host-sensitive keys — RSS accounting, disk latencies — are not
-comparable across a hardware move, so a cross-host pair renders its
-rows informationally and never exits 1 on them. A collapsed headline
-(``*_failed`` shape) still fails regardless — a bench that stopped
-producing results is broken on any host.
-
 Library-usable: :func:`load_profiles`, :func:`render_waterfall`,
-:func:`span_self_times`, :func:`load_bench_capture`,
-:func:`compare_captures`.
+:func:`span_self_times`.
 """
 
 from __future__ import annotations
@@ -80,24 +50,6 @@ import json
 import os
 import sys
 from typing import Any, Dict, List, Optional, Tuple
-
-# bench.py stamps this on every result and prefixes the final marker
-# line with it — the trajectory parser's anchor on degraded runs whose
-# main JSON line was truncated by the capture harness
-BENCH_MARKER = "METISFL_BENCH "
-
-# flattened-capture key carrying the declared capture host (never judged
-# — metric_direction reports 0 for it; see "Host provenance" above)
-HOST_KEY = "_host"
-
-# flattened-capture key carrying the per-key repeat counts (a dict, so
-# the numeric _take filter skips it; comparison rows re-attach it)
-REPEATS_KEY = "_repeats"
-
-# default relative-change threshold for regression flags (20% — well
-# under the 30% regressions the acceptance gate injects, well over
-# normal run-to-run jitter for the judged keys)
-DEFAULT_THRESHOLD = 0.2
 
 
 # --------------------------------------------------------------------- #
@@ -327,237 +279,6 @@ def _load_trace_spans(path: str) -> List[dict]:
     # profile sink lines also live under telemetry/ and parse as dicts
     # without a "span" key — load_spans already filters them out
     return spans
-
-
-# --------------------------------------------------------------------- #
-# bench-capture loading (raw results, driver captures, degraded tails)
-# --------------------------------------------------------------------- #
-
-def load_bench_capture(path: str) -> Dict[str, Any]:
-    """One bench capture as a flat ``{key: float}`` dict, from any of the
-    shapes this repo records:
-
-    - a raw ``bench.py`` result line saved as JSON;
-    - a driver capture ``{"n", "cmd", "rc", "tail", "parsed"}`` —
-      ``parsed`` when present, else the tail scanned for the
-      ``METISFL_BENCH`` marker line or a full result JSON line;
-    - a watcher/partial capture ``{"details": {...}}``.
-
-    Returns ``{}`` when nothing parseable is found (reported by the
-    caller, never fatal)."""
-    try:
-        with open(path) as fh:
-            data = json.load(fh)
-    except (OSError, json.JSONDecodeError):
-        return {}
-    if not isinstance(data, dict):
-        return {}
-    if "metric" in data or "value" in data:
-        return flatten_bench(data)
-    if "parsed" in data or "tail" in data:
-        parsed = data.get("parsed")
-        if isinstance(parsed, dict) and parsed:
-            return flatten_bench(parsed)
-        return _parse_capture_tail(str(data.get("tail") or ""))
-    if "details" in data:
-        return flatten_bench(data)
-    return {}
-
-
-def capture_host(flat: Dict[str, Any]) -> str:
-    """The capture's declared host identity ('' = pre-provenance
-    capture). Kept under a non-judgeable key by :func:`flatten_bench`."""
-    return str(flat.get(HOST_KEY, "") or "")
-
-
-def _parse_capture_tail(tail: str) -> Dict[str, Any]:
-    """Recover a result from a captured stdout tail: the final
-    ``METISFL_BENCH`` marker wins (it is small, so it survives
-    head-truncation of the capture window); else the last line that
-    parses as a full result JSON."""
-    marker: Optional[dict] = None
-    full: Optional[dict] = None
-    for line in tail.splitlines():
-        line = line.strip()
-        if line.startswith(BENCH_MARKER):
-            try:
-                candidate = json.loads(line[len(BENCH_MARKER):])
-                if isinstance(candidate, dict):
-                    marker = candidate
-            except json.JSONDecodeError:
-                continue
-        elif line.startswith("{"):
-            try:
-                candidate = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if isinstance(candidate, dict) and ("metric" in candidate
-                                                or "details" in candidate):
-                full = candidate
-    if full is not None:
-        flat = flatten_bench(full)
-        if marker is not None:
-            flat.setdefault("schema_version",
-                            marker.get("schema_version", 0))
-        return flat
-    if marker is not None:
-        return flatten_bench(marker)
-    return {}
-
-
-_EXCLUDE_KEYS = {
-    # harness bookkeeping, timestamps, and identity keys — never judged
-    # (probe_attempts: captures from before PR 21 still carry it)
-    "n", "rc", "ts", "schema_version", "errors", "probe_attempts",
-    "devices", "bench_wall_s",
-}
-
-
-def flatten_bench(capture: Dict[str, Any]) -> Dict[str, Any]:
-    """Numeric keys from a bench result: top-level value/vs_baseline/mfu
-    plus every numeric ``details`` entry, excluding harness bookkeeping."""
-    flat: Dict[str, Any] = {}
-
-    def _take(key: str, value: Any) -> None:
-        if key in _EXCLUDE_KEYS or isinstance(value, bool):
-            return
-        if isinstance(value, (int, float)):
-            flat[key] = float(value)
-
-    for key in ("value", "vs_baseline", "mfu"):
-        if key in capture:
-            _take(key, capture[key])
-    for key, value in (capture.get("details") or {}).items():
-        _take(key, value)
-    # marker-shaped captures carry their numerics at the top level
-    if "details" not in capture:
-        for key, value in capture.items():
-            _take(key, value)
-    if capture.get("host"):
-        flat[HOST_KEY] = str(capture["host"])
-    repeats = (capture.get("details") or {}).get("repeats")
-    if isinstance(repeats, dict) and repeats:
-        flat[REPEATS_KEY] = {str(k): int(v) for k, v in repeats.items()
-                             if isinstance(v, (int, float))}
-    return flat
-
-
-# --------------------------------------------------------------------- #
-# direction-aware comparison
-# --------------------------------------------------------------------- #
-
-# substrings that classify a key's improvement direction. Higher-better
-# patterns are checked FIRST: throughput keys like samples_per_sec would
-# otherwise match the lower-better "_s"/"secs" time patterns.
-_HIGHER_BETTER = ("mfu", "per_sec", "tokens_per", "samples_per",
-                  "throughput", "vs_baseline", "hit_rate", "tflops",
-                  "rows_per", "speedup", "accuracy")
-_LOWER_BETTER = ("_ms", "ms_per", "_secs", "seconds", "_bytes", "_mb",
-                 "_kb", "rss", "wall", "latency", "pause",
-                 # obs section: sketch-vs-exact quantile error — a
-                 # growing error means the digest got worse, a regression
-                 "relerr",
-                 # prof section: nanosecond-scale per-acquire lock costs
-                 # (the overhead *percentage* is deliberately unjudged —
-                 # a ratio of two noisy medians would flag pure noise;
-                 # the chaos_smoke prof gate bounds it absolutely)
-                 "_ns",
-                 # runtime section: a growing steady-state recompile
-                 # count is always a regression (the smoke gate pins the
-                 # decode path's at zero absolutely)
-                 "recompile",
-                 # secure section: the secure-vs-plain round-time
-                 # multiplier — masking overhead growing is a regression
-                 "multiplier")
-
-
-def metric_direction(key: str) -> int:
-    """+1 = higher is better, -1 = lower is better, 0 = don't judge."""
-    k = key.lower()
-    if k == "value":
-        # the headline bench value is aggregation ms/round
-        return -1
-    for pat in _HIGHER_BETTER:
-        if pat in k:
-            return 1
-    for pat in _LOWER_BETTER:
-        if pat in k:
-            return -1
-    if k.endswith("_s") or "_s_" in k or k.endswith("_insert_s"):
-        return -1
-    return 0
-
-
-def compare_captures(a: Dict[str, Any], b: Dict[str, Any],
-                     threshold: float = DEFAULT_THRESHOLD
-                     ) -> List[Dict[str, Any]]:
-    """Key-by-key relative diff of two flattened captures: one row per
-    shared judgeable key, ``regressed=True`` where B is worse than A by
-    more than ``threshold`` (relative, direction-aware)."""
-    rows: List[Dict[str, Any]] = []
-    rep_a = a.get(REPEATS_KEY) or {}
-    rep_b = b.get(REPEATS_KEY) or {}
-    for key in sorted(set(a) & set(b)):
-        direction = metric_direction(key)
-        if direction == 0:
-            continue
-        va, vb = float(a[key]), float(b[key])
-        if va <= 0.0:
-            continue  # no baseline to be relative to
-        if vb <= 0.0 and direction < 0:
-            # a lower-better metric at 0 means the subsystem recorded
-            # nothing (errored/skipped section, zero-filled degraded
-            # capture), not an infinite speedup — don't judge it.
-            # Higher-better keys keep judging: throughput collapsing to
-            # 0 IS the regression.
-            continue
-        rel = (vb - va) / abs(va)
-        regressed = (rel > threshold if direction < 0
-                     else rel < -threshold)
-        improved = (rel < -threshold if direction < 0
-                    else rel > threshold)
-        rows.append({"key": key, "a": va, "b": vb, "rel": rel,
-                     "direction": direction, "regressed": regressed,
-                     "improved": improved,
-                     # bench noise floor: how many measurements back each
-                     # side (1 = single shot; >1 = median-of-K, bench.py
-                     # re-measured a ms-scale key under the repeat
-                     # threshold) — carried so the gate's verdict is
-                     # auditable as a median, not a lucky shot
-                     "repeats": max(int(rep_a.get(key, 1)),
-                                    int(rep_b.get(key, 1)))})
-    return rows
-
-
-def capture_collapsed(a: Dict[str, Any], b: Dict[str, Any]) -> bool:
-    """True when capture B's headline collapsed while A had one: the
-    later run recorded value<=0 (bench.py's *_failed shape zero-fills
-    it) or lost the key entirely. Per-key comparison deliberately skips
-    lower-better zeros — this capture-level check is what keeps a bench
-    that stopped producing results at all from passing the CI gate."""
-    va = a.get("value")
-    if va is None or va <= 0.0:
-        return False  # no healthy baseline to collapse from
-    vb = b.get("value")
-    return vb is None or vb <= 0.0
-
-
-def render_comparison(rows: List[Dict[str, Any]],
-                      label_a: str = "A", label_b: str = "B",
-                      show_all: bool = False) -> str:
-    lines = [f"{'key':<36} {label_a:>12} {label_b:>12} {'change':>9}"]
-    for row in rows:
-        if not (show_all or row["regressed"] or row["improved"]):
-            continue
-        tag = ("  REGRESSED" if row["regressed"]
-               else "  improved" if row["improved"] else "")
-        if int(row.get("repeats", 1)) > 1:
-            tag += f"  x{int(row['repeats'])}"
-        lines.append(f"{row['key']:<36} {row['a']:>12.4g} "
-                     f"{row['b']:>12.4g} {row['rel'] * 100:>+8.1f}%{tag}")
-    if len(lines) == 1:
-        lines.append("(no judgeable shared keys moved past the threshold)")
-    return "\n".join(lines)
 
 
 # --------------------------------------------------------------------- #
@@ -860,16 +581,6 @@ def _flame_diff_main(path_a: str, path_b: str,
     return 0
 
 
-def _trajectory_paths(args: List[str]) -> List[str]:
-    paths: List[str] = []
-    for arg in args:
-        if os.path.isdir(arg):
-            paths.extend(sorted(glob.glob(os.path.join(arg, "*.json"))))
-        else:
-            paths.append(arg)
-    return paths
-
-
 # --------------------------------------------------------------------- #
 # CLI
 # --------------------------------------------------------------------- #
@@ -878,17 +589,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(
         "metisfl_tpu.perf",
         description="performance observatory analyzer: round-profile "
-                    "waterfalls, span self-times, bench regression diffs")
+                    "waterfalls, span self-times, flame and compile reports")
     parser.add_argument("paths", nargs="*",
-                        help="run dir / profiles .jsonl / experiment.json "
-                             "(default mode), or capture files for "
-                             "--compare/--trajectory")
-    parser.add_argument("--compare", nargs=2, metavar=("A", "B"),
-                        help="diff two bench captures; exit 1 on regression")
-    parser.add_argument("--trajectory", nargs="+", metavar="PATH",
-                        help="diff a series of bench captures pairwise "
-                             "(files and/or dirs of .json); exit 1 on "
-                             "regression")
+                        help="run dir / profiles .jsonl / experiment.json")
     parser.add_argument("--critical-path", action="store_true",
                         help="causal critical path of one round "
                              "(--round; default: the latest) from a run "
@@ -910,16 +613,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("--out", default="",
                         help="--flame: write the collapsed stacks to this "
                              "file and print the table to stdout")
-    parser.add_argument("--threshold", type=float,
-                        default=DEFAULT_THRESHOLD,
-                        help="relative regression threshold "
-                             f"(default {DEFAULT_THRESHOLD})")
     parser.add_argument("--round", type=int, default=None,
                         help="waterfall: only this round")
     parser.add_argument("--top", type=int, default=15,
                         help="span self-time rows to show")
-    parser.add_argument("--all", action="store_true",
-                        help="comparison: show unchanged keys too")
     args = parser.parse_args(argv)
 
     if args.critical_path:
@@ -935,88 +632,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                                 args.round, args.top)
     if args.compile_report:
         return _compile_report_main(args.compile_report, args.top)
-    if args.compare:
-        return _compare_main(args.compare[0], args.compare[1],
-                             args.threshold, args.all)
-    if args.trajectory:
-        return _trajectory_main(_trajectory_paths(args.trajectory),
-                                args.threshold)
     if not args.paths:
         parser.print_usage(sys.stderr)
         return 2
     return _waterfall_main(args.paths, args.round, args.top)
-
-
-def _compare_main(path_a: str, path_b: str, threshold: float,
-                  show_all: bool) -> int:
-    a, b = load_bench_capture(path_a), load_bench_capture(path_b)
-    for path, flat in ((path_a, a), (path_b, b)):
-        if not flat:
-            print(f"cannot parse a bench result from {path}",
-                  file=sys.stderr)
-            return 2
-    rows = compare_captures(a, b, threshold=threshold)
-    print(render_comparison(rows, label_a=os.path.basename(path_a),
-                            label_b=os.path.basename(path_b),
-                            show_all=show_all))
-    regressions = [r for r in rows if r["regressed"]]
-    if capture_collapsed(a, b):
-        # gated regardless of host: a bench that stopped producing a
-        # headline is broken on any machine
-        print(f"REGRESSED: {os.path.basename(path_b)} headline value "
-              f"collapsed to {b.get('value', 'absent')} (failed/degraded "
-              f"run)", file=sys.stderr)
-        return 1
-    host_a, host_b = capture_host(a), capture_host(b)
-    if host_a != host_b:
-        print(f"\nhost changed ({host_a or 'undeclared'} -> "
-              f"{host_b or 'undeclared'}): absolute host-sensitive keys "
-              "are not comparable — rows above are informational, not "
-              "gated", file=sys.stderr)
-        return 0
-    if regressions:
-        print(f"\n{len(regressions)} regression(s) past "
-              f"{threshold * 100:.0f}% threshold", file=sys.stderr)
-        return 1
-    return 0
-
-
-def _trajectory_main(paths: List[str], threshold: float) -> int:
-    captures: List[Tuple[str, Dict[str, Any]]] = []
-    for path in paths:
-        flat = load_bench_capture(path)
-        if flat:
-            captures.append((os.path.basename(path), flat))
-        else:
-            print(f"skipping unparseable capture {path}", file=sys.stderr)
-    if len(captures) < 2:
-        print("need at least two parseable captures for a trajectory",
-              file=sys.stderr)
-        return 2
-    any_regression = False
-    for (name_a, a), (name_b, b) in zip(captures, captures[1:]):
-        rows = compare_captures(a, b, threshold=threshold)
-        regressions = [r for r in rows if r["regressed"]]
-        improvements = [r for r in rows if r["improved"]]
-        host_a, host_b = capture_host(a), capture_host(b)
-        cross_host = host_a != host_b
-        print(f"{name_a} -> {name_b}: {len(regressions)} regression(s), "
-              f"{len(improvements)} improvement(s) over "
-              f"{len(rows)} judged key(s)"
-              + (f"  [host changed: {host_a or 'undeclared'} -> "
-                 f"{host_b or 'undeclared'}; informational, not gated]"
-                 if cross_host else ""))
-        for row in regressions:
-            print(f"  REGRESSED {row['key']}: {row['a']:.4g} -> "
-                  f"{row['b']:.4g} ({row['rel'] * 100:+.1f}%)")
-        if cross_host:
-            regressions = []  # collapse check below still gates
-        if capture_collapsed(a, b):
-            print(f"  REGRESSED {name_b}: headline value collapsed to "
-                  f"{b.get('value', 'absent')} (failed/degraded run)")
-            regressions.append({"key": "value"})
-        any_regression = any_regression or bool(regressions)
-    return 1 if any_regression else 0
 
 
 def _critical_path_main(paths: List[str],
@@ -1054,7 +673,7 @@ def _waterfall_main(paths: List[str], want_round: Optional[int],
         print("no round profiles or trace spans found (is the "
               "performance observatory enabled and the run dir right?)",
               file=sys.stderr)
-        return 2  # unusable input, same code as the compare modes
+        return 2  # unusable input
     if profiles:
         print(render_waterfall(profiles, want_round=want_round))
     if spans:

@@ -217,7 +217,7 @@ class DiskModelStore(ModelStore):
         selects (reference metisfl/controller/store/redis_model_store.cc:
         180-260).
 
-        Lifetime contract (POSIX-only, ADVICE r5): the mmap handle is
+        Lifetime contract (POSIX-only): the mmap handle is
         never explicitly closed — it stays alive through the returned
         numpy views' base references and is unmapped when the last view
         is garbage-collected. Eviction or overwrite may ``unlink`` the
@@ -282,8 +282,8 @@ class DiskModelStore(ModelStore):
 
     def select(self, learner_ids: Sequence[str], k: int = 1) -> Dict[str, List[Any]]:
         """Latest ≤k models per learner, cache-first, learners read in
-        parallel across the pool (cold select_all @64 learners is otherwise
-        ~the whole 2 s round budget — BASELINE.md)."""
+        parallel across the pool (a cold select of a whole cohort, one
+        learner after another, otherwise waits on every read in turn)."""
         out: Dict[str, List[Any]] = {}
         ids = list(learner_ids)
         if len(ids) == 1:  # no pool round-trip for a single learner

@@ -40,7 +40,7 @@ Overhead contract: ``telemetry.health.enabled=false`` (or secure
 aggregation, whose payloads are opaque ciphertext) leaves the
 controller's monitor unset — the uplink hot path costs ONE attribute
 check and performs no statistics work. Enabled, the per-uplink pass is
-O(params) host work, tracked by the ``health`` section of ``bench.py``.
+O(params) host work.
 """
 
 from __future__ import annotations
@@ -69,8 +69,8 @@ _EPS = 1e-12
 _MAX_LAYER_ROWS = 32
 # Pending per-round update vectors are dropped at each cohort fold; this
 # caps the buffer against an async federation whose folds lag uplinks.
-# Sized to the largest supported cohort scale (bench.py bench_cohort
-# drives 4096) so a legitimate sync round is never silently truncated;
+# Sized to the largest supported cohort scale (4096) so a legitimate
+# sync round is never silently truncated;
 # evictions are counted and surfaced as ``pending_evicted`` in the next
 # round snapshot (evicted learners get no score that round).
 _MAX_PENDING = 4096
@@ -246,7 +246,7 @@ class HealthMonitor:
         cohort fold and returns the per-uplink summary. Single pass over
         the tensors: the per-tensor diff feeds both the flat vector and
         the per-layer norm breakdown (this is the health plane's hot
-        path — bench.py section ``health`` tracks it)."""
+        path)."""
         names = sorted(set(model) & set(reference))
         parts: List[np.ndarray] = []
         layer_sq: Dict[str, float] = {}

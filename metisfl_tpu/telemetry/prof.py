@@ -45,7 +45,7 @@ lock factories return raw ``threading.Lock``/``RLock`` objects (the hot
 paths carry zero wrapper cost), and the ``CollectTelemetry`` section is
 an ``{"enabled": false}`` stub. The profiler's own overhead is gated in
 CI (``python -m metisfl_tpu.telemetry --prof-smoke``, wired into
-scripts/chaos_smoke.sh): the bench round loop with profiling on must
+scripts/chaos_smoke.sh): a synthetic fold loop with profiling on must
 stay within the pinned bound of the profiling-off run.
 """
 
@@ -585,7 +585,7 @@ def postmortem_snapshot(top: int = 10) -> Optional[Dict[str, Any]]:
 # --------------------------------------------------------------------- #
 
 def _smoke_round_loop(nlock, blocks: int = 1000) -> float:
-    """One bench-shaped aggregation round: stride-blocked stacked scaled
+    """One synthetic aggregation round: stride-blocked stacked scaled
     adds over synthetic models, each block under a (possibly
     instrumented) lock — the controller fold loop's shape. Sized to run
     a few hundred ms, long enough that the 67 Hz sampler ticks dozens of
@@ -608,7 +608,7 @@ def _smoke_round_loop(nlock, blocks: int = 1000) -> float:
 
 
 def _smoke(bound_pct: float = 3.0, trials: int = 7) -> int:
-    """The CI overhead gate: the bench round loop with profiling ON
+    """The CI overhead gate: the synthetic round loop with profiling ON
     (sampler at the default 67 Hz + an instrumented lock on the fold
     path) vs OFF, ``trials`` interleaved runs each, MINIMA judged.
     Fails (exit 1) when the ON minimum exceeds the OFF minimum by more

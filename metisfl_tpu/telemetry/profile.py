@@ -26,8 +26,7 @@ tuning, fleet autoscaling) is measured through one instrument panel:
   dispatched ``TrainParams.profile_dir``.
 
 ``python -m metisfl_tpu.perf`` renders the phase waterfall and top-span
-self-time table from a run directory, and diffs bench captures with
-regression flags (``--compare`` / ``--trajectory``).
+self-time table from a run directory.
 
 Opt-out: ``telemetry.profile.enabled=false`` leaves every hot path at
 one attribute check (no collector constructed, no device stats shipped).
@@ -95,8 +94,8 @@ _M_HBM = _REG.gauge(
     ("learner",), budget_label="learner")
 
 # bf16 peak FLOP/s per chip, keyed by the exact ``device_kind`` JAX
-# reports — the MFU denominator. The ONE table: bench.py imports
-# device_peak_flops from here rather than keeping its own copy. Every entry
+# reports — the MFU denominator of the learner's gauge (the benchmark
+# keeps its own, with sources, in benchmark/lib/peaks.json). Every entry
 # names its source; an accelerator that is not here is an error, never a
 # default (a near-miss substring match once handed every unknown "v5…"
 # the v5p's peak).

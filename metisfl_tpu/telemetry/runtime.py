@@ -58,7 +58,7 @@ Opt-out ``telemetry.runtime.enabled=false``: no listener is ever
 installed, wrapped jits pass straight through (one attribute check),
 and the ``CollectTelemetry`` section is an ``{"enabled": false}`` stub.
 The CI gate ``python -m metisfl_tpu.telemetry --runtime-smoke``
-(scripts/chaos_smoke.sh) runs the bench round loop plus a
+(scripts/chaos_smoke.sh) runs a synthetic round loop plus a
 continuous-batching decode burst and fails the build if steady-state
 (post-warmup) compiles are nonzero, if a deliberately shape-shifting
 control run does NOT trip the detector, or if wrapper overhead exceeds
@@ -171,7 +171,7 @@ def plane() -> str:
 def set_plane(service: str) -> None:
     """Derive the memory-attribution plane from a process's service name
     (apply_config passes it): learner train / controller fold / serving
-    decode, ``host`` for anything else (bench, tests, CLIs)."""
+    decode, ``host`` for anything else (tests, CLIs)."""
     s = (service or "").lower()
     if s.startswith("controller") or s.startswith("standby"):
         _STATE.plane = "controller"
@@ -649,7 +649,7 @@ def postmortem_snapshot() -> Optional[Dict[str, Any]]:
 # --------------------------------------------------------------------- #
 
 def _smoke_round_kernel():
-    """A bench-shaped jitted round kernel: one monitored train-ish step
+    """A synthetic jitted round kernel: one monitored train-ish step
     over a synthetic two-tensor model (the models/ops.py posture)."""
     import jax
     import jax.numpy as jnp
@@ -681,7 +681,7 @@ def _smoke_decoder(vocab: int = 97):
 
 def _smoke(overhead_budget_ns: float = 50_000.0, trials: int = 5,
            steady_iters: int = 30) -> int:
-    """The CI gate: (1) the bench round loop + a continuous-batching
+    """The CI gate: (1) the synthetic round loop + a continuous-batching
     decode burst must report ZERO post-warmup compiles; (2) a
     deliberately shape-shifting control run must report NONZERO
     recompiles (the detector provably fires, storm event included);
@@ -695,7 +695,7 @@ def _smoke(overhead_budget_ns: float = 50_000.0, trials: int = 5,
     _events.configure(enabled=True, service="runtime-smoke", dir="")
     failures: List[str] = []
 
-    # --- bench round loop: warmup compiles, then steady shapes -------- #
+    # --- round loop: warmup compiles, then steady shapes -------------- #
     step = _smoke_round_kernel()
     rng = np.random.default_rng(5)
     params = {"w": rng.standard_normal((128, 64)).astype(np.float32),

@@ -212,7 +212,7 @@ def write_named_tensors(fd: int, named: NamedTensors,
     ``to_bytes`` pays three full-model memcpys (per-tensor ``tobytes``,
     the body join, the framing join) before the file write — ~3x the
     model size in pure memory traffic, which is what capped disk-store
-    ingest at ~21 models/s (VERDICT weak #5, BENCH_r05). Here each
+    ingest. Here each
     tensor contributes a read-only ``memoryview`` straight over its
     buffer: the crc folds incrementally across the views and ``writev``
     gathers them into the file, so the only model-sized copy left is the
@@ -224,7 +224,7 @@ def write_named_tensors(fd: int, named: NamedTensors,
     decode, ``os.replace`` keeps half-written files from ever appearing
     under their final name, and the length frame still rejects
     truncation — re-hashing the model on every insert AND select was
-    pure hot-path overhead (~half the write cost at bench model size).
+    pure hot-path overhead.
     Wire blobs keep the v2 checksum."""
     chunks: List = []
     for name, arr in named:

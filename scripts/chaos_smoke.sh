@@ -7,9 +7,7 @@
 # and one partitioned learner — AND the no-churn same-seed control, then
 # fails the build when any round fails to complete or the final accuracy
 # drifts past the tolerance from the control run. Deterministic fault
-# schedule (fixed seed), finishes in well under 60 s on one CPU core:
-# churn tolerance is gated exactly like bench regressions are by
-# scripts/check_bench.sh.
+# schedule (fixed seed), finishes in well under 60 s on one CPU core.
 #
 # ISSUE 10 additions, gated in the same run:
 #  - SLO alert lifecycle (--alert-smoke): the partition fault must trip

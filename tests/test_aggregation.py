@@ -18,6 +18,25 @@ def weights(m):
     return np.asarray(m["layer"]["w"])
 
 
+# the twelve tensors of a CIFAR-10-scale CNN, 1.41M parameters: the size
+# of model the reference's aggregation anecdote measures
+# (controller.cc:594-604)
+CNN_SHAPES = {
+    "conv1/kernel": (3, 3, 3, 32), "conv1/bias": (32,),
+    "conv2/kernel": (3, 3, 32, 64), "conv2/bias": (64,),
+    "conv3/kernel": (3, 3, 64, 128), "conv3/bias": (128,),
+    "dense1/kernel": (2048, 512), "dense1/bias": (512,),
+    "dense2/kernel": (512, 512), "dense2/bias": (512,),
+    "head/kernel": (512, 10), "head/bias": (10,),
+}
+
+
+def cnn_models(count, seed=0):
+    rng = np.random.default_rng(seed)
+    return [{name: rng.standard_normal(shape).astype(np.float32)
+             for name, shape in CNN_SHAPES.items()} for _ in range(count)]
+
+
 def test_fedavg_equal_weights_identical_models():
     m = model(range(1, 11))
     out = FedAvg().aggregate([([m], 0.5), ([m], 0.5)])
@@ -76,6 +95,25 @@ def test_fedavg_blockwise_fold_equals_one_shot():
     rule.accumulate(pairs[4:])
     out = rule.result()
     np.testing.assert_allclose(weights(out), weights(expected), rtol=1e-6)
+
+
+def test_fedavg_stride_blocked_over_the_cnn_equals_the_plain_mean():
+    # the controller's fold (controller/core.py _compute_community_model)
+    # at the width of a real model: sixteen learners, stride 8, one block
+    # resident at a time, every one of the twelve tensors
+    models = cnn_models(16)
+    rule = FedAvg()
+    rule.reset()
+    for start in range(0, len(models), 8):
+        rule.accumulate([([m], 1.0 / len(models))
+                         for m in models[start:start + 8]])
+    out = rule.result()
+    assert set(out) == set(CNN_SHAPES)
+    for name in CNN_SHAPES:
+        got = np.asarray(out[name])
+        assert got.dtype == np.float32 and got.shape == CNN_SHAPES[name]
+        np.testing.assert_allclose(
+            got, np.mean([m[name] for m in models], axis=0), atol=1e-5)
 
 
 def test_fedavg_result_before_accumulate_raises():

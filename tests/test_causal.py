@@ -155,6 +155,55 @@ def test_critical_path_fork_join_attribution_and_telescoping():
     assert cp["detached"] == 0
 
 
+def test_critical_path_of_a_two_thousand_span_round():
+    """The walk at the width of a real cohort: one round, four hundred
+    learners whose train spans each outlive their RunTask parent (the
+    fork-join shape), three step leaves under each, an aggregate tail.
+    The slowest learner holds the dominant edge, every edge is
+    attributed once, and the self times telescope to the root."""
+    t0, learners, slow = 1_000_000.0, 400, 137
+    trace = ttrace.round_trace_id(1)
+    root = _rec(0, "round", "", t0, 5_000.0, trace=trace,
+                service="controller", attrs={"round": 1})
+    dispatch = _rec(1, "round.dispatch", root["span"], t0 + 0.001, 80.0,
+                    trace=trace, service="controller")
+    records, i = [root, dispatch], 2
+    for li in range(learners):
+        start = t0 + 0.002 + 1e-5 * li
+        train_ms = 3_100.0 if li == slow else 2_000.0 + li % 13
+        service = f"learner_{li}"
+        task = _rec(i, "rpc.server/RunTask", dispatch["span"], start, 20.0,
+                    trace=trace, service=service)
+        train = _rec(i + 1, "learner.train", task["span"], start + 0.005,
+                     train_ms, trace=trace, service=service,
+                     attrs={"learner": service})
+        records += [task, train] + [
+            _rec(i + 2 + leaf, f"learner.step_{leaf}", train["span"],
+                 start + 0.01 + 0.3 * leaf, 250.0, trace=trace,
+                 service=service) for leaf in range(3)]
+        i += 5
+    agg = _rec(i, "round.aggregate", root["span"], t0 + 3.2, 1_700.0,
+               trace=trace, service="controller")
+    records += [agg, _rec(i + 1, "round.agg_block", agg["span"], t0 + 3.25,
+                          1_600.0, trace=trace, service="controller")]
+    assert len(records) == 2_004
+    cp = tcausal.critical_path(records)
+    assert cp["root"] == "round" and cp["round"] == 1
+    assert cp["dominant"] == f"learner_{slow}/learner.train"
+    labels = [e["label"] for e in cp["edges"]]
+    assert len(labels) == len(set(labels))
+    assert "controller/round.agg_block" in labels
+    # the learners that started before the slow one hold the slivers
+    # between their starts; together the other 399 explain next to nothing
+    others = sum(e["self_ms"] for e in cp["edges"]
+                 if e["service"] not in ("controller", f"learner_{slow}"))
+    assert others < 0.01 * cp["total_ms"]
+    assert sum(e["self_ms"] for e in cp["edges"]) == pytest.approx(
+        cp["total_ms"], rel=1e-6)
+    assert cp["total_ms"] == pytest.approx(5_000.0)
+    assert cp["coverage"] >= 0.9 and cp["detached"] == 0
+
+
 def test_passive_spans_are_never_chain_candidates():
     records = _round_tree()
     # a barrier wait covering almost the whole round: skipped, so the
@@ -244,8 +293,8 @@ def test_outbound_metadata_roundtrip_and_disabled_optout(ring):
 
 
 def test_propagation_overhead_is_sub_budget():
-    # the same measurement the --causal-smoke CI gate and bench.py's
-    # trace section take: inject + extract, per RPC
+    # the same measurement the --causal-smoke CI gate takes: inject +
+    # extract, per RPC
     ns = tcausal._propagation_overhead_ns(iters=2000)
     assert 0 < ns < 50_000
 
@@ -404,20 +453,6 @@ def test_flash_attention_imports_cleanly():
     assert isinstance(mod._SEQ_PARAMS, pltpu.CompilerParams)
     assert mod._SEQ_PARAMS.dimension_semantics == ("parallel", "parallel",
                                                    "arbitrary")
-
-
-def test_bench_registers_trace_section():
-    import bench
-
-    assert "trace" in bench._SECTIONS
-    assert "trace" in bench._HOST_SECTIONS
-    assert bench._SECTION_TIMEOUTS["trace"] > 0
-    out = bench.bench_trace(trials=1, cp_trials=1)
-    # the keys the docs + perf trajectory direction-classify on
-    assert set(out) >= {"trace_propagate_ns", "trace_critical_path_1k_ms",
-                        "trace_critical_path_10k_ms"}
-    assert out["trace_propagate_ns"] > 0
-    assert out["trace_critical_path_10k_ms"] > 0
 
 
 # --------------------------------------------------------------------- #

@@ -518,74 +518,6 @@ def test_template_documents_fabric_defaults():
     assert block["span_ring"] == defaults.span_ring
 
 
-def test_bench_trajectory_host_provenance(tmp_path, capsys):
-    """Bench satellite: cross-host capture pairs are informational, not
-    gated — same-host regressions still fail, and a collapsed headline
-    fails on any host."""
-    from metisfl_tpu import perf
-
-    def _cap(path, value, host=None, extra=None):
-        parsed = {"metric": "agg_ms", "value": value, "unit": "ms",
-                  "details": dict(extra or {})}
-        if host:
-            parsed["host"] = host
-        path.write_text(json.dumps(
-            {"n": 1, "rc": 0, "tail": "", "parsed": parsed}))
-
-    a, b, c = (tmp_path / n for n in ("a.json", "b.json", "c.json"))
-    # 40% regression across a host move: informational, exit 0
-    _cap(a, 100.0, host=None)
-    _cap(b, 140.0, host="new-box")
-    assert perf.main(["--compare", str(a), str(b)]) == 0
-    assert "host changed" in capsys.readouterr().err
-    # the same regression on one host: gated, exit 1
-    _cap(a, 100.0, host="box")
-    _cap(b, 140.0, host="box")
-    assert perf.main(["--compare", str(a), str(b)]) == 1
-    capsys.readouterr()
-    # collapsed headline fails even across hosts
-    _cap(c, 0.0, host="another-box")
-    assert perf.main(["--compare", str(b), str(c)]) == 1
-    capsys.readouterr()
-    # trajectory: cross-host pair not gated, same-host pair gated
-    _cap(tmp_path / "t1.json", 100.0, host="old")
-    _cap(tmp_path / "t2.json", 150.0, host="new")
-    _cap(tmp_path / "t3.json", 150.0, host="new")
-    assert perf.main(["--trajectory", str(tmp_path / "t1.json"),
-                      str(tmp_path / "t2.json"),
-                      str(tmp_path / "t3.json")]) == 0
-    out = capsys.readouterr().out
-    assert "host changed" in out
-
-
-def test_bench_trajectory_survives_truncated_capture(tmp_path):
-    """A driver capture whose main result line was cut off parses from
-    its tail marker, and a host move between two captures (the old
-    capture names no host) does not gate the pair."""
-    from metisfl_tpu import perf
-
-    marker = {"schema_version": 2, "metric": "agg_ms", "value": 39.7,
-              "unit": "ms", "vs_baseline": 50.3, "errors": 0}
-    old = tmp_path / "BENCH_old.json"
-    old.write_text(json.dumps(
-        {"n": 5, "rc": 0, "parsed": None,
-         "tail": '78.5, "store_cached_hit_rate": 1.0}\n'
-                 + perf.BENCH_MARKER + json.dumps(marker)}))
-    new = tmp_path / "BENCH_new.json"
-    new.write_text(json.dumps(
-        {"n": 6, "rc": 0, "tail": "", "parsed": {
-            "metric": "agg_ms", "value": 48.0, "unit": "ms",
-            "host": "new-box",
-            "details": {"fabric_peers_8_poll_ms": 3.0}}}))
-    r_old = perf.load_bench_capture(str(old))
-    r_new = perf.load_bench_capture(str(new))
-    assert r_old.get("value", 0) > 0, "marker-only capture must parse"
-    assert r_new.get("value", 0) > 0
-    assert any(k.startswith("fabric_peers_") for k in r_new)
-    assert perf.capture_host(r_new)
-    assert perf.main(["--compare", str(old), str(new)]) == 0
-
-
 def test_fabric_config_validation():
     from metisfl_tpu.config import FabricConfig, FederationConfig, \
         TelemetryConfig

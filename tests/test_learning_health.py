@@ -230,6 +230,41 @@ def test_sketch_bounds_buffer_memory_and_still_separates(monkeypatch):
     assert abs(s["cos_prev_delta"]) > 0.0
 
 
+def test_fold_at_model_width_scores_every_learner_and_the_outlier():
+    """The plane's O(params) pass at the width of a real model (the
+    twelve-tensor CNN, 1.41M parameters, 86 times the sketch): exact
+    norms, a buffer of the sketch's size, every learner scored in each of
+    two rounds, and the learner that moved forty times as far flagged."""
+    from metisfl_tpu.telemetry import health as health_mod
+    from tests.test_aggregation import CNN_SHAPES, cnn_models
+
+    reference = cnn_models(1, seed=10)[0]
+    assert sum(a.size for a in reference.values()) > 1_000_000
+    steps = cnn_models(8, seed=9)
+    ids = [f"learner_{i}" for i in range(len(steps))]
+    monitor = HealthMonitor()
+    monitor.note_community(reference)
+    for r in range(2):
+        for i, (lid, step) in enumerate(zip(ids, steps)):
+            scale = np.float32(0.4 if i == 5 else 0.01)
+            model = {n: reference[n] + scale * step[n] for n in reference}
+            summary = monitor.observe_update(
+                lid, model, reference, train_metrics={"loss": 1.0 - 0.1 * r})
+            exact = np.sqrt(sum(
+                float(np.sum(np.square(model[n] - reference[n],
+                                       dtype=np.float64)))
+                for n in reference))
+            assert summary["update_norm"] == pytest.approx(exact, rel=1e-4)
+            assert set(summary["layer_norms"]) == {
+                layer_key(n) for n in CNN_SHAPES}
+            assert monitor._pending[lid][0].size == health_mod._SKETCH_DIM
+        health, anomalies = monitor.complete_round(
+            r, reference, {lid: 1.0 / len(ids) for lid in ids})
+        assert sorted(health["divergence_score"]) == ids
+        assert [a["learner_id"] for a in anomalies] == ["learner_5"]
+        assert not monitor._pending
+
+
 def test_off_width_update_is_unscored_not_falsely_anomalous(monkeypatch):
     """A different-width update (partial tensor set: version skew,
     malformed uplink) sketches to the SAME shape as the cohort but

@@ -6,10 +6,9 @@ Protocol-level tests drive a bare :class:`Controller` over no-op proxies
 with crafted uplinks (deterministic byte counts — the wire-attribution
 equality the acceptance gate pins); the integration test runs a real
 in-process 2-round federation and checks waterfall coverage + device
-stats; CLI tests cover ``--compare``/``--trajectory`` regression flags,
-degraded-capture recovery via the bench marker line, pruning on leave,
-the disabled-path inertness contract, post-mortem profile tails, and the
-doc catalog drift guard.
+stats; the rest covers the waterfall CLI, pruning on leave, the
+disabled-path inertness contract, post-mortem profile tails, and the doc
+catalog drift guard.
 """
 
 import json
@@ -554,131 +553,8 @@ def test_rpc_peer_byte_series_and_pruning(clean_telemetry):
 
 
 # --------------------------------------------------------------------- #
-# perf CLI: compare + trajectory + degraded-capture recovery
+# perf CLI: span self-times
 # --------------------------------------------------------------------- #
-
-
-def _bench_capture(value=100.0, tokens=5000.0, mfu=0.2, rss=100000.0):
-    return {
-        "schema_version": 2,
-        "metric": "aggregation_ms_per_round_64learners",
-        "value": value, "unit": "ms",
-        "vs_baseline": round(2000.0 / value, 2),
-        "mfu": mfu,
-        "details": {"ms_per_round_median": value,
-                    "lm_tokens_per_sec": tokens,
-                    "peak_rss_kb": rss,
-                    "backend": "cpu"},
-    }
-
-
-def test_perf_compare_flags_injected_regression(tmp_path, capsys):
-    """Acceptance: a 30% regression exits 1; clean captures exit 0."""
-    from metisfl_tpu import perf
-
-    a = tmp_path / "a.json"
-    b = tmp_path / "b.json"
-    a.write_text(json.dumps(_bench_capture(value=100.0)))
-    b.write_text(json.dumps(_bench_capture(value=130.0)))  # +30% slower
-    assert perf.main(["--compare", str(a), str(b)]) == 1
-    out = capsys.readouterr()
-    assert "REGRESSED" in out.out
-    assert "ms_per_round_median" in out.out
-
-    clean = tmp_path / "c.json"
-    clean.write_text(json.dumps(_bench_capture(value=102.0)))  # 2% jitter
-    assert perf.main(["--compare", str(a), str(clean)]) == 0
-
-    # direction-awareness: a 30% THROUGHPUT/mfu drop also regresses
-    slow = tmp_path / "d.json"
-    slow.write_text(json.dumps(_bench_capture(value=100.0, tokens=3000.0,
-                                              mfu=0.1)))
-    assert perf.main(["--compare", str(a), str(slow)]) == 1
-    # ...and a throughput GAIN does not
-    fast = tmp_path / "e.json"
-    fast.write_text(json.dumps(_bench_capture(value=100.0, tokens=9000.0)))
-    assert perf.main(["--compare", str(a), str(fast)]) == 0
-
-
-def test_metric_direction_classifies_real_bench_keys():
-    """Direction heuristic pins: every real bench key family judges the
-    right way. ``*_ms_per_step`` is the trap — a greedy higher-better
-    throughput pattern ("per_s") used to swallow it."""
-    from metisfl_tpu.perf import metric_direction
-
-    for key in ("train_ms_per_step", "lm_b8_dense_ms_per_step",
-                "cohort_1024_insert_s", "peak_rss_kb", "value",
-                "hot_swap_pause_ms", "store_disk_select_all_ms"):
-        assert metric_direction(key) == -1, key
-    for key in ("train_samples_per_sec", "lm_tokens_per_sec",
-                "serving_batched_rows_per_sec", "mfu", "vs_baseline",
-                "lm_achieved_tflops", "store_cached_hit_rate"):
-        assert metric_direction(key) == 1, key
-    # identity/bookkeeping keys are never judged
-    for key in ("num_learners", "rounds", "lm_flops_per_step"):
-        assert metric_direction(key) == 0, key
-
-
-def test_perf_trajectory_parses_driver_and_degraded_captures(tmp_path,
-                                                             capsys):
-    """--trajectory walks a bench_results-style dir: raw results, driver
-    {tail, parsed} captures, and degraded tails recovered via the
-    METISFL_BENCH marker line all judge; a marker-less truncated tail
-    (the BENCH_r05 failure shape) is skipped, not fatal."""
-    from metisfl_tpu import perf
-
-    d = tmp_path / "captures"
-    d.mkdir()
-    # r1: raw bench result file
-    (d / "r1.json").write_text(json.dumps(_bench_capture(value=100.0)))
-    # r2: driver capture with parsed payload
-    (d / "r2.json").write_text(json.dumps(
-        {"n": 2, "cmd": "python bench.py", "rc": 0, "tail": "",
-         "parsed": _bench_capture(value=98.0)}))
-    # r3: driver capture, parsed=null, tail holds the full result line
-    (d / "r3.json").write_text(json.dumps(
-        {"n": 3, "rc": 0, "parsed": None,
-         "tail": "noise\n" + json.dumps(_bench_capture(value=101.0))
-                 + "\n"}))
-    # r4: degraded — head-truncated tail, only the final marker survives
-    marker = {"schema_version": 2, "metric": "agg", "value": 99.0,
-              "unit": "ms", "vs_baseline": 20.2, "mfu": 0.2, "errors": 1}
-    (d / "r4.json").write_text(json.dumps(
-        {"n": 4, "rc": 0, "parsed": None,
-         "tail": 'per_sec": 5000, "trunc...\n'
-                 + "METISFL_BENCH " + json.dumps(marker) + "\n"}))
-    # r5: the old failure shape — truncated, no marker: skipped
-    (d / "r5.json").write_text(json.dumps(
-        {"n": 5, "rc": 0, "parsed": None, "tail": '": 48.2, "cohort_10'}))
-    assert perf.main(["--trajectory", str(d)]) == 0
-    err = capsys.readouterr().err
-    assert "r5.json" in err and "unparseable" in err
-
-    # inject a regression at the end of the series → exit 1
-    (d / "r6.json").write_text(json.dumps(_bench_capture(value=140.0)))
-    assert perf.main(["--trajectory", str(d)]) == 1
-
-
-def test_bench_emits_schema_version_and_final_marker(capsys, monkeypatch):
-    import bench
-
-    result = bench._result_from(
-        {"ms_per_round_median": 123.0, "mfu": 0.21}, {"mfu": "x"}, 8)
-    assert result["schema_version"] == bench.SCHEMA_VERSION == 2
-    bench._emit(result)
-    lines = capsys.readouterr().out.strip().splitlines()
-    assert json.loads(lines[0])["value"] == 123.0
-    assert lines[-1].startswith(bench.BENCH_MARKER)
-    marker = json.loads(lines[-1][len(bench.BENCH_MARKER):])
-    assert marker["schema_version"] == 2
-    assert marker["value"] == 123.0
-    assert marker["mfu"] == 0.21
-    assert marker["errors"] == 1
-    # the marker prefix is the contract the perf parser anchors on
-    from metisfl_tpu import perf
-
-    assert bench.BENCH_MARKER == perf.BENCH_MARKER
-
 
 def test_span_self_times_subtract_children():
     from metisfl_tpu import perf
@@ -874,24 +750,9 @@ def test_serving_gateway_wires_queue_probe_into_collector(clean_telemetry):
     assert coll.serving_probe is None
 
 
-def test_compare_does_not_credit_lower_better_collapse_to_zero():
-    """A lower-better metric at 0 in capture B means the subsystem
-    recorded nothing — skipped, not an 'improvement' that passes CI. A
-    higher-better metric collapsing to 0 is still a regression."""
-    from metisfl_tpu import perf
-
-    rows = perf.compare_captures({"swap_pause_ms": 12.0},
-                                 {"swap_pause_ms": 0.0})
-    assert rows == []
-    rows = perf.compare_captures({"train_samples_per_sec": 30.0},
-                                 {"train_samples_per_sec": 0.0})
-    assert len(rows) == 1 and rows[0]["regressed"]
-
-
 def test_perf_waterfall_unreadable_input_exits_2(tmp_path, capsys):
     """A missing or corrupt experiment.json path exits 2 with a clean
-    stderr message (the compare modes' unusable-input code), never a
-    traceback."""
+    stderr message, never a traceback."""
     from metisfl_tpu import perf
 
     assert perf.main([str(tmp_path / "nope-experiment.json")]) == 2
@@ -959,35 +820,3 @@ def test_collector_close_releases_sink_handle(tmp_path):
     coll.persist({"round": 1, "phases": {}})
     assert sum(1 for _ in open(coll.profiles_path())) == 2
     coll.close()
-
-
-def test_bench_marker_single_definition():
-    """bench.py shares the parser's BENCH_MARKER constant — the
-    degraded-capture anchor cannot drift between writer and reader."""
-    import bench as bench_mod
-
-    from metisfl_tpu import perf
-
-    assert bench_mod.BENCH_MARKER is perf.BENCH_MARKER
-
-
-def test_compare_flags_collapsed_failed_capture(tmp_path, capsys):
-    """A bench run that degraded to the *_failed shape (value zero-filled,
-    detail keys gone) must not pass the CI gate by having nothing left to
-    judge: --compare exits 1 on the headline collapse."""
-    from metisfl_tpu import perf
-
-    healthy = tmp_path / "a.json"
-    healthy.write_text(json.dumps({
-        "schema_version": 2, "metric": "aggregation_ms_per_round_8learners",
-        "value": 250.0, "unit": "ms", "vs_baseline": 8.0,
-        "details": {"ms_per_round_median": 250.0}}))
-    failed = tmp_path / "b.json"
-    failed.write_text(json.dumps({
-        "schema_version": 2, "metric": "aggregation_ms_per_round_failed",
-        "value": 0.0, "unit": "ms", "vs_baseline": 0.0,
-        "details": {"error": "boom"}}))
-    assert perf.main(["--compare", str(healthy), str(failed)]) == 1
-    assert "collapsed" in capsys.readouterr().err
-    # the same pair through --trajectory regresses too
-    assert perf.main(["--trajectory", str(healthy), str(failed)]) == 1

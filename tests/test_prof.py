@@ -8,8 +8,7 @@ NOT counted as contention), the opt-out pins (raw locks + stub reply +
 no sampler thread), the CollectTelemetry prof section and the
 FleetCollector's per-peer absorption + peer-prefixed merge + dump, the
 RoundProfile per-round stack delta, perf --flame / --flame-diff
-(including the injected lock-hold differential), the bench noise-floor
-repeats (median-of-K + the perf repeats field), post-mortem prof
+(including the injected lock-hold differential), post-mortem prof
 snapshots, config validation + template pins, and the DriverSession
 acceptance federation (controller + 2 learners + 2 slice aggregators
 over real gRPC with per-peer hot-frame attribution).
@@ -547,67 +546,6 @@ def test_flame_diff_surfaces_injected_lock_hold(clean_prof, tmp_path,
                     if "prof.acquire" in line]
     assert acquire_rows, out
     assert any("+" in line for line in acquire_rows)
-
-
-# --------------------------------------------------------------------- #
-# bench noise floor: median-of-K repeats + the perf repeats field
-# --------------------------------------------------------------------- #
-
-def test_bench_repeat_noisy_keys_median(monkeypatch):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_prof_test", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    runs = iter([
-        {"obs_expose_ms_10k_exact": 40.0, "obs_bytes": 100},
-        {"obs_expose_ms_10k_exact": 22.0, "obs_bytes": 101},
-    ])
-    monkeypatch.setattr(
-        bench, "_run_section",
-        lambda name, quick, timeout, errors, **kw: next(runs))
-    first = {"obs_expose_ms_10k_exact": 30.0, "obs_bytes": 99,
-             "obs_big_ms": 800.0}
-    details = dict(first)
-    monkeypatch.setenv("METISFL_BENCH_REPEATS", "3")
-    bench._repeat_noisy_keys("obs", first, False, details)
-    # the sub-threshold ms key became the median of 3 samples
-    assert details["obs_expose_ms_10k_exact"] == 30.0
-    assert details["repeats"] == {"obs_expose_ms_10k_exact": 3}
-    # non-ms and above-threshold keys keep their single shot
-    assert details["obs_bytes"] == 99
-    assert details["obs_big_ms"] == 800.0
-
-
-def test_compare_carries_repeats_field(capsys):
-    from metisfl_tpu import perf
-
-    a = {"metric": "m", "value": 10.0, "host": "h",
-         "details": {"obs_expose_ms": 20.0,
-                     "repeats": {"obs_expose_ms": 3}}}
-    b = {"metric": "m", "value": 10.0, "host": "h",
-         "details": {"obs_expose_ms": 21.0}}
-    rows = perf.compare_captures(perf.flatten_bench(a),
-                                 perf.flatten_bench(b))
-    row = next(r for r in rows if r["key"] == "obs_expose_ms")
-    assert row["repeats"] == 3
-    rendered = perf.render_comparison(rows, show_all=True)
-    assert "x3" in rendered
-    # single-shot keys render without the marker
-    assert "value" in rendered and "x1" not in rendered
-
-
-def test_prof_bench_keys_direction_classified():
-    from metisfl_tpu import perf
-
-    assert perf.metric_direction("prof_round_ms_off") == -1
-    assert perf.metric_direction("prof_round_ms_on") == -1
-    assert perf.metric_direction("prof_sample_ms") == -1
-    assert perf.metric_direction("prof_acquire_ns_timed") == -1
-    # the overhead ratio is deliberately informational (noise of noise)
-    assert perf.metric_direction("prof_overhead_pct") == 0
 
 
 # --------------------------------------------------------------------- #

@@ -178,7 +178,7 @@ def test_async_chunked(echo_server, monkeypatch):
 
 
 def test_async_future_resolves_with_final_outcome(echo_server, monkeypatch):
-    """Regression (ADVICE r5 double signal): the future call_async returns
+    """Regression (the double signal): the future call_async returns
     must resolve only with the FINAL outcome. On the unary-oversize →
     chunked retry the old code handed back the grpc future of the FAILED
     unary attempt, so a caller inspecting it saw RESOURCE_EXHAUSTED for a

@@ -11,8 +11,8 @@ pass-through wrapper, listener never installed — subprocess-proven), the
 CollectTelemetry runtime section and the FleetCollector's absorb /
 merge / dump, status --fleet's runtime: and ha: lines, perf
 --compile-report from both a fleet dump and raw jax.compile trace
-spans, post-mortem bundles, config validation + template pins, bench
-key direction classification, and the PR 13 slot-decoder regression:
+spans, post-mortem bundles, config validation + template pins, and the
+PR 13 slot-decoder regression:
 steady-state decode is zero-recompile after warmup while an
 over-LRU-bound prompt-length sweep provably shows up in the counters.
 """
@@ -521,7 +521,7 @@ def test_slot_decoder_steady_state_is_zero_recompile(clean_runtime):
 
 
 # --------------------------------------------------------------------- #
-# config validation + template pins + constants + bench directions
+# config validation + template pins + constants
 # --------------------------------------------------------------------- #
 
 def test_runtime_config_validation():
@@ -578,16 +578,3 @@ def test_runtime_metric_constants_match_module():
     # the HA satellite's standby-lag gauge (controller/__main__.py)
     assert (telemetry.M_CONTROLLER_WAL_LAG_RECORDS
             == "controller_wal_lag_records")
-
-
-def test_runtime_bench_keys_direction_classified():
-    from metisfl_tpu import perf
-
-    assert perf.metric_direction("runtime_decode_recompiles_len8") == -1
-    assert perf.metric_direction("runtime_decode_recompiles_len64") == -1
-    assert perf.metric_direction("runtime_listener_overhead_ns") == -1
-    assert perf.metric_direction("runtime_cold_compile_ms") == -1
-    assert perf.metric_direction("runtime_cached_call_ms") == -1
-    # raw totals are informational (a new monitored site is not a
-    # regression), the listener-mode flag is a boolean
-    assert perf.metric_direction("runtime_compiles") == 0

@@ -567,55 +567,6 @@ def test_tree_tier_ignored_for_full_cohort_rules():
 
 
 # --------------------------------------------------------------------- #
-# CI bench gate
-# --------------------------------------------------------------------- #
-
-def _capture(path, insert_s):
-    import json
-
-    path.write_text(json.dumps({
-        "schema_version": 2, "metric": "aggregation_ms_per_round_64learners",
-        "value": 80.0, "unit": "ms", "vs_baseline": 1.0,
-        "details": {"cohort_1024_insert_s": insert_s,
-                    "cohort_ingest_workers": [1, 4, 16],
-                    "round_10k_wall_s": 12.5}}))
-    return str(path)
-
-
-def test_check_bench_script_gates_ingest_regression(tmp_path):
-    """scripts/check_bench.sh passes on improvement, FAILS the build on
-    an ingest-throughput regression, and fails on an unparseable capture
-    (a result that cannot be judged must not pass)."""
-    import os
-    import subprocess
-    import sys
-
-    script = os.path.join(os.path.dirname(__file__), "..", "scripts",
-                          "check_bench.sh")
-    fast = _capture(tmp_path / "fast.json", 5.8)
-    slow = _capture(tmp_path / "slow.json", 48.2)
-    env = dict(os.environ, PYTHON=sys.executable)
-
-    def run(*args):
-        return subprocess.run(["bash", script, *args], env=env,
-                              capture_output=True, text=True).returncode
-
-    assert run(slow, fast) == 0       # improvement passes
-    assert run(fast, slow) == 1       # regression fails the build
-    garbage = tmp_path / "bad.json"
-    garbage.write_text("not json")
-    assert run(fast, str(garbage)) == 2  # unjudgeable fails too
-    # directory mode compares the newest two BENCH_*.json
-    bdir = tmp_path / "captures"
-    bdir.mkdir()
-    _capture(bdir / "BENCH_r05.json", 48.2)
-    _capture(bdir / "BENCH_r06.json", 5.8)
-    assert run(str(bdir)) == 0
-    _capture(bdir / "BENCH_r07.json", 70.0)
-    assert run(str(bdir)) == 1
-
-
-# --------------------------------------------------------------------- #
 # soak scale (tier-2)
 # --------------------------------------------------------------------- #
 

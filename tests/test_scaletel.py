@@ -832,27 +832,6 @@ def test_status_alerts_quiet_line():
 
 
 # --------------------------------------------------------------------- #
-# perf direction classification for the obs bench keys (satellite)
-# --------------------------------------------------------------------- #
-
-
-def test_obs_bench_keys_are_direction_classified():
-    from metisfl_tpu.perf import compare_captures, metric_direction
-
-    for key in ("obs_expose_ms_100k_sketch", "obs_expose_bytes_100k_exact",
-                "obs_describe_bytes_10k_sketch", "obs_ckpt_bytes_1k_exact",
-                "obs_q99_relerr_100k"):
-        assert metric_direction(key) == -1, key
-    assert metric_direction("obs_budget") == 0
-    # a 3x exposition-time regression past the threshold is flagged
-    a = {"obs_expose_ms_100k_sketch": 2.0, "obs_q99_relerr_100k": 0.001}
-    b = {"obs_expose_ms_100k_sketch": 6.0, "obs_q99_relerr_100k": 0.03}
-    rows = {r["key"]: r for r in compare_captures(a, b)}
-    assert rows["obs_expose_ms_100k_sketch"]["regressed"]
-    assert rows["obs_q99_relerr_100k"]["regressed"]
-
-
-# --------------------------------------------------------------------- #
 # cross-device harness at scale (the tentpole's acceptance scenario)
 # --------------------------------------------------------------------- #
 

@@ -35,6 +35,15 @@ def _ops(seed=0, outputs=3):
                         np.zeros((2, 4), np.float32), rng_seed=seed)
 
 
+def _sized_ops(size):
+    """(engine, input width): the toy every test here serves, or an MLP
+    of 1.38M parameters, the width of a model a federation would serve."""
+    if size == "toy":
+        return _ops(), 4
+    return FlaxModelOps(MLP(features=(1024, 1024), num_outputs=64),
+                        np.zeros((2, 256), np.float32), rng_seed=0), 256
+
+
 def _gateway(canary_percent=0.0, max_batch=8, max_wait_ms=5.0, ops=None):
     ops = ops or _ops()
     gw = ServingGateway(ops, ServingConfig(
@@ -89,14 +98,17 @@ def test_microbatcher_error_propagates_per_request():
     batcher.close()
 
 
-def test_microbatch_results_bit_identical_to_unbatched(clean_telemetry):
+@pytest.mark.parametrize("size", ["toy", "1.38M"])
+def test_microbatch_results_bit_identical_to_unbatched(clean_telemetry,
+                                                       size):
     """The acceptance contract: coalescing must not change a single bit
     of any request's output (every forward pads to the same fixed-shape
     program, so per-row math is independent of batch composition)."""
-    gw, ops = _gateway(max_batch=8, max_wait_ms=20.0)
+    ops, dim = _sized_ops(size)
+    gw, ops = _gateway(max_batch=8, max_wait_ms=20.0, ops=ops)
     gw.install("stable", 1, pack_model(ops.get_variables()))
     rng = np.random.default_rng(0)
-    xs = [rng.standard_normal((3, 4)).astype(np.float32)
+    xs = [rng.standard_normal((3, dim)).astype(np.float32)
           for _ in range(6)]
     # unbatched: one request at a time through the same gateway
     singles = [gw.predict(x, key=f"k{i}")[0] for i, x in enumerate(xs)]
@@ -142,14 +154,16 @@ def test_oversized_request_chunks_through_the_bucket():
 # hot-swap + canary
 # ---------------------------------------------------------------------- #
 
-def test_hot_swap_drops_zero_inflight_requests(clean_telemetry):
+@pytest.mark.parametrize("size", ["toy", "1.38M"])
+def test_hot_swap_drops_zero_inflight_requests(clean_telemetry, size):
     import jax
 
-    gw, ops = _gateway(max_batch=4, max_wait_ms=2.0)
+    ops, dim = _sized_ops(size)
+    gw, ops = _gateway(max_batch=4, max_wait_ms=2.0, ops=ops)
     v1 = ops.get_variables()
     v2 = jax.tree.map(lambda a: np.asarray(a) * 2.0, v1)
     gw.install("stable", 1, pack_model(v1))
-    x = np.random.default_rng(2).standard_normal((2, 4)).astype(np.float32)
+    x = np.random.default_rng(2).standard_normal((2, dim)).astype(np.float32)
     errors, versions = [], set()
     stop = threading.Event()
 
@@ -336,16 +350,36 @@ def test_inprocess_federation_feeds_gateway(clean_telemetry):
         desc = fed.controller.describe_registry()
         assert desc["stable"] > 0, desc
 
+        # the federation runs on under all of this, rounds back to back,
+        # and with retention=3 a version that is no longer a head or the
+        # rollback target is erased once three newer ones stand: keep what
+        # the gateway fetched, for the registry may not have it when asked
+        # again (the predict below pays a compile in between)
+        fetched = {}
+
+        class KeepingSource(DirectRegistrySource):
+            def blob(self, version):
+                fetched[version] = super().blob(version)
+                return fetched[version]
+
+        source = KeepingSource(fed.controller)
         gw = ServingGateway(_ops(seed=0, outputs=2), config.serving)
-        installed = gw.sync(DirectRegistrySource(fed.controller))
+        installed = gw.sync(source)
+        deadline = time.time() + 30.0
+        while "stable" not in installed and time.time() < deadline:
+            # the head it was told of was erased under the poll: the next
+            # poll, as in the gateway's own loop, finds the newer one
+            installed = gw.sync(source)
         # the federation may promote again between the snapshot and the
         # sync — the gateway serves SOME promoted stable version
         assert installed.get("stable", 0) >= desc["stable"]
         outs, version, channel = gw.predict(x[:4], key="user1")
         assert outs.shape == (4, 2) and version == installed["stable"]
         # the served model IS the promoted community blob
-        blob = fed.controller.registered_model(version=version)
-        assert blob is not None
+        blob = fetched[version]
+        assert blob
+        still = fed.controller.registered_model(version=version)
+        assert still is None or still == blob
         ref_ops = _ops(seed=0, outputs=2)
         ref = ServingGateway(ref_ops, config.serving)
         ref.install("stable", version, blob)

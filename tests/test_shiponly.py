@@ -234,7 +234,7 @@ def test_never_trained_learner_evaluates_subset_blob():
 
 
 def test_eval_and_infer_clear_stale_ship_regex():
-    """Regression (ADVICE r5): run_eval/run_infer must adopt
+    """Regression: run_eval/run_infer must adopt
     ``task.ship_tensor_regex`` UNCONDITIONALLY, mirroring the train path
     — a regex-less task clears stale subset semantics from an earlier
     configuration instead of leaving them armed. The stale regex here

@@ -277,6 +277,39 @@ def test_rehome_mid_round_completes_bit_identical(tmp_path, caplog):
         np.testing.assert_array_equal(killed[k], control[k], err_msg=k)
 
 
+def test_rehome_at_a_thousand_clients_equals_the_flat_fold(tmp_path):
+    """One of three aggregators dies with its third of a thousand
+    clients' uplinks spooled and the reduce about to run: the slice
+    re-homes once, from the spool, and the reduced model is the flat
+    mean of all thousand, as the clean reduce before it was."""
+    rng = np.random.default_rng(23)
+    ids = [f"L{i:05d}" for i in range(1000)]
+    models = {lid: {"w": rng.standard_normal(256).astype(np.float32)}
+              for lid in ids}
+    scales = {lid: 1.0 / len(ids) for lid in ids}
+    flat = np.mean([m["w"] for m in models.values()], axis=0,
+                   dtype=np.float64)
+    servers, specs = _boot_servers(tmp_path, 3)
+    red = _reducer(specs)
+    try:
+        red.assign(ids)
+        for lid in ids:
+            assert red.submit(lid, models[lid], 0)
+        clean, partials, errors = red.reduce(ids, scales, stride=0,
+                                             round_id=0)
+        assert not errors and red.rehomed_total == 0
+        assert sum(p.count for p in partials) == len(ids)
+        servers[0].stop()
+        rehomed, partials, _ = red.reduce(ids, scales, stride=0, round_id=1)
+        assert red.rehomed_total == 1
+        assert len(partials) == 3
+        assert sum(p.count for p in partials) == len(ids)
+    finally:
+        _stop_all(servers, red)
+    np.testing.assert_allclose(clean["w"], flat, atol=1e-6)
+    np.testing.assert_array_equal(rehomed["w"], clean["w"])
+
+
 def test_rehome_event_records_target_and_recovery(tmp_path):
     servers, specs = _boot_servers(tmp_path, 2)
     red = _reducer(specs)

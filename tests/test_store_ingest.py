@@ -387,6 +387,47 @@ def _make_backend(kind: str, root) -> ModelStore:
     return InMemoryModelStore()
 
 
+@pytest.mark.parametrize("kind", ["memory", "disk", "cached"])
+def test_select_at_model_width_returns_each_learners_two_newest(tmp_path,
+                                                                kind):
+    """Three rounds of eight learners' twelve-tensor CNN models (1.41M
+    parameters each) under a lineage of two: the select of all returns
+    every learner's two newest, newest first, every tensor whole; the
+    cached store, budgeted to that working set, serves it without one
+    read of the disk."""
+    from tests.test_aggregation import CNN_SHAPES, cnn_models
+
+    base = cnn_models(8, seed=5)
+    ids = [f"learner_{i}" for i in range(len(base))]
+    nbytes = sum(a.nbytes for a in base[0].values())
+    policy = dict(policy=EvictionPolicy.LINEAGE_LENGTH, lineage_length=2)
+    if kind == "memory":
+        store = InMemoryModelStore(**policy)
+    elif kind == "disk":
+        store = DiskModelStore(str(tmp_path), **policy)
+    else:
+        store = CachedDiskStore(str(tmp_path), **policy,
+                                cache_bytes=nbytes * (2 * len(ids) + 1))
+    for r in range(3):
+        for lid, model in zip(ids, base):
+            store.insert(lid, {n: a + np.float32(r)
+                               for n, a in model.items()})
+    selected = store.select(ids, k=2)
+    assert sorted(selected) == ids
+    for lid, model in zip(ids, base):
+        lineage = selected[lid]
+        assert len(lineage) == 2
+        for got, r in zip(lineage, (2, 1)):
+            assert set(got) == set(CNN_SHAPES)
+            for name, arr in model.items():
+                np.testing.assert_array_equal(
+                    np.asarray(got[name]), arr + np.float32(r),
+                    err_msg=f"{lid} {name} round {r}")
+    if kind == "cached":
+        assert store.cache_misses == 0 and store.cache_hits >= 2 * len(ids)
+        assert store._cached_total == 2 * len(ids) * nbytes
+
+
 @pytest.mark.parametrize("kind", ["disk", "cached", "memory"])
 def test_concurrent_insert_select_erase_hammer(tmp_path, kind):
     """The contract regression test: 8 threads hammer insert/select/erase
