@@ -34,6 +34,12 @@ _M_CODEC = _tmetrics.registry().histogram(
 _M_CODEC_BYTES = _tmetrics.registry().counter(
     _tel.M_CODEC_BYTES_TOTAL, "Message codec bytes by operation", ("op",))
 _SPAN_MIN_BYTES = 1 << 18
+# The codec never copies a bytes value this large (a model blob riding a
+# message): ``dumps_segments`` hands the object through as a segment of
+# its own, and a decode asked for views (``loads(buf, views=...)``)
+# returns a slice of the request buffer. Segments and views are an
+# in-process matter; the bytes on the wire and on disk are ``dumps``'s.
+BORROW_MIN_BYTES = _SPAN_MIN_BYTES
 
 # Per-learner codec attribution (performance observatory): call sites
 # that know which learner a message belongs to wrap the encode in
@@ -123,7 +129,31 @@ def _unzigzag(value: int) -> int:
     return (value >> 1) ^ -(value & 1)
 
 
-def _encode(out: bytearray, value: Any) -> None:
+class Segments:
+    """One encoded message as ordered buffers: ``b"".join(parts)`` is the
+    message. Heads and tails are ``bytes`` the encoder wrote; a bulk
+    value is the very object the caller passed in, so whoever holds a
+    ``Segments`` keeps those alive and must not mutate them. ``len()``
+    is the message's size in bytes; re-iterable (a retry re-sends)."""
+
+    __slots__ = ("parts", "nbytes", "borrowed_bytes")
+
+    def __init__(self, parts, borrowed_bytes: int = 0):
+        self.parts = tuple(parts)
+        self.nbytes = sum(len(p) for p in self.parts)
+        self.borrowed_bytes = borrowed_bytes
+
+    def __len__(self) -> int:
+        return self.nbytes
+
+    def __bytes__(self) -> bytes:
+        return b"".join(self.parts)
+
+
+def _encode(out: bytearray, value: Any, segs: list) -> None:
+    """Append ``value``'s encoding to ``out``. A bulk bytes value closes
+    the head written so far into ``segs``, follows it as a segment of its
+    own, and ``out`` (emptied) goes on as the tail."""
     # Coerce numpy scalars (jit outputs land here via metric dicts).
     if isinstance(value, np.generic):
         value = value.item()
@@ -145,16 +175,23 @@ def _encode(out: bytearray, value: Any) -> None:
         _write_varint(out, len(encoded))
         out.extend(encoded)
     elif isinstance(value, (bytes, bytearray, memoryview)):
-        if isinstance(value, memoryview) and (value.itemsize != 1 or value.ndim != 1):
+        if isinstance(value, memoryview) and (
+                value.itemsize != 1 or value.ndim != 1
+                or not value.c_contiguous):
             value = bytes(value)  # measure/extend in bytes, not elements
         out.append(_T_BYTES)
         _write_varint(out, len(value))
-        out.extend(value)
+        if len(value) >= BORROW_MIN_BYTES:
+            segs.append(bytes(out))
+            segs.append(value)
+            out.clear()
+        else:
+            out.extend(value)
     elif isinstance(value, (list, tuple)):
         out.append(_T_LIST)
         _write_varint(out, len(value))
         for item in value:
-            _encode(out, item)
+            _encode(out, item, segs)
     elif isinstance(value, dict):
         out.append(_T_DICT)
         _write_varint(out, len(value))
@@ -164,28 +201,59 @@ def _encode(out: bytearray, value: Any) -> None:
             encoded = key.encode("utf-8")
             _write_varint(out, len(encoded))
             out.extend(encoded)
-            _encode(out, item)
+            _encode(out, item, segs)
     else:
         raise TypeError(f"codec cannot encode {type(value)!r}")
 
 
-def dumps(value: Any) -> bytes:
+def _encode_segments(value: Any) -> Segments:
     out = bytearray()
+    segs: list = []
+    _encode(out, value, segs)
+    # head, bulk, head, bulk, ..., tail: the bulk values sit at the odd
+    # places, each behind the (never empty) head that frames it
+    borrowed = sum(len(p) for p in segs[1::2])
+    if out or not segs:
+        segs.append(bytes(out))
+    return Segments(segs, borrowed)
+
+
+def _dumps(value: Any, join: bool):
     if not _tmetrics.enabled():
-        _encode(out, value)
-        return bytes(out)
+        message = _encode_segments(value)
+        return bytes(message) if join else message
     t0 = time.perf_counter()
-    _encode(out, value)
-    buf = bytes(out)
+    message = _encode_segments(value)
+    # joined, the bulk values are copied here after all: nothing borrowed
+    borrowed = 0 if join else message.borrowed_bytes
+    if join:
+        message = bytes(message)
     elapsed = time.perf_counter() - t0
+    nbytes = len(message)
     _M_CODEC.observe(elapsed, op="encode")
-    _M_CODEC_BYTES.inc(len(buf), op="encode")
+    _M_CODEC_BYTES.inc(nbytes, op="encode")
+    if borrowed:
+        _M_CODEC_BYTES.inc(borrowed, op="encode_borrowed")
     lid = _ATTR.get()
     if lid:
         attribute(lid, "encode", elapsed)
-    if len(buf) >= _SPAN_MIN_BYTES:
-        _ttrace.event("codec.encode", elapsed, attrs={"bytes": len(buf)})
-    return buf
+    if nbytes >= _SPAN_MIN_BYTES:
+        _ttrace.event("codec.encode", elapsed,
+                      attrs={"bytes": nbytes, "borrowed_bytes": borrowed})
+    return message
+
+
+def dumps(value: Any) -> bytes:
+    return _dumps(value, join=True)
+
+
+def dumps_segments(value: Any) -> Segments:
+    """``dumps`` without the copies: ``bytes(dumps_segments(v)) ==
+    dumps(v)`` for any value, and every bytes value of at least
+    ``BORROW_MIN_BYTES`` is a segment that ``is`` the object passed in.
+    For sinks that gather (the chunked stream, a file): the one copy left
+    is theirs."""
+    return _dumps(value, join=False)
 
 
 def _read_varint(view: memoryview, offset: int) -> tuple[int, int]:
@@ -222,7 +290,11 @@ def _take(view: memoryview, offset: int, length: int) -> tuple[memoryview, int]:
 _MAX_DEPTH = 100
 
 
-def _decode(view: memoryview, offset: int, depth: int = 0) -> tuple[Any, int]:
+def _decode(view: memoryview, offset: int, depth: int = 0,
+            views=()) -> tuple[Any, int]:
+    """``views``: keys of the TOP-LEVEL dict whose bytes value, when it
+    is bulk, comes back as a read-only slice of ``view`` (no copy; it
+    keeps the whole buffer alive). Everything else decodes to ``bytes``."""
     if depth > _MAX_DEPTH:
         raise ValueError(f"codec: nesting exceeds {_MAX_DEPTH} levels")
     if offset >= len(view):
@@ -263,31 +335,51 @@ def _decode(view: memoryview, offset: int, depth: int = 0) -> tuple[Any, int]:
             klen, offset = _read_varint(view, offset)
             raw, offset = _take(view, offset, klen)
             key = bytes(raw).decode("utf-8")
-            result[key], offset = _decode(view, offset, depth + 1)
+            if (views and depth == 0 and key in views
+                    and offset < len(view) and view[offset] == _T_BYTES):
+                vlen, offset = _read_varint(view, offset + 1)
+                raw, offset = _take(view, offset, vlen)
+                result[key] = (raw.toreadonly() if vlen >= BORROW_MIN_BYTES
+                               else bytes(raw))
+            else:
+                result[key], offset = _decode(view, offset, depth + 1)
         return result, offset
     raise ValueError(f"codec: unknown tag 0x{tag:02x} at offset {offset - 1}")
 
 
-def loads(buf) -> Any:
+def loads(buf, views=()) -> Any:
+    """Decode one value. ``views`` names keys of a top-level dict whose
+    bulk bytes value (``BORROW_MIN_BYTES`` and up) the caller takes as a
+    read-only ``memoryview`` over ``buf`` in place of a copy: for a
+    consumer that reads it once and lets go (``ModelBlob.from_bytes``),
+    since the slice keeps all of ``buf`` alive. Without it every bytes
+    value is ``bytes``."""
     if not _tmetrics.enabled():
-        return _loads(buf)
+        return _loads(buf, views)
     t0 = time.perf_counter()
-    value = _loads(buf)
+    value = _loads(buf, views)
     elapsed = time.perf_counter() - t0
     nbytes = memoryview(buf).nbytes
+    borrowed = 0
+    if views and isinstance(value, dict):
+        borrowed = sum(value[k].nbytes for k in views
+                       if isinstance(value.get(k), memoryview))
     _M_CODEC.observe(elapsed, op="decode")
     _M_CODEC_BYTES.inc(nbytes, op="decode")
+    if borrowed:
+        _M_CODEC_BYTES.inc(borrowed, op="decode_borrowed")
     lid = _ATTR.get()
     if lid:
         attribute(lid, "decode", elapsed)
     if nbytes >= _SPAN_MIN_BYTES:
-        _ttrace.event("codec.decode", elapsed, attrs={"bytes": nbytes})
+        _ttrace.event("codec.decode", elapsed,
+                      attrs={"bytes": nbytes, "borrowed_bytes": borrowed})
     return value
 
 
-def _loads(buf) -> Any:
+def _loads(buf, views=()) -> Any:
     view = memoryview(buf)
-    value, offset = _decode(view, 0)
+    value, offset = _decode(view, 0, 0, views)
     if offset != len(view):
         # trailing bytes mean a framing error (truncated write spliced with
         # the next frame, corrupt length prefix): decoding a prefix and

@@ -23,7 +23,7 @@ import functools
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, get_type_hints
 
-from metisfl_tpu.comm.codec import dumps, loads
+from metisfl_tpu.comm.codec import Segments, dumps, dumps_segments, loads
 
 
 @functools.lru_cache(maxsize=None)
@@ -33,6 +33,11 @@ def _hints_for(cls):
 
 class Message:
     """Base: dataclass ⇄ codec bytes, with nested-message support."""
+
+    # bytes fields that ``from_wire`` may hand over as a read-only view
+    # of the request buffer (codec.loads ``views``) when they are bulk:
+    # only where every consumer takes a buffer and none keeps it
+    _VIEW_FIELDS = ()
 
     def to_dict(self) -> dict:
         out = {}
@@ -67,9 +72,14 @@ class Message:
     def to_wire(self) -> bytes:
         return dumps(self.to_dict())
 
+    def to_segments(self) -> Segments:
+        """``to_wire`` for a sink that gathers (``RpcClient``): the same
+        bytes, with a bulk field riding as the object it is."""
+        return dumps_segments(self.to_dict())
+
     @classmethod
     def from_wire(cls, buf):
-        return cls.from_dict(loads(buf))
+        return cls.from_dict(loads(buf, views=cls._VIEW_FIELDS))
 
 
 def _nested_message_type(hint):
@@ -222,6 +232,7 @@ class TrainTask(Message):
     round_id: int = 0
     global_iteration: int = 0
     model: bytes = b""          # ModelBlob wire bytes (community model)
+    _VIEW_FIELDS = ("model",)   # read by _load_model, then let go
     params: TrainParams = field(default_factory=TrainParams)
     # SCAFFOLD (aggregation.rule='scaffold'): ``scaffold`` marks the task
     # as control-variate-corrected (the learner must report a delta even
@@ -252,6 +263,7 @@ class TaskResult(Message):
     controller_epoch: str = ""
     round_id: int = 0
     model: bytes = b""          # locally trained ModelBlob
+    _VIEW_FIELDS = ("model",)   # read by _parse_result_model, then let go
     num_train_examples: int = 0
     completed_steps: int = 0
     completed_epochs: float = 0.0
@@ -289,6 +301,7 @@ class EvalTask(Message):
     learner_id: str = ""
     round_id: int = 0
     model: bytes = b""
+    _VIEW_FIELDS = ("model",)   # read by _load_model, then let go
     batch_size: int = 256
     datasets: List[str] = field(default_factory=lambda: ["test"])
     metrics: List[str] = field(default_factory=lambda: ["loss", "accuracy"])

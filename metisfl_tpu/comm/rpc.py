@@ -31,6 +31,7 @@ from typing import Callable, Dict, Optional
 import grpc
 
 from metisfl_tpu import chaos as _chaos
+from metisfl_tpu.comm.codec import Segments
 from metisfl_tpu.telemetry import events as _events
 from metisfl_tpu import telemetry as _tel
 from metisfl_tpu.telemetry import metrics as _metrics
@@ -122,13 +123,32 @@ _CHUNK_SUFFIX = "Chunked"
 _OVERSIZE_MARK = "response exceeds unary framing; retry chunked"
 
 
-def _iter_chunks(payload: bytes):
-    if not payload:
+def _as_bytes(payload) -> bytes:
+    """A payload is ``bytes`` or a codec ``Segments``; what takes one
+    message whole (a unary call, a chaos injector) gets it joined."""
+    return bytes(payload) if isinstance(payload, Segments) else payload
+
+
+def _iter_chunks(payload):
+    """Frames of ``CHUNK_BYTES`` (the last one shorter) over a payload's
+    bytes, filled across segment borders: the one copy gRPC needs, and
+    the same frames whether the payload came joined or in segments."""
+    if not len(payload):
         yield b""
         return
-    view = memoryview(payload)
-    for i in range(0, len(payload), CHUNK_BYTES):
-        yield bytes(view[i : i + CHUNK_BYTES])
+    parts = payload.parts if isinstance(payload, Segments) else (payload,)
+    frame, room = [], CHUNK_BYTES
+    for part in parts:
+        view = memoryview(part)
+        while len(view):
+            piece, view = view[:room], view[room:]
+            frame.append(piece)
+            room -= len(piece)
+            if not room:
+                yield b"".join(frame)
+                frame, room = [], CHUNK_BYTES
+    if frame:
+        yield b"".join(frame)
 
 
 class BytesService:
@@ -429,15 +449,20 @@ class RpcClient:
         # calls skip the fail-then-retry (which runs the handler twice)
         self._chunked_methods: set = set()
 
-    def call(self, method: str, payload: bytes, timeout: Optional[float] = None,
+    def call(self, method: str, payload, timeout: Optional[float] = None,
              wait_ready: bool = True, idempotent: bool = False) -> bytes:
-        """``idempotent=True`` additionally retries DEADLINE_EXCEEDED —
+        """``payload``: ``bytes``, or a codec ``Segments`` (the chunked
+        stream gathers it frame by frame; the unary path joins it once).
+
+        ``idempotent=True`` additionally retries DEADLINE_EXCEEDED —
         only safe for methods whose re-execution cannot double-apply
         (getters, join/rejoin, health)."""
         if timeout is None:
             timeout = self.default_deadline_s
         chunked = (len(payload) > STREAM_THRESHOLD
                    or method in self._chunked_methods)
+        if not chunked:
+            payload = _as_bytes(payload)
         attempt = 0
         retried = 0
         t0 = time.perf_counter()
@@ -446,7 +471,8 @@ class RpcClient:
                 try:
                     inj = _chaos.get()
                     send = (payload if inj is None else inj.intercept(
-                        "client", self.service_name, method, payload))
+                        "client", self.service_name, method,
+                        _as_bytes(payload)))
                     if chunked:
                         result = self._call_chunked(method, send, timeout,
                                                     wait_ready)
@@ -503,7 +529,7 @@ class RpcClient:
             # inside (the regression contract tests/test_rpc.py pins)
             self._record_client_call(method, str(retried), t0)
 
-    def _call_chunked(self, method: str, payload: bytes,
+    def _call_chunked(self, method: str, payload,
                       timeout: Optional[float], wait_ready: bool) -> bytes:
         fn = self._channel.stream_stream(
             f"/{self.service_name}/{method}{_CHUNK_SUFFIX}",
@@ -527,7 +553,7 @@ class RpcClient:
         except futures.InvalidStateError:  # pragma: no cover - cancelled
             pass
 
-    def call_async(self, method: str, payload: bytes,
+    def call_async(self, method: str, payload,
                    callback: Optional[Callable[[bytes], None]] = None,
                    error_callback: Optional[Callable[[Exception], None]] = None,
                    timeout: Optional[float] = None,
@@ -560,13 +586,14 @@ class RpcClient:
             # raises here, which dispatch paths already treat as a failed
             # dispatch (liveness accounting)
             payload = inj.intercept("client", self.service_name, method,
-                                    payload)
+                                    _as_bytes(payload))
         if (len(payload) > STREAM_THRESHOLD
                 or method in self._chunked_methods):
             self._async_chunked(method, payload, callback,
                                 error_callback, timeout, wait_ready,
                                 ctx=ctx, t0=t0, outer=outer)
             return outer
+        payload = _as_bytes(payload)
         fn = self._channel.unary_unary(
             f"/{self.service_name}/{method}",
             request_serializer=_IDENTITY,

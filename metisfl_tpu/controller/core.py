@@ -40,7 +40,7 @@ import numpy as np
 from metisfl_tpu.aggregation import make_aggregation_rule
 from metisfl_tpu.aggregation.base import host_fold_backend
 from metisfl_tpu.aggregation.secure import SecureAgg
-from metisfl_tpu.comm.codec import dumps as codec_dumps
+from metisfl_tpu.comm.codec import dumps_segments as codec_dumps_segments
 from metisfl_tpu.comm.codec import loads as codec_loads
 from metisfl_tpu.comm.messages import (
     EvalResult,
@@ -1589,7 +1589,9 @@ class Controller:
     def _parse_result_model(self, result: TaskResult):
         blob = ModelBlob.from_bytes(result.model)
         if self.config.secure.enabled:
-            return result.model if blob.opaque else dict(blob.tensors)
+            # the opaque blob is stored as it came: a copy of its own,
+            # never a view that would pin the whole request buffer
+            return bytes(result.model) if blob.opaque else dict(blob.tensors)
         tensors = dict(blob.tensors)
         if self.config.train.ship_dtype.lower() == "int8q":
             # int8q uplink: restore exact f32 before storage/aggregation.
@@ -2859,9 +2861,11 @@ class Controller:
             path = os.path.join(self.config.checkpoint.dir, self._CKPT_NAME)
         if state is None:
             state = self._checkpoint_state()
-        buf = codec_dumps(state)
+        # segments: the community blob (and the registry's retained ones)
+        # go to the file as the objects they are; same file bytes
+        buf = codec_dumps_segments(state)
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        _durable.atomic_write(path, buf, prefix=".ckpt_")
+        _durable.atomic_write(path, buf.parts, prefix=".ckpt_")
         return path
 
     def restore_checkpoint(self, path: Optional[str] = None) -> bool:

@@ -64,14 +64,16 @@ class RpcLearnerProxy:
                                  **_comm_kwargs(comm))
 
     @staticmethod
-    def _to_wire_attributed(task) -> bytes:
-        # attributed(): the envelope encode (which embeds the model blob)
-        # lands in the learner's codec_learner_seconds_total series;
-        # profile off → plain encode, no attribution series minted
+    def _to_wire_attributed(task):
+        # segments: the model blob rides the envelope as the object it
+        # is, and the transport gathers it (comm/codec.py Segments).
+        # attributed(): the envelope encode lands in the learner's
+        # codec_learner_seconds_total series; profile off → plain
+        # encode, no attribution series minted
         if _tprofile.collector() is None:
-            return task.to_wire()
+            return task.to_segments()
         with _codec.attributed(task.learner_id):
-            return task.to_wire()
+            return task.to_segments()
 
     def run_task(self, task: TrainTask) -> None:
         self._client.call_async("RunTask", self._to_wire_attributed(task))
@@ -382,7 +384,7 @@ class ControllerClient:
         return bool(loads(raw)["ok"])
 
     def task_completed(self, result: TaskResult) -> bool:
-        raw = self._call("MarkTaskCompleted", result.to_wire())
+        raw = self._call("MarkTaskCompleted", result.to_segments())
         return bool(loads(raw)["ok"])
 
     def replace_community_model(self, blob: bytes) -> bool:

@@ -44,7 +44,7 @@ import os
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
-from metisfl_tpu.comm.codec import dumps as codec_dumps
+from metisfl_tpu.comm.codec import dumps_segments as codec_dumps_segments
 from metisfl_tpu.comm.codec import loads as codec_loads
 from metisfl_tpu.store import durable as _durable
 
@@ -98,10 +98,11 @@ class RoundStateLog:
         with self._lock:
             self._seq += 1
             seq = self._seq
-        payload = codec_dumps({"seq": seq, "kind": kind, "data": data})
+        payload = codec_dumps_segments(
+            {"seq": seq, "kind": kind, "data": data})
         _durable.atomic_write(os.path.join(self.wal_dir,
                                            _record_name(seq, kind)),
-                              payload, prefix=".wal_")
+                              payload.parts, prefix=".wal_")
         return seq
 
     def snapshot(self, state: Dict[str, Any]) -> int:

@@ -41,16 +41,21 @@ def sanitize_id(identifier: str) -> str:
     return safe
 
 
-def atomic_write(path: str, payload: bytes, prefix: str = ".tmp_") -> None:
-    """Durably write ``payload`` to ``path``: unique temp file in the
-    target directory (concurrent writers never share a staging file),
-    then atomic ``os.replace``. On any failure the temp file is removed
-    and the previous content of ``path`` (if any) is untouched."""
+def atomic_write(path: str, payload, prefix: str = ".tmp_") -> None:
+    """Durably write ``payload`` (one buffer, or a sequence of buffers
+    written in order: a codec ``Segments.parts``) to ``path``: unique
+    temp file in the target directory (concurrent writers never share a
+    staging file), then atomic ``os.replace``. On any failure the temp
+    file is removed and the previous content of ``path`` (if any) is
+    untouched."""
     target_dir = os.path.dirname(path) or "."
+    if isinstance(payload, (bytes, bytearray, memoryview)):
+        payload = (payload,)
     fd, tmp = tempfile.mkstemp(dir=target_dir, prefix=prefix, suffix=".tmp")
     try:
         with os.fdopen(fd, "wb") as fh:
-            fh.write(payload)
+            for part in payload:
+                fh.write(part)
         os.replace(tmp, path)
     except BaseException:
         if os.path.exists(tmp):

@@ -1,5 +1,6 @@
 """gRPC bytes-transport unit tests."""
 
+import os
 import threading
 
 import pytest
@@ -422,6 +423,233 @@ def test_value_error_maps_to_invalid_argument(slow_server):
         assert "malformed widget" in err.value.details()
     finally:
         client.close()
+
+
+# ---------------------------------------------------------------------- #
+# segmented payloads (comm/codec.py Segments): gathered by the transport
+# ---------------------------------------------------------------------- #
+
+def _segmented(n_bulk: int = 2):
+    """A blob-bearing message as the codec hands it to the transport:
+    heads and tails around bulk segments that are the caller's objects."""
+    import os
+
+    from metisfl_tpu.comm.codec import BORROW_MIN_BYTES, dumps_segments
+
+    value = {"task_id": "t1", "round": 3}
+    for i in range(n_bulk):
+        value[f"model{i}"] = os.urandom(BORROW_MIN_BYTES + 1000 * i + 17)
+    value["tail"] = "x" * 100
+    segments = dumps_segments(value)
+    assert len(segments.parts) == 2 * n_bulk + 1
+    return segments, dumps(value)
+
+
+def test_chunk_frames_cross_segment_borders(monkeypatch):
+    """The chunker fills frames across segment borders: every frame but
+    the last is CHUNK_BYTES, and they are the frames of the joined
+    payload."""
+    from metisfl_tpu.comm import rpc
+    from metisfl_tpu.comm.codec import Segments
+
+    monkeypatch.setattr(rpc, "CHUNK_BYTES", 100_000)
+    segments, wire = _segmented()
+    frames = list(rpc._iter_chunks(segments))
+    assert frames == list(rpc._iter_chunks(wire))
+    assert all(type(f) is bytes for f in frames)
+    assert {len(f) for f in frames[:-1]} == {100_000}
+    assert 0 < len(frames[-1]) <= 100_000 and b"".join(frames) == wire
+    # re-iterable (a retry re-sends), and the empty payload is one frame
+    assert list(rpc._iter_chunks(segments)) == frames
+    assert list(rpc._iter_chunks(Segments([b""]))) == [b""]
+    assert list(rpc._iter_chunks(b"")) == [b""]
+    # a frame boundary landing exactly on a segment border
+    exact = Segments([b"a" * 100_000, b"b" * 200_000, b"c" * 5])
+    assert [len(f) for f in rpc._iter_chunks(exact)] == [
+        100_000, 100_000, 100_000, 5]
+
+
+@pytest.mark.parametrize("transport", ["unary", "chunked"])
+@pytest.mark.parametrize("mode", ["call", "call_async"])
+def test_segmented_payload_roundtrip(echo_server, monkeypatch, rpc_metrics,
+                                     transport, mode):
+    """A segment list round-trips over both methods, sync and async, as
+    the bytes of its join; one logical call, the message's bytes counted."""
+    from metisfl_tpu.comm import rpc
+
+    if transport == "chunked":
+        monkeypatch.setattr(rpc, "STREAM_THRESHOLD", 1024)
+        monkeypatch.setattr(rpc, "CHUNK_BYTES", 100_000)
+    port, state = echo_server
+    client = RpcClient("127.0.0.1", port, "test.Echo")
+    segments, wire = _segmented()
+    if mode == "call":
+        assert client.call("Echo", segments) == wire
+    else:
+        got = {}
+        future = client.call_async(
+            "Echo", segments, callback=lambda raw: got.update(raw=raw))
+        assert future.result(timeout=30) == wire
+        assert got["raw"] == wire
+    assert state["count"] == 1
+    calls = rpc_metrics.counter("rpc_client_calls_total", "",
+                                ("service", "method", "retried"))
+    assert calls.value(service="test.Echo", method="Echo", retried="0") == 1
+    sent = rpc_metrics.counter("rpc_client_bytes_total", "",
+                               ("service", "method", "direction"))
+    assert sent.value(service="test.Echo", method="Echo",
+                      direction="sent") == len(wire)
+    invocations = rpc_metrics.counter("rpc_server_calls_total", "",
+                                      ("service", "method", "transport"))
+    assert invocations.value(service="test.Echo", method="Echo",
+                             transport=transport) == 1
+    client.close()
+
+
+@pytest.mark.parametrize("transport", ["unary", "chunked"])
+def test_segmented_payload_survives_unavailable_retry(monkeypatch,
+                                                      rpc_metrics, transport):
+    """UNAVAILABLE on the first attempt: the retry re-sends the same
+    segments and the handler gets the message intact, once; still one
+    logical call, marked retried."""
+    import grpc
+
+    from metisfl_tpu.comm import rpc
+
+    class _Unavailable(Exception):
+        def code(self):
+            return grpc.StatusCode.UNAVAILABLE
+
+    seen = []
+
+    def flaky(payload: bytes) -> bytes:
+        seen.append(payload)
+        if len(seen) == 1:
+            raise _Unavailable("try again")
+        return dumps({"n": len(payload)})
+
+    if transport == "chunked":
+        monkeypatch.setattr(rpc, "STREAM_THRESHOLD", 1024)
+        monkeypatch.setattr(rpc, "CHUNK_BYTES", 100_000)
+    server = RpcServer("127.0.0.1", 0)
+    server.add_service(BytesService("test.Flaky", {"Put": flaky}))
+    port = server.start()
+    client = RpcClient("127.0.0.1", port, "test.Flaky", retry_sleep_s=0.01)
+    try:
+        segments, wire = _segmented()
+        assert loads(client.call("Put", segments)) == {"n": len(wire)}
+        assert seen == [wire, wire]
+        calls = rpc_metrics.counter("rpc_client_calls_total", "",
+                                    ("service", "method", "retried"))
+        assert calls.value(service="test.Flaky", method="Put",
+                           retried="1") == 1
+        assert calls.value(service="test.Flaky", method="Put",
+                           retried="0") == 0
+    finally:
+        client.close()
+        server.stop()
+
+
+@pytest.mark.parametrize("transport", ["unary", "chunked"])
+@pytest.mark.parametrize("mode", ["call", "call_async"])
+def test_chaos_injector_sees_bytes(echo_server, monkeypatch, transport, mode):
+    """With an injector installed the payload is joined first:
+    ``intercept`` keeps seeing (and may corrupt) plain bytes."""
+    from metisfl_tpu.comm import rpc
+
+    class _Recorder:
+        def __init__(self):
+            self.seen = []
+
+        def intercept(self, side, service, method, payload):
+            self.seen.append((side, type(payload), len(payload)))
+            return payload
+
+    recorder = _Recorder()
+    monkeypatch.setattr(rpc._chaos, "get", lambda: recorder)
+    if transport == "chunked":
+        monkeypatch.setattr(rpc, "STREAM_THRESHOLD", 1024)
+        monkeypatch.setattr(rpc, "CHUNK_BYTES", 100_000)
+    port, _ = echo_server
+    client = RpcClient("127.0.0.1", port, "test.Echo")
+    segments, wire = _segmented(n_bulk=1)
+    if mode == "call":
+        assert client.call("Echo", segments) == wire
+    else:
+        assert client.call_async("Echo", segments).result(timeout=30) == wire
+    assert recorder.seen == [("client", bytes, len(wire)),
+                             ("server", bytes, len(wire))]
+    client.close()
+
+
+def test_checkpoint_written_from_segments(tmp_path):
+    """save_checkpoint writes the state's segments in order: the file is
+    ``dumps(state)`` byte for byte, the community blob went to it as the
+    object it is, and a fresh controller restores the same state."""
+    import numpy as np
+
+    from metisfl_tpu.comm import codec
+    from metisfl_tpu.comm.messages import JoinRequest, TrainParams
+    from metisfl_tpu.config import (AggregationConfig, CheckpointConfig,
+                                    EvalConfig, FederationConfig)
+    from metisfl_tpu.controller.core import Controller
+    from metisfl_tpu.tensor.pytree import pack_model
+
+    class _Proxy:
+        def run_task(self, task):
+            pass
+
+        def evaluate(self, task, callback):
+            pass
+
+        def shutdown(self):
+            pass
+
+    def controller():
+        return Controller(FederationConfig(
+            protocol="asynchronous",
+            aggregation=AggregationConfig(rule="fedavg",
+                                          scaler="participants"),
+            train=TrainParams(batch_size=4, local_steps=1),
+            eval=EvalConfig(every_n_rounds=0),
+            checkpoint=CheckpointConfig(dir=str(tmp_path / "ckpt"),
+                                        every_n_rounds=1)),
+            lambda record: _Proxy())
+
+    rng = np.random.default_rng(0)
+    blob = pack_model({"w": rng.standard_normal((300, 300)).astype(np.float32)})
+    assert len(blob) >= codec.BORROW_MIN_BYTES
+    ctrl = controller()
+    try:
+        ctrl.set_community_model(blob)
+        joins = [ctrl.join(JoinRequest(hostname="h", port=7000 + i,
+                                       num_train_examples=5))
+                 for i in range(2)]
+        state = ctrl._checkpoint_state()
+        segments = codec.dumps_segments(state)
+        assert any(p is state["community_blob"] for p in segments.parts)
+        # a path of its own: the controller's coalesced saver (armed by
+        # the joins) writes checkpoint.dir on its own thread
+        path = ctrl.save_checkpoint(path=str(tmp_path / "mine" / "ckpt.bin"),
+                                    state=state)
+        with open(path, "rb") as fh:
+            on_disk = fh.read()
+        assert on_disk == codec.dumps(state) == bytes(segments)
+        assert os.listdir(tmp_path / "mine") == ["ckpt.bin"]
+    finally:
+        ctrl.shutdown()
+    ctrl2 = controller()
+    try:
+        assert ctrl2.restore_checkpoint(path)
+        assert ctrl2.community_model_bytes() == blob
+        assert sorted(ctrl2.active_learners()) == sorted(
+            j.learner_id for j in joins)
+        restored = ctrl2._checkpoint_state()
+        for key in ("global_iteration", "community_blob", "round_metadata",
+                    "community_evaluations"):
+            assert restored[key] == state[key]
+    finally:
+        ctrl2.shutdown()
 
 
 def _available_ram_gb() -> float:
