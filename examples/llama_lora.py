@@ -13,7 +13,11 @@ inserts the all-reduces), and FedAvg merges the rounds.
 ``--hybrid`` swaps the model for the attention / state-space hybrid
 (``JambaLite``: Mamba mixers with one attention block a period, adapters on
 ``in_proj``/``out_proj`` and ``wq``/``wv``); the federation, the shipped
-subset and the decode at the end are the same code.
+subset and the decode at the end are the same code. ``--latent`` swaps it
+for the latent-attention decoder with a share of its routed experts
+(``MlaMoeLite``: a dense block, then blocks whose router chooses 4 of 16
+experts of which this model holds 4, beside a shared expert; adapters on
+the four latent projections; the frozen base held in bfloat16).
 """
 
 from __future__ import annotations
@@ -38,6 +42,10 @@ def main() -> int:
     parser.add_argument("--hybrid", action="store_true",
                         help="JambaLite (Mamba mixers, an attention block "
                              "every second layer) in LlamaLite's place")
+    parser.add_argument("--latent", action="store_true",
+                        help="MlaMoeLite (latent attention, routed experts "
+                             "of which a share is held, a bfloat16 base) "
+                             "in LlamaLite's place")
     parser.add_argument("--scan-chunk", type=int, default=1,
                         help="fuse this many local steps into one compiled "
                              "scan program (dispatch amortization on TPU)")
@@ -57,7 +65,7 @@ def main() -> int:
     from metisfl_tpu.driver import InProcessFederation
     from metisfl_tpu.models import ArrayDataset, FlaxModelOps
     from metisfl_tpu.models.zoo import (TRANSFORMER_RULES, JambaLite,
-                                        LlamaLite)
+                                        LlamaLite, MlaMoeLite)
     from metisfl_tpu.parallel.mesh import MeshConfig, build_mesh
 
     mesh = build_mesh(MeshConfig(("dp", "tp"), (args.dp, args.tp)))
@@ -82,6 +90,18 @@ def main() -> int:
                            depth=args.depth, heads=args.heads, kv_heads=1,
                            attn_period=2, attn_offset=1, d_state=8,
                            lora_rank=args.lora_rank)
+    elif args.latent:
+        import jax.numpy as jnp
+        module = MlaMoeLite(vocab_size=args.vocab, dim=args.dim,
+                            depth=max(2, args.depth), heads=args.heads,
+                            q_rank=args.dim // 4, kv_rank=args.dim // 8,
+                            nope_dim=16, rope_dim=8, v_dim=16,
+                            moe_hidden=args.dim // 2, num_experts=16,
+                            top_k=4, experts_count=4, routed_scale=2.0,
+                            rope_factor=4.0, rope_original_max=16,
+                            rope_mscale_all_dim=1.0,
+                            lora_rank=args.lora_rank, dtype=jnp.bfloat16,
+                            param_dtype=jnp.bfloat16)
     else:
         module = LlamaLite(vocab_size=args.vocab, dim=args.dim,
                            depth=args.depth, heads=args.heads,

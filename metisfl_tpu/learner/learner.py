@@ -886,9 +886,12 @@ class Learner:
         step_flops = getattr(engine, "step_flops", None)
         if callable(step_flops):
             flops = float(step_flops(params.batch_size))
-        return self._device_monitor.observe(
-            steps=out.completed_steps, ms_per_step=out.ms_per_step,
-            flops_per_step=flops)
+        # the module's own counters (``TrainOutput.counts``, a step) ride
+        # beside ``ms_per_step`` into ``RoundProfile.learners[*].device``
+        return {**getattr(out, "counts", {}),
+                **self._device_monitor.observe(
+                    steps=out.completed_steps, ms_per_step=out.ms_per_step,
+                    flops_per_step=flops)}
 
     def _scaffold_offset(self, control_bytes: bytes):
         """(c, c - c_i) for this task — both params-shaped f32 trees.

@@ -61,6 +61,11 @@ class TrainOutput:
     steps_ms: float = 0.0
     readback_ms: float = 0.0
     readback_bytes: int = 0
+    # what the module counted, a step (mean over the task's steps): for
+    # each name ending ``_count`` that modules sow under ``intermediates``
+    # the sum over the modules (``_sown_counts``). Empty for a module that
+    # sows none.
+    counts: Dict[str, float] = field(default_factory=dict)
 
 
 def softmax_cross_entropy_loss(logits, y):
@@ -75,6 +80,19 @@ _LOSSES = {
     "softmax_cross_entropy": softmax_cross_entropy_loss,
     "mse": mse_loss,
 }
+
+
+def _sown_counts(intermediates) -> Dict[str, Any]:
+    """{name: sum over the modules} of what a forward pass sowed under a
+    name ending ``_count`` (a routed layer's assignments on held experts,
+    ``models/zoo/transformer.py``). An empty dict, and so the same
+    program, for a module that sows none."""
+    out: Dict[str, Any] = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
+        names = [k.key for k in path if hasattr(k, "key")]
+        if names and str(names[-1]).endswith("_count"):
+            out[names[-1]] = out.get(names[-1], 0.0) + leaf
+    return out
 
 
 def _accuracy(logits, y):
@@ -380,11 +398,12 @@ class FlaxModelOps:
                                      jax.tree.leaves(global_params))
                 )
                 loss = loss + 0.5 * mu * prox
-            return loss, (logits, new_bs)
+            counts = _sown_counts(mutated.get("intermediates", {}))
+            return loss, (logits, new_bs, counts)
 
         def step(params, batch_stats, opt_state, global_params, grad_offset,
                  x, y, rng):
-            (loss, (logits, new_bs)), grads = jax.value_and_grad(
+            (loss, (logits, new_bs, counts)), grads = jax.value_and_grad(
                 loss_and_aux, has_aux=True)(params, batch_stats, global_params,
                                             x, y, rng)
             if jax.tree_util.tree_leaves(grad_offset):
@@ -397,7 +416,7 @@ class FlaxModelOps:
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             acc = _accuracy(logits, y)
-            return params, new_bs, opt_state, loss, acc
+            return params, new_bs, opt_state, loss, acc, counts
 
         compiled = _runtime.monitored_jit(step, name="train.step",
                                           donate_argnums=(0, 1, 2))
@@ -425,15 +444,16 @@ class FlaxModelOps:
                 params, batch_stats, opt_state, rng = carry
                 x, y, step_id = batch
                 rng = jax.random.fold_in(rng, step_id)
-                params, batch_stats, opt_state, loss, acc = step(
+                params, batch_stats, opt_state, loss, acc, counts = step(
                     params, batch_stats, opt_state, global_params,
                     grad_offset, x, y, rng)
-                return (params, batch_stats, opt_state, rng), (loss, acc)
+                return ((params, batch_stats, opt_state, rng),
+                        (loss, acc, counts))
 
-            (params, batch_stats, opt_state, rng), (losses, accs) = (
+            (params, batch_stats, opt_state, rng), (losses, accs, counts) = (
                 jax.lax.scan(body, (params, batch_stats, opt_state, rng0),
                              (xs, ys, step_ids)))
-            return params, batch_stats, opt_state, rng, losses, accs
+            return params, batch_stats, opt_state, rng, losses, accs, counts
 
         compiled = _runtime.monitored_jit(scan_steps,
                                           name="train.scan_steps",
@@ -470,6 +490,15 @@ class FlaxModelOps:
         epoch_metrics: List[Dict[str, float]] = []
         epoch_losses: List[Any] = []
         step_times: List[float] = []
+        # the module's counters, a chunk's or a step's as the program
+        # returned them: left on the device until the task's end (no sync
+        # and no program of their own), summed on the host there
+        counted: Dict[str, list] = {}
+
+        def _add_counts(counts) -> None:
+            for name, value in counts.items():
+                counted.setdefault(name, []).append(value)
+
         completed = 0
         rng = self._rng
         # the task waterfall's inner tiles (TrainOutput.feed_ms/steps_ms):
@@ -528,10 +557,11 @@ class FlaxModelOps:
                     feed_s += t0 - t_feed
                     if feed_at is None:
                         feed_at, steps_at = t_feed, t0
-                    params, batch_stats, opt_state, rng, c_losses, c_accs = (
-                        scan_compiled(params, batch_stats, opt_state,
-                                      global_params, grad_offset, rng,
-                                      step_ids, xs, ys))
+                    (params, batch_stats, opt_state, rng, c_losses, c_accs,
+                     c_counts) = scan_compiled(
+                         params, batch_stats, opt_state, global_params,
+                         grad_offset, rng, step_ids, xs, ys)
+                    _add_counts(c_counts)
                     c_losses = np.asarray(c_losses)
                     c_accs = np.asarray(c_accs)   # host sync, once per chunk
                     chunk_s = time.perf_counter() - t0
@@ -574,9 +604,10 @@ class FlaxModelOps:
                 feed_s += t0 - t_feed
                 if feed_at is None:
                     feed_at, steps_at = t_feed, t0
-                params, batch_stats, opt_state, loss, acc = compiled(
+                params, batch_stats, opt_state, loss, acc, counts = compiled(
                     params, batch_stats, opt_state, global_params,
                     grad_offset, place(x), place(y), rng)
+                _add_counts(counts)
                 per_step_runs += 1
                 if per_step_runs > 1 or (remaining == 1 and not step_times):
                     # the per-step program's first execution pays its jit
@@ -646,6 +677,9 @@ class FlaxModelOps:
             steps_ms=steps_s * 1e3,
             readback_ms=readback_s * 1e3,
             readback_bytes=read_bytes,
+            counts={name: float(sum(np.sum(np.asarray(v)) for v in values))
+                    / max(1, completed)
+                    for name, values in counted.items()},
         )
 
     # -- inference ---------------------------------------------------------

@@ -14,10 +14,13 @@ from metisfl_tpu.models.zoo.rnn import LSTMClassifier
 from metisfl_tpu.models.zoo.transformer import (
     TRANSFORMER_RULES,
     BertLite,
+    ExpertShareMLP,
     JambaLite,
+    LatentAttention,
     LlamaLite,
     LoRADense,
     MambaMixer,
+    MlaMoeLite,
     MoEMLP,
     ViTLite,
 )
@@ -26,6 +29,7 @@ __all__ = [
     "MLP", "HousingMLP", "FashionMnistCNN", "Cifar10CNN", "ResNet20",
     "BrainAge3DCNN", "LSTMClassifier",
     "ViTLite", "BertLite", "LlamaLite", "JambaLite", "MambaMixer",
+    "MlaMoeLite", "LatentAttention", "ExpertShareMLP",
     "LoRADense", "MoEMLP",
     "TRANSFORMER_RULES",
 ]

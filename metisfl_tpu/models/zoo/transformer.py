@@ -1,5 +1,6 @@
-"""Transformer family: ViT-lite, BERT-lite, Llama-lite (+LoRA), and the
-attention/state-space hybrid Jamba-lite.
+"""Transformer family: ViT-lite, BERT-lite, Llama-lite (+LoRA), the
+attention/state-space hybrid Jamba-lite, and the latent-attention decoder
+with a share of its routed experts, MlaMoe-lite.
 
 The BASELINE.md scale ladder (ViT-B/16 semi-sync, BERT async + secure,
 Llama-3-8B-LoRA with in-learner sharding) needs transformer workloads the
@@ -18,6 +19,7 @@ reference examples/keras/models/imdb_lstm.py). Designed TPU-first:
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -34,8 +36,13 @@ from jax.sharding import PartitionSpec as P
 # parallelism) and their hidden axis over ``tp`` — XLA inserts the
 # dispatch/combine all-to-alls between token- and expert-sharded layouts.
 TRANSFORMER_RULES = [
-    (r"experts_w1", P("ep", None, "tp")),
-    (r"experts_w2", P("ep", "tp", None)),
+    (r"experts_(w1|gate|up)", P("ep", None, "tp")),
+    (r"experts_(w2|down)", P("ep", "tp", None)),
+    # latent attention: the up-projections carry the heads (column-
+    # parallel), o_proj brings them back; the two down-projections are thin
+    # and stay whole
+    (r"(q_b_proj|kv_b_proj)(/base)?/kernel", P(None, "tp")),
+    (r"o_proj/kernel", P("tp", None)),
     (r"(wq|wk|wv|gate|up|fc1)(/base)?/kernel", P(None, "tp")),
     (r"(wo|down|fc2)(/base)?/kernel", P("tp", None)),
     # Mamba mixer: d_inner is the sharded axis throughout (column-parallel
@@ -64,11 +71,15 @@ class LoRADense(nn.Module):
     # computation dtype (mixed precision: fp32 params, e.g. bf16 compute —
     # the MXU-native mode); None keeps full fp32
     dtype: Any = None
+    # the base kernel's own type (a frozen base held in the type the step
+    # uses it in has no cast in the step); the adapters stay float32
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
         y = nn.Dense(self.features, use_bias=self.use_bias,
-                     dtype=self.dtype, name="base")(x)
+                     dtype=self.dtype, param_dtype=self.param_dtype,
+                     name="base")(x)
         if self.rank > 0:
             a = self.param("lora_a", nn.initializers.normal(0.02),
                            (x.shape[-1], self.rank))
@@ -80,10 +91,12 @@ class LoRADense(nn.Module):
         return y
 
 
-def _rotary(x, positions):
-    """Rotary position embedding over the last (head) dimension."""
+def _rotary(x, positions, freqs=None):
+    """Rotary position embedding over the last (head) dimension, half-split;
+    ``freqs`` (half the width) replaces the base-10000 ladder."""
     half = x.shape[-1] // 2
-    freqs = 1.0 / (10000 ** (np.arange(0, half) / half))
+    if freqs is None:
+        freqs = 1.0 / (10000 ** (np.arange(0, half) / half))
     angles = positions[..., None] * freqs  # (..., L, half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     x1, x2 = x[..., :half], x[..., half:]
@@ -286,15 +299,15 @@ class SwiGLU(nn.Module):
     dim: int
     hidden: int
     dtype: Any = None
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        gate = nn.Dense(self.hidden, use_bias=False, dtype=self.dtype,
-                        name="gate")(x)
-        up = nn.Dense(self.hidden, use_bias=False, dtype=self.dtype,
-                      name="up")(x)
-        return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
-                        name="down")(nn.silu(gate) * up)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                                  param_dtype=self.param_dtype)
+        gate = dense(self.hidden, name="gate")(x)
+        up = dense(self.hidden, name="up")(x)
+        return dense(self.dim, name="down")(nn.silu(gate) * up)
 
 
 class MoEMLP(nn.Module):
@@ -378,6 +391,250 @@ class MoEMLP(nn.Module):
         out = jnp.einsum("ech,ehd->ecd", h, w2.astype(dt))       # (E, C, D)
         mixed = jnp.einsum("tec,ecd->td", gate_disp.astype(dt), out)
         return mixed.reshape(B, L, D)
+
+
+def yarn_frequencies(width: int, base: float, factor: float,
+                     original_max: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0) -> np.ndarray:
+    """The ``width / 2`` rotary frequencies under YaRN: ladder step ``i``
+    keeps its frequency ``f_i = base ** (-2 i / width)`` where it turns
+    more than ``beta_fast`` times over the original context, is divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times, and is
+    blended on a linear ramp between the two steps."""
+    half = width // 2
+    f = base ** (-np.arange(half, dtype=np.float64) * 2.0 / width)
+
+    def turns_at(beta: float) -> float:
+        return (width * np.log(original_max / (beta * 2 * np.pi))
+                / (2 * np.log(base)))
+
+    low = max(int(np.floor(turns_at(beta_fast))), 0)
+    high = min(int(np.ceil(turns_at(beta_slow))), width - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (f / factor * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention factor: ``0.1 mscale ln(factor) + 1`` past 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention, with ``h`` the block's normed input::
+
+        c_q = RMSNorm(h W_qa);  q = c_q W_qb      heads of [nope | rope]
+        [c_kv | k_rope] = h W_kva;  c_kv = RMSNorm(c_kv)
+        c_kv W_kvb                                  heads of [k_nope | v]
+        key of a head = [k_nope | rotary(k_rope)], k_rope shared by all
+        out = softmax(q k^T scale, causal) v W_o
+
+    The rotary columns take YaRN's frequencies (:func:`yarn_frequencies`)
+    in the half-split rotation of :func:`_rotary`; ``scale`` is
+    ``(nope + rope) ** -0.5 * m ** 2`` with ``m = yarn_mscale(factor,
+    mscale_all_dim)``, and cos and sin carry ``yarn_mscale(factor, mscale)
+    / m``. No bias anywhere. ``lora_rank`` puts adapters on the four
+    latent projections; ``o_proj`` has none.
+
+    ``cache`` is ``(c_kv (B, L_max, kv_rank), k_rope (B, L_max, rope))``:
+    the latents, not the heads' keys and values (576 values a position
+    where 64 heads would hold 64 x 320). The cached path expands keys and
+    values from the cached latents at every call."""
+
+    dim: int
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_base: float = 10000.0
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    eps: float = 1e-6
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    use_flash: Any = False
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+
+    def rotary_frequencies(self) -> np.ndarray:
+        return yarn_frequencies(self.rope_dim, self.rope_base,
+                                self.rope_factor, self.rope_original_max,
+                                self.rope_beta_fast, self.rope_beta_slow)
+
+    def softmax_scale(self) -> float:
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return float((self.nope_dim + self.rope_dim) ** -0.5 * m * m)
+
+    def _rotate(self, x, positions):
+        """``x`` (B, L, heads, rope) at ``positions`` (L,)."""
+        amp = (yarn_mscale(self.rope_factor, self.rope_mscale)
+               / yarn_mscale(self.rope_factor, self.rope_mscale_all_dim))
+        out = _rotary(jnp.swapaxes(x, 1, 2), positions,
+                      self.rotary_frequencies())
+        if amp != 1.0:
+            out = out * amp
+        return jnp.swapaxes(out, 1, 2).astype(x.dtype)
+
+    def init_cache(self, batch: int, max_len: int):
+        dtype = self.dtype or jnp.float32
+        return (jnp.zeros((batch, max_len, self.kv_rank), dtype),
+                jnp.zeros((batch, max_len, self.rope_dim), dtype))
+
+    @nn.compact
+    def __call__(self, h, cache=None, position=None):
+        B, L, _ = h.shape
+        H, nope, rope, vd = (self.heads, self.nope_dim, self.rope_dim,
+                             self.v_dim)
+
+        def proj(name, features):
+            return LoRADense(features, rank=self.lora_rank,
+                             alpha=self.lora_alpha, use_bias=False,
+                             dtype=self.dtype, param_dtype=self.param_dtype,
+                             name=name)
+
+        def norm(name):
+            return nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name=name)
+
+        with jax.named_scope("mla"):
+            c_q = norm("q_a_norm")(proj("q_a_proj", self.q_rank)(h))
+            q = proj("q_b_proj", H * (nope + rope))(c_q).reshape(
+                B, L, H, nope + rope)
+            kva = proj("kv_a_proj_with_mqa", self.kv_rank + rope)(h)
+            c_kv = norm("kv_a_norm")(kva[..., :self.kv_rank])
+            k_rope = kva[..., None, self.kv_rank:]          # (B, L, 1, rope)
+            pos0 = (jnp.zeros((), jnp.int32) if cache is None
+                    else jnp.asarray(position, jnp.int32))
+            positions = (pos0 + jnp.arange(L)).astype(jnp.float32)
+            q = jnp.concatenate(
+                [q[..., :nope], self._rotate(q[..., nope:], positions)], -1)
+            k_rope = self._rotate(k_rope, positions)
+            if cache is not None:
+                zero = jnp.zeros((), jnp.int32)
+                cc, cr = cache
+                cc = jax.lax.dynamic_update_slice(
+                    cc, c_kv.astype(cc.dtype), (zero, pos0, zero))
+                cr = jax.lax.dynamic_update_slice(
+                    cr, k_rope[:, :, 0].astype(cr.dtype), (zero, pos0, zero))
+                cache, c_kv, k_rope = (cc, cr), cc, cr[:, :, None]
+            S = c_kv.shape[1]                   # keys: L, or the cache's
+            kv = proj("kv_b_proj", H * (nope + vd))(c_kv).reshape(
+                B, S, H, nope + vd)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope.astype(kv.dtype),
+                                  (B, S, H, rope))], -1)
+            q, k, v = (jnp.swapaxes(t, 1, 2)
+                       for t in (q, k, kv[..., nope:]))    # (B, H, ., .)
+            scale = self.softmax_scale()
+            if cache is not None:
+                s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(
+                    jnp.float32) * scale
+                mask = (jnp.arange(S)[None, :]
+                        <= pos0 + jnp.arange(L)[:, None])
+                s = jnp.where(mask[None, None], s, jnp.finfo(s.dtype).min)
+                out = jnp.einsum("bhqk,bhkd->bhqd",
+                                 nn.softmax(s, axis=-1).astype(v.dtype), v)
+            elif self.use_flash == "auto":
+                from metisfl_tpu.ops import attention
+                out = attention(q, k, v, True, scale=scale)
+            elif self.use_flash:
+                from metisfl_tpu.ops import flash_attention
+                out = flash_attention(q, k, v, True, None, None, None, scale)
+            else:
+                from metisfl_tpu.ops.flash_attention import _dense_attention
+                out = _dense_attention(q, k, v, True, scale)
+            out = jnp.swapaxes(out, 1, 2).reshape(B, L, H * vd)
+            out = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                           param_dtype=self.param_dtype, name="o_proj")(out)
+        return out if cache is None else (out, cache)
+
+
+class ExpertShareMLP(nn.Module):
+    """A routed-expert FFN that holds ``count`` of the router's
+    ``num_experts`` experts, ``first .. first + count``, beside a shared
+    expert::
+
+        s = sigmoid(h W_g)                  float32, over ALL the experts
+        chosen = top_k(s + b)               b chooses and does not weigh
+        g_e = s_e / (sum_chosen s + 1e-20) * routed_scale
+        out = shared(h) + sum_{e chosen and held} g_e expert_e(h)
+
+    each expert and the shared one ``W_down(silu(W_gate h) * W_up h)``.
+    What the experts held elsewhere would add is left out: on one chip the
+    layer runs without its exchange, and the partial result goes on. No
+    token is dropped and no capacity exists: the held assignments are
+    sorted by expert and multiplied group by group
+    (:mod:`metisfl_tpu.ops.grouped_matmul`). The held experts are stacked
+    on a leading axis (``experts_gate``, ``experts_up``, ``experts_down``)
+    that :data:`TRANSFORMER_RULES` shards over ``ep``. The router and ``b``
+    (``e_score_correction_bias``) are float32 whatever ``param_dtype`` is.
+
+    Sows, under ``intermediates``, ``moe_local_count`` (the assignments
+    that fell on held experts) and ``moe_max_group_count`` (the largest
+    group): counters, which ``FlaxModelOps`` sums and returns beside the
+    loss (``models/ops.py``)."""
+
+    dim: int
+    hidden: int
+    num_experts: int
+    top_k: int
+    first: int = 0
+    count: int = 0              # 0 = all of them
+    shared_hidden: int = 0      # 0 = no shared expert
+    routed_scale: float = 1.0
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+    # None: the kernels on a TPU, ``ragged_dot`` elsewhere; True runs the
+    # kernels in Pallas's interpreter (CPU tests)
+    gmm_interpret: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        from metisfl_tpu.ops import grouped_matmul as gm
+        B, L, D = x.shape
+        E, K = self.num_experts, self.top_k
+        G = self.count or E
+        h = x.reshape(B * L, D)
+        with jax.named_scope("moe_router"):
+            s = nn.sigmoid(nn.Dense(
+                E, use_bias=False, dtype=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST, name="router")(
+                    h.astype(jnp.float32)))
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros, (E,), jnp.float32)
+            _, chosen = jax.lax.top_k(s + bias, K)
+            picked = jnp.take_along_axis(s, chosen, axis=-1)
+            gates = picked / (jnp.sum(picked, -1, keepdims=True)
+                              + 1e-20) * self.routed_scale
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        stack = lambda name, a, b: self.param(             # noqa: E731
+            name, init, (G, a, b), self.param_dtype)
+        w_gate = stack("experts_gate", D, self.hidden)
+        w_up = stack("experts_up", D, self.hidden)
+        w_down = stack("experts_down", self.hidden, D)
+        dt = self.dtype or h.dtype
+        # scopes moe_dispatch, moe_experts, moe_combine inside
+        out, sizes = gm.routed_experts(
+            h.astype(dt), chosen, gates, w_gate.astype(dt), w_up.astype(dt),
+            w_down.astype(dt), first=self.first, num_experts=E,
+            interpret=self.gmm_interpret)
+        self.sow("intermediates", "moe_local_count",
+                 jnp.sum(sizes).astype(jnp.float32))
+        self.sow("intermediates", "moe_max_group_count",
+                 jnp.max(sizes).astype(jnp.float32))
+        if self.shared_hidden:
+            with jax.named_scope("moe_shared"):
+                out = out + SwiGLU(D, self.shared_hidden, dtype=self.dtype,
+                                   param_dtype=self.param_dtype,
+                                   name="shared")(h)
+        return out.reshape(B, L, D)
 
 
 class GeluMLP(nn.Module):
@@ -540,7 +797,8 @@ class MambaMixer(nn.Module):
 
 class DecoderBlock(nn.Module):
     """Pre-RMSNorm causal block: a mixer (attention, Llama style with
-    rotary by default, or the Mamba mixer) and a SwiGLU or MoE FFN."""
+    rotary by default, the Mamba mixer or latent attention) and an FFN
+    (SwiGLU, MoE, or the module the model hands in)."""
 
     dim: int
     heads: int
@@ -561,6 +819,10 @@ class DecoderBlock(nn.Module):
     # a MambaMixer (unbound, as the model builds it) in the attention
     # mixer's place; flax adopts it under the field's name, "mamba"
     mamba: Any = None
+    # likewise a LatentAttention in the attention mixer's place ("mla"),
+    # and an FFN module in SwiGLU's or MoEMLP's ("ffn")
+    mla: Any = None
+    ffn: Any = None
 
     @nn.compact
     def __call__(self, x, train: bool = False, cache=None, position=None):
@@ -569,6 +831,10 @@ class DecoderBlock(nn.Module):
             with jax.named_scope("mamba_mixer"):
                 a = (self.mamba(normed) if cache is None
                      else self.mamba(normed, cache=cache, position=position))
+        elif self.mla is not None:
+            with jax.named_scope("attention_mixer"):
+                a = (self.mla(normed) if cache is None
+                     else self.mla(normed, cache=cache, position=position))
         else:
             attn = Attention(self.dim, self.heads, causal=True,
                              rotary=self.rotary,
@@ -586,7 +852,9 @@ class DecoderBlock(nn.Module):
             a, cache = a
         x = x + a
         hidden = self.ffn_dim or self.mlp_ratio * self.dim
-        if self.moe_experts > 0:
+        if self.ffn is not None:
+            ffn = self.ffn
+        elif self.moe_experts > 0:
             ffn = MoEMLP(self.dim, hidden,
                          num_experts=self.moe_experts, top_k=self.moe_top_k,
                          dtype=self.dtype, name="moe")
@@ -830,3 +1098,108 @@ class JambaLite(nn.Module):
     def cache_kinds(self):
         return tuple("kv" if self.is_attention(i) else "state"
                      for i in range(self.depth))
+
+
+class MlaMoeLite(nn.Module):
+    """Decoder-only causal LM with latent attention
+    (:class:`LatentAttention`) in every block, a dense SwiGLU FFN in the
+    first ``first_dense`` blocks and a routed-expert FFN that holds a share
+    of the experts (:class:`ExpertShareMLP`) in the rest; pre-RMSNorm, an
+    untied head with float32 logits. ``lora_rank > 0`` adds adapters on the
+    four latent projections; train with
+    ``FlaxModelOps(trainable_regex="lora_")`` to freeze the base.
+    ``param_dtype`` is the type of the frozen matrices and the embedding
+    (norm scales, the router, the head and the adapters stay float32).
+
+    Decoding: ``init_cache`` gives a block's latent cache ``(c_kv,
+    k_rope)``, kind ``"kv"``."""
+
+    vocab_size: int = 8192
+    dim: int = 64
+    depth: int = 3
+    heads: int = 4
+    q_rank: int = 16
+    kv_rank: int = 8
+    nope_dim: int = 16
+    rope_dim: int = 8
+    v_dim: int = 16
+    ffn_dim: int = 0            # the dense blocks' width; 0 = 4 x dim
+    first_dense: int = 1
+    moe_hidden: int = 32
+    num_experts: int = 16
+    top_k: int = 4
+    experts_first: int = 0
+    experts_count: int = 0      # 0 = all of them
+    shared_experts: int = 1
+    routed_scale: float = 1.0
+    rope_base: float = 10000.0
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    eps: float = 1e-6
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    use_flash: Any = False
+    remat: bool = False
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+    gmm_interpret: Any = None       # see ExpertShareMLP
+
+    def _mla(self) -> LatentAttention:
+        return LatentAttention(
+            self.dim, self.heads, self.q_rank, self.kv_rank, self.nope_dim,
+            self.rope_dim, self.v_dim, rope_base=self.rope_base,
+            rope_factor=self.rope_factor,
+            rope_original_max=self.rope_original_max,
+            rope_beta_fast=self.rope_beta_fast,
+            rope_beta_slow=self.rope_beta_slow,
+            rope_mscale=self.rope_mscale,
+            rope_mscale_all_dim=self.rope_mscale_all_dim, eps=self.eps,
+            lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
+            use_flash=self.use_flash, dtype=self.dtype,
+            param_dtype=self.param_dtype, parent=None)  # the block adopts it
+
+    def _ffn(self, layer: int):
+        if layer < self.first_dense:
+            return SwiGLU(self.dim, self.ffn_dim or 4 * self.dim,
+                          dtype=self.dtype, param_dtype=self.param_dtype,
+                          parent=None)
+        return ExpertShareMLP(
+            self.dim, self.moe_hidden, self.num_experts, self.top_k,
+            first=self.experts_first, count=self.experts_count,
+            shared_hidden=self.shared_experts * self.moe_hidden,
+            routed_scale=self.routed_scale, dtype=self.dtype,
+            param_dtype=self.param_dtype, gmm_interpret=self.gmm_interpret,
+            parent=None)
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, caches=None,
+                 position=None):
+        x = nn.Embed(self.vocab_size, self.dim, dtype=self.dtype,
+                     param_dtype=self.param_dtype, name="embed")(tokens)
+        block_cls = (nn.remat(DecoderBlock, static_argnums=(2,))
+                     if self.remat and caches is None else DecoderBlock)
+        new_caches = []
+        for i in range(self.depth):
+            block = block_cls(self.dim, self.heads, dtype=self.dtype,
+                              eps=self.eps, mla=self._mla(),
+                              ffn=self._ffn(i), name=f"block_{i}")
+            if caches is not None:
+                x, c = block(x, train, cache=caches[i], position=position)
+                new_caches.append(c)
+            else:
+                x = block(x, train)
+        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype)(x)
+        logits = nn.Dense(self.vocab_size, use_bias=False,
+                          name="lm_head")(x.astype(jnp.float32))
+        return logits if caches is None else (logits, tuple(new_caches))
+
+    def init_cache(self, batch: int, max_len: int):
+        return tuple(self._mla().init_cache(batch, max_len)
+                     for _ in range(self.depth))
+
+    def cache_kinds(self):
+        return ("kv",) * self.depth

@@ -1,6 +1,7 @@
-"""Pallas TPU kernels for the hot ops: flash attention (exported here)
-and the selective scan (``metisfl_tpu.ops.selective_scan``, imported as a
-module: its routed entry point has the module's name)."""
+"""Pallas TPU kernels for the hot ops: flash attention (exported here),
+the selective scan (``metisfl_tpu.ops.selective_scan``, imported as a
+module: its routed entry point has the module's name) and the grouped
+products of a routed-expert layer (``metisfl_tpu.ops.grouped_matmul``)."""
 
 from metisfl_tpu.ops.flash_attention import (FLASH_MIN_SEQ, attention,
                                              flash_attention)

@@ -213,16 +213,22 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
-def _dense_attention(q, k, v, causal: bool):
+def _score_scale(q, scale: Optional[float]) -> float:
+    """The softmax scale: a caller's own (latent attention's carries its
+    rotary scaling's factor), else ``1/sqrt`` of the score width."""
+    return float(1.0 / np.sqrt(q.shape[-1]) if scale is None else scale)
+
+
+def _dense_attention(q, k, v, causal: bool, scale: Optional[float] = None):
     """XLA reference implementation (tests oracle + the routed dense path).
+    ``v`` may be of another width than ``q`` and ``k``.
 
     Softmax in fp32 regardless of compute dtype — bf16 exp/normalize loses
     too much precision (same policy as the flash kernel's fp32 online
     statistics and the model zoo's dense branch); probabilities cast back
     so the PV matmul stays on the MXU's native path."""
-    D = q.shape[-1]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * float(
-        1.0 / np.sqrt(D))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(
+        jnp.float32) * _score_scale(q, scale)
     if causal:
         L = q.shape[2]
         mask = jnp.tril(jnp.ones((L, L), bool))
@@ -287,24 +293,30 @@ def _kv_block_index(kv_ix, blk_q: int, blk_k: int, causal: bool):
     return ix
 
 
-def _gqa_shapes(q, k):
+def _gqa_shapes(q, k, v):
+    """(B, Hq, Hkv, L, D, Dv): ``D`` is the width of the scores (q and k),
+    ``Dv`` the width of the values and of the output."""
     B, Hq, L, D = q.shape
     Hkv = k.shape[1]
     if Hq % Hkv:
         raise ValueError(
             f"query heads ({Hq}) must be a multiple of KV heads ({Hkv})")
-    return B, Hq, Hkv, L, D
+    if k.shape[-1] != D or v.shape[1] != Hkv:
+        raise ValueError(
+            f"k {k.shape} must share q's width ({D}) and v's heads "
+            f"({v.shape[1]})")
+    return B, Hq, Hkv, L, D, v.shape[-1]
 
 
 def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
-                   interpret: bool):
-    B, H, Hkv, L, D = _gqa_shapes(q, k)
+                   interpret: bool, scale: Optional[float] = None):
+    B, H, Hkv, L, D, Dv = _gqa_shapes(q, k, v)
     blk_q, blk_k, Lp = _resolve_blocks(L, blk_q, blk_k)
-    scale = float(1.0 / np.sqrt(D))
+    scale = _score_scale(q, scale)
     kv_ix = _kv_head_index(H, Hkv)
     qf = q.reshape(B * H, L, D)
     kf = k.reshape(B * Hkv, L, D)
-    vf = v.reshape(B * Hkv, L, D)
+    vf = v.reshape(B * Hkv, L, Dv)
     if Lp != L:
         pad = ((0, 0), (0, Lp - L), (0, 0))
         qf, kf, vf = (jnp.pad(x, pad) for x in (qf, kf, vf))
@@ -315,7 +327,7 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Lp, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Lp, Dv), q.dtype),
             # logsumexp replicated across the lane dim (2D-tiled layout;
             # callers slice [:, :, 0])
             jax.ShapeDtypeStruct((B * H, Lp, _STAT_LANES), jnp.float32),
@@ -324,37 +336,38 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
         in_specs=[
             pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_k, D), kv_index),
-            pl.BlockSpec((1, blk_k, D), kv_index),
+            pl.BlockSpec((1, blk_k, Dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_q, _STAT_LANES), lambda b, i, j: (b, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, _LANES), jnp.float32),   # m
             pltpu.VMEM((blk_q, _LANES), jnp.float32),   # l
-            pltpu.VMEM((blk_q, D), jnp.float32),        # acc
+            pltpu.VMEM((blk_q, Dv), jnp.float32),       # acc
         ],
         compiler_params=None if interpret else _SEQ_PARAMS,
         interpret=interpret,
         name="flash_fwd",
     )(qf, kf, vf)
-    return out[:, :L].reshape(B, H, L, D), lse
+    return out[:, :L].reshape(B, H, L, Dv), lse
 
 
 def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
-                    blk_k: int, interpret: bool, delta=None):
+                    blk_k: int, interpret: bool, delta=None,
+                    scale: Optional[float] = None):
     """``lse`` (and the optional precomputed ``delta``) arrive in LOGICAL
     layout — (B, H, L) fp32; the kernel HBM layout (padded, lane-
     replicated) is produced here so callers never touch it. Padded query
     rows get a large lse sentinel: their g/delta are zero, but a small pad
     value could overflow p = exp(s - lse) into inf·0 = nan."""
-    B, H, Hkv, L, D = _gqa_shapes(q, k)
+    B, H, Hkv, L, D, Dv = _gqa_shapes(q, k, v)
     G = H // Hkv
     blk_q, blk_k, Lp = _resolve_blocks(L, blk_q, blk_k)
-    scale = float(1.0 / np.sqrt(D))
+    scale = _score_scale(q, scale)
     kv_ix = _kv_head_index(H, Hkv)
-    flat = lambda x: x.reshape(-1, L, D)
+    flat = lambda x: x.reshape(-1, L, x.shape[-1])
     qf, kf, vf, of, gf = map(flat, (q, k, v, out, g))
     if delta is None:
         # delta_i = rowsum(dO_i * O_i)
@@ -381,8 +394,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
         in_specs=[
             pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_k, D), kv_index),
-            pl.BlockSpec((1, blk_k, D), kv_index),
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_k, Dv), kv_index),
+            pl.BlockSpec((1, blk_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_q, _STAT_LANES), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_q, _STAT_LANES), lambda b, i, j: (b, i, 0)),
         ],
@@ -410,24 +423,24 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
                           kv_len=L, nq=nq, g_size=G),
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, Lp, D), k.dtype),
-            jax.ShapeDtypeStruct((B * Hkv, Lp, D), v.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, Lp, Dv), v.dtype),
         ],
         grid=(B * Hkv, nk, nq * G),
         in_specs=[
             pl.BlockSpec((1, blk_q, D), q_ix),
             pl.BlockSpec((1, blk_k, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, blk_q, D), q_ix),
+            pl.BlockSpec((1, blk_k, Dv), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, blk_q, Dv), q_ix),
             pl.BlockSpec((1, blk_q, _STAT_LANES), q_ix),
             pl.BlockSpec((1, blk_q, _STAT_LANES), q_ix),
         ],
         out_specs=[
             pl.BlockSpec((1, blk_k, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, blk_k, Dv), lambda b, j, t: (b, j, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, D), jnp.float32),
-            pltpu.VMEM((blk_k, D), jnp.float32),
+            pltpu.VMEM((blk_k, Dv), jnp.float32),
         ],
         compiler_params=None if interpret else _SEQ_PARAMS,
         interpret=interpret,
@@ -436,15 +449,19 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
 
     return (dq[:, :L].reshape(B, H, L, D),
             dk[:, :L].reshape(B, Hkv, L, D),
-            dv[:, :L].reshape(B, Hkv, L, D))
+            dv[:, :L].reshape(B, Hkv, L, Dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False,
                     blk_q: Optional[int] = None,
                     blk_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
-    """Flash attention over (B, H, L, D). Grouped-query attention is
+                    interpret: Optional[bool] = None,
+                    scale: Optional[float] = None):
+    """Flash attention over (B, H, L, D). ``v`` (and so the output) may be
+    of another width than ``q`` and ``k`` (latent attention scores over 192
+    columns and carries 128); ``scale`` replaces the softmax's
+    ``1/sqrt(D)``. Grouped-query attention is
     native: ``k``/``v`` may carry fewer heads than ``q`` (Hq a multiple of
     Hkv) and stay at kv-head size in HBM — block index maps route each
     query head to its KV group; dK/dV accumulate over the group in the
@@ -453,26 +470,27 @@ def flash_attention(q, k, v, causal: bool = False,
     off-TPU so the same call works in CI and on chip."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    out, _ = _flash_forward(q, k, v, causal, blk_q, blk_k, interpret)
+    out, _ = _flash_forward(q, k, v, causal, blk_q, blk_k, interpret, scale)
     return out
 
 
-def _fwd(q, k, v, causal, blk_q, blk_k, interpret):
+def _fwd(q, k, v, causal, blk_q, blk_k, interpret, scale):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    out, lse = _flash_forward(q, k, v, causal, blk_q, blk_k, interpret)
+    out, lse = _flash_forward(q, k, v, causal, blk_q, blk_k, interpret,
+                              scale)
     B, H, L, _ = q.shape
     # residual lse in logical layout: 8x smaller than the kernel's
     # lane-replicated padded buffer, and the layout knowledge stays here
     return out, (q, k, v, out, lse[:, :L, 0].reshape(B, H, L))
 
 
-def _bwd(causal, blk_q, blk_k, interpret, res, g):
+def _bwd(causal, blk_q, blk_k, interpret, scale, res, g):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, blk_q, blk_k,
-                           interpret)
+                           interpret, scale=scale)
 
 
 flash_attention.defvjp(_fwd, _bwd)
@@ -490,16 +508,18 @@ FLASH_MIN_SEQ = 4096
 def attention(q, k, v, causal: bool = False, *,
               min_flash_seq: Optional[int] = None,
               blk_q: Optional[int] = None,
-              blk_k: Optional[int] = None):
+              blk_k: Optional[int] = None,
+              scale: Optional[float] = None):
     """Sequence-length-routed attention: the pallas flash kernel at
     ``L >= min_flash_seq`` (default :data:`FLASH_MIN_SEQ`), XLA's fused
-    dense attention below. GQA inputs (fewer K/V heads) work on both paths
-    — dense broadcasts the KV groups at compute time."""
+    dense attention below. GQA inputs (fewer K/V heads) and a value width
+    other than the scores' work on both paths — dense broadcasts the KV
+    groups at compute time."""
     threshold = FLASH_MIN_SEQ if min_flash_seq is None else int(min_flash_seq)
     if q.shape[2] >= threshold:
-        return flash_attention(q, k, v, causal, blk_q, blk_k)
+        return flash_attention(q, k, v, causal, blk_q, blk_k, None, scale)
     if k.shape[1] != q.shape[1]:
         group = q.shape[1] // k.shape[1]
         k = jnp.repeat(k, group, axis=1)
         v = jnp.repeat(v, group, axis=1)
-    return _dense_attention(q, k, v, causal)
+    return _dense_attention(q, k, v, causal, scale)

@@ -209,6 +209,16 @@ def render_waterfall(profiles: List[dict], width: int = 40,
                     f"{(f'{step:.2f}' if step else '-'):>8} "
                     f"{(f'{mfu:.3f}' if mfu else '-'):>6} "
                     f"{(_fmt_bytes(hbm) if hbm else '-'):>9}")
+            for lid in sorted(learners):
+                # what the learner's module counted, a step (a routed
+                # layer's assignments on held experts, its largest group)
+                counts = {k: v for k, v in
+                          (learners[lid].get("device") or {}).items()
+                          if k.endswith("_count")}
+                if counts:
+                    lines.append(f"  counts {lid}  " + "  ".join(
+                        f"{k[:-len('_count')]} {float(v):.0f}"
+                        for k, v in sorted(counts.items())) + "  a step")
         lines.append("")
     return "\n".join(lines).rstrip()
 

@@ -25,6 +25,8 @@ from benchmark.lib import common, spec
 TRACE_ONLY = {
     "train_step_mfu", "hybrid_step_mfu", "flash_roofline",
     "ssm_scan_roofline", "ssm_scan_share", "serve_mfu",
+    "mla_moe_step_mfu", "mla_flash_roofline", "moe_experts_roofline",
+    "moe_experts_share",
     "device_idle_share.train", "device_idle_share.serve"}
 # what a CPU's clock leaves of each reading
 POSITIVE = {"step_ms", "decode_step_ms", "slot_ms", "prefill_ms",
