@@ -2,22 +2,30 @@
 
 Blockwise attention with online softmax: the (L, L) score matrix never
 reaches HBM. Forward and backward are Mosaic-native grid-accumulation
-kernels — the KV (resp. Q) block index is a sequential GRID dimension,
-running statistics live in VMEM scratch across grid steps, and causal
-skipping is ``pl.when`` predication of whole blocks. No dynamic loop trip
-counts anywhere (an earlier revision drove a ``fori_loop`` with a
-program-id-dependent bound; grid predication is the pattern the TPU
-toolchain is built for), and K/V stream through VMEM one block per step, so
-VMEM stays bounded at any sequence length.
+kernels — the sequential GRID dimension walks (query block, key block)
+pairs, running statistics live in VMEM scratch across grid steps, and K/V
+stream through VMEM one block per step, so VMEM stays bounded at any
+sequence length. No dynamic loop trip counts anywhere.
+
+The block structure is decided once, at trace time, from shapes
+(:func:`_block_classes`): a pair with no unmasked element is DEAD and gets
+no grid step at all — the sequential dimension enumerates live pairs only,
+through small int32 tables handed to the kernels as scalar prefetch
+(:func:`_step_tables`: which query block, which key block, and whether
+the step is the first or last of its output block), so causal mode costs
+about half the steps, FLOPs and HBM traffic of full attention. A pair
+with every element unmasked is WHOLE and runs a body with no mask at all;
+only a pair the diagonal or the ragged tail cuts through is MASKED and
+builds its mask from iotas. :func:`flash_block_census` counts the three
+(10 live of 16 a head at 4,096 by the automatic edge of 1024, causal:
+6 whole, 4 masked; 36 of 64 at 512: 28 and 8). The
+arithmetic of a live element is the same in both bodies, bit for bit.
 
 The backward is the FlashAttention-2 scheme: dQ accumulates over KV blocks,
 dK/dV accumulate over Q blocks, both recomputing probabilities from the
 forward's saved logsumexp — training memory is O(L·D) end to end. The
 forward accumulator is FA2's unnormalized numerator (one alpha rescale per
-step, a single divide at the store). Causal mode skips fully-masked blocks
-in all three kernels (~half the FLOPs), and the skipped steps' block
-index maps clamp to the last valid block so the pipeline elides their
-DMAs too (~half the HBM traffic).
+step, a single divide at the store).
 
 Where it wins: the kernel's value is O(L·D) memory (the (L, L) score
 matrix never materializes), which is what makes long sequences fit at all;
@@ -25,8 +33,9 @@ on raw speed XLA's fused dense attention is competitive at moderate L
 (dense against flash at 2,048 is not measured on today's code), with the
 kernel's causal block skip paying off as L grows past the score-matrix
 memory wall. At 4,096, in the learner's step of the cell
-``internlm2-1.8b.lora-round``, the kernels run at 36.57 % of their roofline
-(``flash_roofline``; PERF_LEDGER.jsonl, PR 29). Use
+``internlm2-1.8b.lora-round``, the kernels run at 52.05 % of their
+roofline (``flash_roofline``; the builder's chip run, PERF.md PR 33;
+36.56 % before it, PERF_LEDGER.jsonl, PR 32). Use
 :func:`attention` to route between the two on sequence length instead of
 hand-picking.
 
@@ -58,53 +67,68 @@ _STAT_LANES = 8   # lse/delta in HBM: minimal tile-legal lane replication
 # the full array dim; 128-lane replication in HBM would put the VJP's lse
 # residual on par with Q itself at long sequence lengths)
 
-
-def _causal_overlap(qi, blk_q, kj, blk_k):
-    """True when key block kj has any unmasked column for query block qi."""
-    return kj * blk_k <= (qi + 1) * blk_q - 1
+# a (query block, key block) pair's class, by the mask `_mask_for` would
+# build for it: no element unmasked, every element, or some
+_DEAD, _WHOLE, _MASKED = 0, 1, 2
+# a grid step's flags (third prefetched table): first / last step of its
+# output block, and whether its pair is _MASKED
+_F_FIRST, _F_LAST, _F_MASKED = 1, 2, 4
 
 
 def _mask_for(qi, blk_q, kj, blk_k, kv_len, causal):
-    q_pos = qi * blk_q + jax.lax.broadcasted_iota(
-        jnp.int32, (blk_q, blk_k), 0)
+    """The mask of one _MASKED block. ``kv_len`` is None where the keys
+    have no padded tail (the comparison would be all true)."""
     k_pos = kj * blk_k + jax.lax.broadcasted_iota(
         jnp.int32, (blk_q, blk_k), 1)
-    mask = k_pos < kv_len                       # tail-padding mask
+    mask = None if kv_len is None else k_pos < kv_len   # tail-padding mask
     if causal:
-        mask &= q_pos >= k_pos
+        q_pos = qi * blk_q + jax.lax.broadcasted_iota(
+            jnp.int32, (blk_q, blk_k), 0)
+        mask = q_pos >= k_pos if mask is None else mask & (q_pos >= k_pos)
     return mask
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
-                causal: bool, scale: float, kv_len: int, nk: int):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    blk_q, D = q_ref.shape[1], q_ref.shape[2]
+def _for_class(flag, has_whole: bool, has_masked: bool, body):
+    """Run ``body(masked)`` for the class ``flag`` names. A class the
+    step tables do not hold (known at trace time) gets no code."""
+    if has_whole and has_masked:
+        pl.when((flag & _F_MASKED) == 0)(lambda: body(False))
+        pl.when((flag & _F_MASKED) != 0)(lambda: body(True))
+    else:
+        body(has_masked)
+
+
+def _fwd_kernel(q_of, k_of, flags, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_s, l_s, acc_s, *, causal: bool, scale: float, kv_len,
+                has_whole: bool, has_masked: bool):
+    t = pl.program_id(1)
+    flag = flags[t]
+    blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
 
-    @pl.when(kj == 0)
+    @pl.when((flag & _F_FIRST) != 0)
     def _init():
         m_s[...] = jnp.full(m_s.shape, _NEG, jnp.float32)
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    run = _causal_overlap(qi, blk_q, kj, blk_k) if causal else True
-
-    @pl.when(run)
-    def _attend():
+    def _attend(masked: bool):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = _mask_for(qi, blk_q, kj, blk_k, kv_len, causal)
-        s = jnp.where(mask, s, _NEG)
+        mask = (_mask_for(q_of[t], blk_q, k_of[t], blk_k, kv_len, causal)
+                if masked else None)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG)
         m_prev = m_s[...]                       # (blk_q, LANES), lanes equal
         l_prev = l_s[...]
         m_curr = jnp.max(s, axis=1)[:, None]    # (blk_q, 1)
         m_next = jnp.maximum(m_prev, m_curr)    # (blk_q, LANES)
         p = jnp.exp(s - m_next[:, :1])
-        p = jnp.where(mask, p, 0.0)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_next)        # (blk_q, LANES)
         m_s[...] = m_next
         l_s[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
@@ -115,7 +139,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         acc_s[...] = acc_s[...] * alpha[:, :1] + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    @pl.when(kj == nk - 1)
+    _for_class(flag, has_whole, has_masked, _attend)
+
+    @pl.when((flag & _F_LAST) != 0)
     def _store():
         l_fin = l_s[...]
         # fully-masked rows (tail padding) have l == 0: emit 0, not nan
@@ -125,21 +151,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
             :, :_STAT_LANES]
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_s, *, causal: bool, scale: float, kv_len: int, nk: int):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+def _dq_kernel(q_of, k_of, flags, q_ref, k_ref, v_ref, do_ref, lse_ref,
+               delta_ref, dq_ref, dq_s, *, causal: bool, scale: float,
+               kv_len, has_whole: bool, has_masked: bool):
+    t = pl.program_id(1)
+    flag = flags[t]
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
 
-    @pl.when(kj == 0)
+    @pl.when((flag & _F_FIRST) != 0)
     def _init():
         dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
 
-    run = _causal_overlap(qi, blk_q, kj, blk_k) if causal else True
-
-    @pl.when(run)
-    def _accumulate():
+    def _accumulate(masked: bool):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -148,43 +172,44 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         delta = delta_ref[0][:, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = _mask_for(qi, blk_q, kj, blk_k, kv_len, causal)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        p = jnp.exp(s - lse)
+        mask = (_mask_for(q_of[t], blk_q, k_of[t], blk_k, kv_len, causal)
+                if masked else None)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
         dq_s[...] += jax.lax.dot(ds.astype(k.dtype), k,
                                  preferred_element_type=jnp.float32)
 
-    @pl.when(kj == nk - 1)
+    _for_class(flag, has_whole, has_masked, _accumulate)
+
+    @pl.when((flag & _F_LAST) != 0)
     def _store():
         dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_s, dv_s, *, causal: bool, scale: float,
-                kv_len: int, nq: int, g_size: int = 1):
-    kj = pl.program_id(1)
-    # sequential dim enumerates (group member × q block), MEMBER-MAJOR
-    # (t = member * nq + qi): the dK/dV of one KV head accumulates over
-    # every query head in its group, and within one member's segment the
-    # head component of the block index is constant — so the causal
-    # clamp's repeated indices actually elide DMAs (q-block-major would
-    # cycle heads every step and never repeat an index)
-    t = pl.program_id(2)
-    qi = t % nq
+def _dkv_kernel(qm_of, k_of, flags, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, dk_ref, dv_ref, dk_s, dv_s, *, causal: bool,
+                scale: float, kv_len, nq: int, has_whole: bool,
+                has_masked: bool):
+    # the dK/dV of one KV head accumulates over every query head in its
+    # group: the steps of a key block walk (group member, query block),
+    # MEMBER-MAJOR, and ``qm_of`` holds member * nq + query block. Within
+    # one member's segment the head component of the block index is
+    # constant (q-block-major would cycle heads every step)
+    t = pl.program_id(1)
+    flag = flags[t]
     blk_k = k_ref.shape[1]
     blk_q = q_ref.shape[1]
 
-    @pl.when(t == 0)
+    @pl.when((flag & _F_FIRST) != 0)
     def _init():
         dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
 
-    run = _causal_overlap(qi, blk_q, kj, blk_k) if causal else True
-
-    @pl.when(run)
-    def _accumulate():
+    def _accumulate(masked: bool):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -193,8 +218,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0][:, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = _mask_for(qi, blk_q, kj, blk_k, kv_len, causal)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        p = jnp.exp(s - lse)
+        mask = (_mask_for(qm_of[t] % nq, blk_q, k_of[t], blk_k, kv_len,
+                          causal) if masked else None)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
         # dV += P^T @ dO
         dv_s[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -207,7 +235,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(t == nq * g_size - 1)
+    _for_class(flag, has_whole, has_masked, _accumulate)
+
+    @pl.when((flag & _F_LAST) != 0)
     def _store():
         dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
@@ -241,31 +271,108 @@ def _pad_len(L: int, blk: int) -> int:
     return (L + blk - 1) // blk * blk
 
 
-def _auto_blk(L: int) -> int:
-    """Largest block edge in {512, 256, 128} that divides the 8-aligned
-    sequence length. Bigger blocks cut grid steps (less per-step predication
-    / scratch traffic, larger MXU matmuls) and stay well inside VMEM —
-    q/k/v/do blocks at 512x128 bf16 are 128 KB each, the f32 scratch
-    accumulators 256 KB — but an edge that does NOT divide L would pad the
-    grid up to the next multiple and burn the padding as masked FLOPs
-    (e.g. L=640 at blk 512 pads to 1024: ~2.5x the work), so divisibility
-    wins over size."""
+_WIDE_EDGE_MAX_WIDTH = 256   # widest head (scores or values) at edge 1024
+
+
+def _auto_blk(L: int, D: int = 128, Dv: int = 128) -> int:
+    """Largest block edge in {1024, 512, 256, 128} that divides the
+    8-aligned sequence length. Bigger blocks cut grid steps: a step's
+    cost outside the two products (statistics and accumulator read,
+    rescaled and written back, the pipeline's bookkeeping) is per step,
+    and at 4,096 causal a layer's forward and backward take 2.54 ms at
+    1024 against 3.08 at 512 and 6.29 at 256 (16 query heads on 8 KV
+    heads of 128; PERF.md, PR 33), though 1024 computes 40 blocks' worth
+    of scores for 32 under the diagonal where 512 computes 36. 1024 holds
+    while scores and values are at most 256 wide (a 1024 x 1024 float32
+    score block is 4 MB of VMEM and the kernels keep a few; the chip's
+    compiler refuses 384 in float32 and 512 in bfloat16). An edge that
+    does NOT divide L would pad the grid up to the next multiple and burn
+    the padding as masked FLOPs (e.g. L=640 at blk 512 pads to 1024:
+    ~2.5x the work), so divisibility wins over size."""
     L8 = _pad_len(L, 8)
-    for cand in (512, 256, 128):
+    edges = ((1024, 512, 256, 128) if max(D, Dv) <= _WIDE_EDGE_MAX_WIDTH
+             else (512, 256, 128))
+    for cand in edges:
         if cand <= L8 and L8 % cand == 0:
             return cand
     return min(128, L8)
 
 
-def _resolve_blocks(L: int, blk_q: Optional[int], blk_k: Optional[int]):
-    blk_q = min(blk_q or _auto_blk(L), _pad_len(L, 8))
-    blk_k = min(blk_k or _auto_blk(L), _pad_len(L, 8))
+def _resolve_blocks(L: int, blk_q: Optional[int], blk_k: Optional[int],
+                    D: int = 128, Dv: int = 128):
+    blk_q = min(blk_q or _auto_blk(L, D, Dv), _pad_len(L, 8))
+    blk_k = min(blk_k or _auto_blk(L, D, Dv), _pad_len(L, 8))
     Lp = max(_pad_len(L, blk_q), _pad_len(L, blk_k))
     return blk_q, blk_k, Lp
 
 
+def _block_classes(L: int, blk_q: int, blk_k: int, Lp: int,
+                   causal: bool) -> np.ndarray:
+    """(query blocks, key blocks) array of _DEAD / _WHOLE / _MASKED: what
+    `_mask_for`'s mask (key inside ``L``, and under the diagonal where
+    causal) holds of each block — nothing, everything, or some. Padded
+    QUERY rows are not masked (their results are cut off), so they do not
+    enter."""
+    qi = np.arange(Lp // blk_q)[:, None]
+    kj = np.arange(Lp // blk_k)[None, :]
+    some = np.broadcast_to(kj * blk_k < L, (qi.size, kj.size))
+    every = np.broadcast_to((kj + 1) * blk_k <= L, some.shape)
+    if causal:
+        some = some & (kj * blk_k <= (qi + 1) * blk_q - 1)
+        every = every & ((kj + 1) * blk_k - 1 <= qi * blk_q)
+    return np.where(every, _WHOLE, np.where(some, _MASKED, _DEAD))
+
+
+def flash_block_census(L: int, blk_q: Optional[int] = None,
+                       blk_k: Optional[int] = None,
+                       causal: bool = False) -> dict:
+    """How many (query block, key block) pairs one head's kernels walk:
+    ``live`` grid steps, of them ``whole`` on the body without a mask and
+    ``masked`` on the body with one, against the ``walked_before`` of a
+    grid over every pair. Counted from the very classes the step tables
+    are built from; an edge left None is the automatic one at widths of
+    128."""
+    blk_q, blk_k, Lp = _resolve_blocks(L, blk_q, blk_k)
+    classes = _block_classes(L, blk_q, blk_k, Lp, causal)
+    whole = int((classes == _WHOLE).sum())
+    masked = int((classes == _MASKED).sum())
+    return {"live": whole + masked, "whole": whole, "masked": masked,
+            "walked_before": int(classes.size)}
+
+
+def _step_tables(classes: np.ndarray, members: Optional[int] = None):
+    """The live pairs in walking order, as the three int32 tables the
+    kernels prefetch. ``members=None`` walks by query block, key blocks
+    ascending within it (forward, dQ). ``members=G`` walks by key block,
+    member-major and query blocks ascending within it (dK/dV), and the
+    first table holds ``member * nq + query block``. The third holds
+    _F_FIRST / _F_LAST on the first and last step of each output block
+    and _F_MASKED on a _MASKED pair. A key block wholly in the padding
+    has no live pair and gets no step: its rows of dK/dV are never
+    written, and are cut off with the padding."""
+    nq, nk = classes.shape
+    classes = classes.tolist()      # plain ints: this loop runs per trace
+    if members is None:     # an output block is a query block
+        walks = [[(i, i, j) for j in range(nk)] for i in range(nq)]
+    else:                   # an output block is a key block
+        walks = [[(m * nq + i, i, j)
+                  for m in range(members) for i in range(nq)]
+                 for j in range(nk)]
+    q_of, k_of, flags = [], [], []
+    for walk in walks:
+        live = [(entry, j, classes[i][j]) for entry, i, j in walk
+                if classes[i][j] != _DEAD]
+        for at, (entry, j, cls) in enumerate(live):
+            q_of.append(entry)
+            k_of.append(j)
+            flags.append(_F_FIRST * (at == 0)
+                         | _F_LAST * (at == len(live) - 1)
+                         | _F_MASKED * (cls == _MASKED))
+    return tuple(np.asarray(x, np.int32) for x in (q_of, k_of, flags))
+
+
 _SEQ_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+    dimension_semantics=("parallel", "arbitrary"))
 
 
 def _kv_head_index(Hq: int, Hkv: int):
@@ -276,21 +383,28 @@ def _kv_head_index(Hq: int, Hkv: int):
     return lambda b: (b // Hq) * Hkv + (b % Hq) // G
 
 
-def _kv_block_index(kv_ix, blk_q: int, blk_k: int, causal: bool):
-    """K/V block index map for the forward and dQ kernels. In causal mode
-    the index clamps to the last unmasked block for the current query
-    block: skipped steps (`pl.when` predicated off) then re-request the
-    SAME block and the Mosaic pipeline elides the copy — causal saves
-    ~half the HBM traffic, not just half the FLOPs. The clamp bound must
-    match `_causal_overlap`'s run predicate (identical on live steps)."""
-    if causal:
-        def ix(b, i, j):
-            return (kv_ix(b), jnp.minimum(j, ((i + 1) * blk_q - 1)
-                                          // blk_k), 0)
-    else:
-        def ix(b, i, j):
-            return (kv_ix(b), j, 0)
-    return ix
+def _walk_call(kernel, name: str, classes: np.ndarray, members, heads: int,
+               interpret: bool, operands, *, in_specs, out_specs, out_shape,
+               scratch_shapes, **static):
+    """One kernel over ``heads`` × the live steps of ``classes``: the step
+    tables ride in front of the operands as scalar prefetch (index maps
+    and kernel both read them), and the kernel holds a body only for the
+    classes the tables hold."""
+    tables = _step_tables(classes, members)
+    masked = (tables[2] & _F_MASKED) != 0
+    return pl.pallas_call(
+        functools.partial(kernel, has_whole=bool((~masked).any()),
+                          has_masked=bool(masked.any()), **static),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(heads, len(tables[0])),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        compiler_params=None if interpret else _SEQ_PARAMS,
+        interpret=interpret,
+        name=name,
+    )(*tables, *operands)
 
 
 def _gqa_shapes(q, k, v):
@@ -311,7 +425,7 @@ def _gqa_shapes(q, k, v):
 def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
                    interpret: bool, scale: Optional[float] = None):
     B, H, Hkv, L, D, Dv = _gqa_shapes(q, k, v)
-    blk_q, blk_k, Lp = _resolve_blocks(L, blk_q, blk_k)
+    blk_q, blk_k, Lp = _resolve_blocks(L, blk_q, blk_k, D, Dv)
     scale = _score_scale(q, scale)
     kv_ix = _kv_head_index(H, Hkv)
     qf = q.reshape(B * H, L, D)
@@ -320,37 +434,33 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
     if Lp != L:
         pad = ((0, 0), (0, Lp - L), (0, 0))
         qf, kf, vf = (jnp.pad(x, pad) for x in (qf, kf, vf))
-    nk = Lp // blk_k
-    kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                               kv_len=L, nk=nk)
-    kv_index = _kv_block_index(kv_ix, blk_q, blk_k, causal)
-    out, lse = pl.pallas_call(
-        kernel,
+    q_index = lambda b, t, q_of, k_of, flags: (b, q_of[t], 0)
+    kv_index = lambda b, t, q_of, k_of, flags: (kv_ix(b), k_of[t], 0)
+    out, lse = _walk_call(
+        _fwd_kernel, "flash_fwd",
+        _block_classes(L, blk_q, blk_k, Lp, causal), None, B * H, interpret,
+        (qf, kf, vf),
+        in_specs=[
+            pl.BlockSpec((1, blk_q, D), q_index),
+            pl.BlockSpec((1, blk_k, D), kv_index),
+            pl.BlockSpec((1, blk_k, Dv), kv_index),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, blk_q, Dv), q_index),
+            pl.BlockSpec((1, blk_q, _STAT_LANES), q_index),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Lp, Dv), q.dtype),
             # logsumexp replicated across the lane dim (2D-tiled layout;
             # callers slice [:, :, 0])
             jax.ShapeDtypeStruct((B * H, Lp, _STAT_LANES), jnp.float32),
         ],
-        grid=(B * H, Lp // blk_q, nk),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_k, D), kv_index),
-            pl.BlockSpec((1, blk_k, Dv), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_q, Dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q, _STAT_LANES), lambda b, i, j: (b, i, 0)),
-        ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, _LANES), jnp.float32),   # m
             pltpu.VMEM((blk_q, _LANES), jnp.float32),   # l
             pltpu.VMEM((blk_q, Dv), jnp.float32),       # acc
         ],
-        compiler_params=None if interpret else _SEQ_PARAMS,
-        interpret=interpret,
-        name="flash_fwd",
-    )(qf, kf, vf)
+        causal=causal, scale=scale, kv_len=None if L == Lp else L)
     return out[:, :L].reshape(B, H, L, Dv), lse
 
 
@@ -364,7 +474,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
     value could overflow p = exp(s - lse) into inf·0 = nan."""
     B, H, Hkv, L, D, Dv = _gqa_shapes(q, k, v)
     G = H // Hkv
-    blk_q, blk_k, Lp = _resolve_blocks(L, blk_q, blk_k)
+    blk_q, blk_k, Lp = _resolve_blocks(L, blk_q, blk_k, D, Dv)
     scale = _score_scale(q, scale)
     kv_ix = _kv_head_index(H, Hkv)
     flat = lambda x: x.reshape(-1, L, x.shape[-1])
@@ -383,69 +493,59 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
     delta = jnp.broadcast_to(delta[..., None], (B * H, Lp, _STAT_LANES))
     lse = jnp.broadcast_to(lse[..., None], (B * H, Lp, _STAT_LANES))
     nq = Lp // blk_q
-    nk = Lp // blk_k
+    classes = _block_classes(L, blk_q, blk_k, Lp, causal)
+    static = dict(causal=causal, scale=scale, kv_len=None if L == Lp else L)
 
-    kv_index = _kv_block_index(kv_ix, blk_q, blk_k, causal)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale,
-                          kv_len=L, nk=nk),
-        out_shape=jax.ShapeDtypeStruct((B * H, Lp, D), q.dtype),
-        grid=(B * H, nq, nk),
+    q_index = lambda b, t, q_of, k_of, flags: (b, q_of[t], 0)
+    kv_index = lambda b, t, q_of, k_of, flags: (kv_ix(b), k_of[t], 0)
+    dq = _walk_call(
+        _dq_kernel, "flash_bwd_dq", classes, None, B * H, interpret,
+        (qf, kf, vf, gf, lse, delta),
         in_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, D), q_index),
             pl.BlockSpec((1, blk_k, D), kv_index),
             pl.BlockSpec((1, blk_k, Dv), kv_index),
-            pl.BlockSpec((1, blk_q, Dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q, _STAT_LANES), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q, _STAT_LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, Dv), q_index),
+            pl.BlockSpec((1, blk_q, _STAT_LANES), q_index),
+            pl.BlockSpec((1, blk_q, _STAT_LANES), q_index),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, blk_q, D), q_index),
+        out_shape=jax.ShapeDtypeStruct((B * H, Lp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        compiler_params=None if interpret else _SEQ_PARAMS,
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qf, kf, vf, gf, lse, delta)
+        **static)
 
-    # dK/dV accumulate over (group member × q block), member-major
-    # (t = member * nq + qi): grid b runs over B*Hkv KV heads. In causal
-    # mode, Q blocks strictly above the diagonal are skipped — clamp
-    # their index up to the first contributing block; within a member's
-    # segment the head component is constant, so those repeated indices
-    # elide the leading DMAs of every segment.
-    def q_ix(b, j, t):
-        qi = t % nq
-        if causal:
-            qi = jnp.maximum(qi, (j * blk_k) // blk_q)
-        return ((b // Hkv) * H + (b % Hkv) * G + t // nq, qi, 0)
+    # dK/dV: grid b runs over B*Hkv KV heads, and a key block's steps
+    # over (group member, live query block), member-major
+    # (qm_of[t] = member * nq + query block)
+    def qm_index(b, t, qm_of, k_of, flags):
+        return ((b // Hkv) * H + (b % Hkv) * G + qm_of[t] // nq,
+                qm_of[t] % nq, 0)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          kv_len=L, nq=nq, g_size=G),
+    k_index = lambda b, t, qm_of, k_of, flags: (b, k_of[t], 0)
+    dk, dv = _walk_call(
+        _dkv_kernel, "flash_bwd_dkv", classes, G, B * Hkv, interpret,
+        (qf, kf, vf, gf, lse, delta),
+        in_specs=[
+            pl.BlockSpec((1, blk_q, D), qm_index),
+            pl.BlockSpec((1, blk_k, D), k_index),
+            pl.BlockSpec((1, blk_k, Dv), k_index),
+            pl.BlockSpec((1, blk_q, Dv), qm_index),
+            pl.BlockSpec((1, blk_q, _STAT_LANES), qm_index),
+            pl.BlockSpec((1, blk_q, _STAT_LANES), qm_index),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, blk_k, D), k_index),
+            pl.BlockSpec((1, blk_k, Dv), k_index),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, Lp, D), k.dtype),
             jax.ShapeDtypeStruct((B * Hkv, Lp, Dv), v.dtype),
-        ],
-        grid=(B * Hkv, nk, nq * G),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, D), q_ix),
-            pl.BlockSpec((1, blk_k, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, Dv), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, blk_q, Dv), q_ix),
-            pl.BlockSpec((1, blk_q, _STAT_LANES), q_ix),
-            pl.BlockSpec((1, blk_q, _STAT_LANES), q_ix),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_k, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, Dv), lambda b, j, t: (b, j, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, D), jnp.float32),
             pltpu.VMEM((blk_k, Dv), jnp.float32),
         ],
-        compiler_params=None if interpret else _SEQ_PARAMS,
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(qf, kf, vf, gf, lse, delta)
+        nq=nq, **static)
 
     return (dq[:, :L].reshape(B, H, L, D),
             dk[:, :L].reshape(B, Hkv, L, D),
@@ -465,8 +565,8 @@ def flash_attention(q, k, v, causal: bool = False,
     native: ``k``/``v`` may carry fewer heads than ``q`` (Hq a multiple of
     Hkv) and stay at kv-head size in HBM — block index maps route each
     query head to its KV group; dK/dV accumulate over the group in the
-    backward. ``blk_q``/``blk_k=None`` auto-size blocks (512 capped to the
-    padded sequence). ``interpret=None`` auto-selects interpret mode
+    backward. ``blk_q``/``blk_k=None`` auto-size blocks (:func:`_auto_blk`,
+    capped to the padded sequence). ``interpret=None`` auto-selects interpret mode
     off-TPU so the same call works in CI and on chip."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

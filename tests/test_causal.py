@@ -451,8 +451,9 @@ def test_flash_attention_imports_cleanly():
     mod = importlib.import_module("metisfl_tpu.ops.flash_attention")
     from jax.experimental.pallas import tpu as pltpu
     assert isinstance(mod._SEQ_PARAMS, pltpu.CompilerParams)
-    assert mod._SEQ_PARAMS.dimension_semantics == ("parallel", "parallel",
-                                                   "arbitrary")
+    # (heads, live steps): the live (query block, key block) pairs are one
+    # sequential dimension since PR 33
+    assert mod._SEQ_PARAMS.dimension_semantics == ("parallel", "arbitrary")
 
 
 # --------------------------------------------------------------------- #
