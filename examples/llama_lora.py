@@ -18,6 +18,11 @@ for the latent-attention decoder with a share of its routed experts
 (``MlaMoeLite``: a dense block, then blocks whose router chooses 4 of 16
 experts of which this model holds 4, beside a shared expert; adapters on
 the four latent projections; the frozen base held in bfloat16).
+``--shortcut`` swaps it for the shortcut-connected decoder (``ScMoeLite``:
+two latent-attention sublayers and two dense FFNs a layer beside one
+routed layer whose softmax router chooses 4 of 16 routed and 8
+zero-computation experts, 4 routed ones held; two latent caches a layer in
+the decode).
 """
 
 from __future__ import annotations
@@ -46,6 +51,11 @@ def main() -> int:
                         help="MlaMoeLite (latent attention, routed experts "
                              "of which a share is held, a bfloat16 base) "
                              "in LlamaLite's place")
+    parser.add_argument("--shortcut", action="store_true",
+                        help="ScMoeLite (two latent-attention sublayers and "
+                             "two dense FFNs a layer beside a routed layer "
+                             "with zero-computation experts, a bfloat16 "
+                             "base) in LlamaLite's place")
     parser.add_argument("--scan-chunk", type=int, default=1,
                         help="fuse this many local steps into one compiled "
                              "scan program (dispatch amortization on TPU)")
@@ -65,7 +75,7 @@ def main() -> int:
     from metisfl_tpu.driver import InProcessFederation
     from metisfl_tpu.models import ArrayDataset, FlaxModelOps
     from metisfl_tpu.models.zoo import (TRANSFORMER_RULES, JambaLite,
-                                        LlamaLite, MlaMoeLite)
+                                        LlamaLite, MlaMoeLite, ScMoeLite)
     from metisfl_tpu.parallel.mesh import MeshConfig, build_mesh
 
     mesh = build_mesh(MeshConfig(("dp", "tp"), (args.dp, args.tp)))
@@ -102,6 +112,16 @@ def main() -> int:
                             rope_mscale_all_dim=1.0,
                             lora_rank=args.lora_rank, dtype=jnp.bfloat16,
                             param_dtype=jnp.bfloat16)
+    elif args.shortcut:
+        import jax.numpy as jnp
+        module = ScMoeLite(vocab_size=args.vocab, dim=args.dim,
+                           depth=max(2, args.depth // 2), heads=args.heads,
+                           q_rank=args.dim // 4, kv_rank=args.dim // 8,
+                           nope_dim=16, rope_dim=8, v_dim=16,
+                           moe_hidden=args.dim // 2, num_experts=16,
+                           zero_experts=8, top_k=4, experts_count=4,
+                           routed_scale=6.0, lora_rank=args.lora_rank,
+                           dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     else:
         module = LlamaLite(vocab_size=args.vocab, dim=args.dim,
                            depth=args.depth, heads=args.heads,

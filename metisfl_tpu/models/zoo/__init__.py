@@ -4,7 +4,8 @@ TPU-native counterparts of the reference's example model zoo
 (reference examples/keras/models/*.py, examples/pytorch/models/mlp.py):
 small federated workloads (MLP, CNNs, LSTM) plus the scale-ladder models
 from BASELINE.md (ResNet-20, ViT, BERT, Llama+LoRA) and the attention /
-state-space hybrid JambaLite (Mamba mixer + MQA attention, tied head).
+state-space hybrid JambaLite (Mamba mixer + MQA attention, tied head), the
+latent-attention decoders MlaMoeLite and ScMoeLite.
 """
 
 from metisfl_tpu.models.zoo.mlp import MLP, HousingMLP
@@ -22,6 +23,8 @@ from metisfl_tpu.models.zoo.transformer import (
     MambaMixer,
     MlaMoeLite,
     MoEMLP,
+    ScMoeLite,
+    ShortcutMoEBlock,
     ViTLite,
 )
 
@@ -30,6 +33,7 @@ __all__ = [
     "BrainAge3DCNN", "LSTMClassifier",
     "ViTLite", "BertLite", "LlamaLite", "JambaLite", "MambaMixer",
     "MlaMoeLite", "LatentAttention", "ExpertShareMLP",
+    "ScMoeLite", "ShortcutMoEBlock",
     "LoRADense", "MoEMLP",
     "TRANSFORMER_RULES",
 ]

@@ -1,6 +1,8 @@
 """Transformer family: ViT-lite, BERT-lite, Llama-lite (+LoRA), the
-attention/state-space hybrid Jamba-lite, and the latent-attention decoder
-with a share of its routed experts, MlaMoe-lite.
+attention/state-space hybrid Jamba-lite, the latent-attention decoder
+with a share of its routed experts, MlaMoe-lite, and the shortcut-connected
+decoder ScMoe-lite (two latent-attention sublayers and two dense FFNs a
+layer beside one routed layer with zero-computation experts).
 
 The BASELINE.md scale ladder (ViT-B/16 semi-sync, BERT async + secure,
 Llama-3-8B-LoRA with in-learner sharding) needs transformer workloads the
@@ -423,8 +425,8 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
 class LatentAttention(nn.Module):
     """Multi-head latent attention, with ``h`` the block's normed input::
 
-        c_q = RMSNorm(h W_qa);  q = c_q W_qb      heads of [nope | rope]
-        [c_kv | k_rope] = h W_kva;  c_kv = RMSNorm(c_kv)
+        c_q = RMSNorm(h W_qa);  q = (c_q W_qb) s_q      heads of [nope | rope]
+        [c_kv | k_rope] = h W_kva;  c_kv = RMSNorm(c_kv) s_kv
         c_kv W_kvb                                  heads of [k_nope | v]
         key of a head = [k_nope | rotary(k_rope)], k_rope shared by all
         out = softmax(q k^T scale, causal) v W_o
@@ -433,8 +435,11 @@ class LatentAttention(nn.Module):
     in the half-split rotation of :func:`_rotary`; ``scale`` is
     ``(nope + rope) ** -0.5 * m ** 2`` with ``m = yarn_mscale(factor,
     mscale_all_dim)``, and cos and sin carry ``yarn_mscale(factor, mscale)
-    / m``. No bias anywhere. ``lora_rank`` puts adapters on the four
-    latent projections; ``o_proj`` has none.
+    / m``. ``s_q`` and ``s_kv`` (``q_scale``, ``kv_scale``; 1 leaves them
+    out) are the two latent scales of a family that sets them, ``(dim /
+    rank) ** 0.5`` there; ``k_rope`` is not scaled. No bias anywhere.
+    ``lora_rank`` puts adapters on the four latent projections; ``o_proj``
+    has none.
 
     ``cache`` is ``(c_kv (B, L_max, kv_rank), k_rope (B, L_max, rope))``:
     the latents, not the heads' keys and values (576 values a position
@@ -455,6 +460,8 @@ class LatentAttention(nn.Module):
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
     eps: float = 1e-6
     lora_rank: int = 0
     lora_alpha: float = 16.0
@@ -505,8 +512,16 @@ class LatentAttention(nn.Module):
             c_q = norm("q_a_norm")(proj("q_a_proj", self.q_rank)(h))
             q = proj("q_b_proj", H * (nope + rope))(c_q).reshape(
                 B, L, H, nope + rope)
+            if self.q_scale != 1.0:
+                q = (q.astype(jnp.float32) * self.q_scale).astype(q.dtype)
             kva = proj("kv_a_proj_with_mqa", self.kv_rank + rope)(h)
             c_kv = norm("kv_a_norm")(kva[..., :self.kv_rank])
+            if self.kv_scale != 1.0:
+                # in float32, rounded once (12 ** 0.5 is 0.13% off in
+                # bfloat16: every key and value alike); the cache holds
+                # the latent scaled
+                c_kv = (c_kv.astype(jnp.float32)
+                        * self.kv_scale).astype(c_kv.dtype)
             k_rope = kva[..., None, self.kv_rank:]          # (B, L, 1, rope)
             pos0 = (jnp.zeros((), jnp.int32) if cache is None
                     else jnp.asarray(position, jnp.int32))
@@ -566,6 +581,18 @@ class ExpertShareMLP(nn.Module):
         out = shared(h) + sum_{e chosen and held} g_e expert_e(h)
 
     each expert and the shared one ``W_down(silu(W_gate h) * W_up h)``.
+    ``score_func="softmax"`` scores by a softmax over the router's columns
+    in the sigmoid's place, and ``norm_topk=False`` leaves the chosen
+    scores as they are (``g_e = s_e * routed_scale``). With
+    ``zero_experts`` Z > 0 the router is ``num_experts + Z`` wide, the
+    columns past ``num_experts`` are zero-computation experts that return
+    their input, and the layer adds::
+
+        (sum_{e chosen, e >= num_experts} g_e) * h
+
+    one masked sum of gates times the input, which every chip computes
+    for its own tokens (like a shared expert: no weights, no exchange).
+
     What the experts held elsewhere would add is left out: on one chip the
     layer runs without its exchange, and the partial result goes on. No
     token is dropped and no capacity exists: the held assignments are
@@ -576,9 +603,11 @@ class ExpertShareMLP(nn.Module):
     (``e_score_correction_bias``) are float32 whatever ``param_dtype`` is.
 
     Sows, under ``intermediates``, ``moe_local_count`` (the assignments
-    that fell on held experts) and ``moe_max_group_count`` (the largest
-    group): counters, which ``FlaxModelOps`` sums and returns beside the
-    loss (``models/ops.py``)."""
+    that fell on held experts), ``moe_max_group_count`` (the largest
+    group) and, where there are zero-computation experts,
+    ``moe_zero_count`` (the assignments that fell on them): counters,
+    which ``FlaxModelOps`` sums and returns beside the loss
+    (``models/ops.py``)."""
 
     dim: int
     hidden: int
@@ -588,6 +617,9 @@ class ExpertShareMLP(nn.Module):
     count: int = 0              # 0 = all of them
     shared_hidden: int = 0      # 0 = no shared expert
     routed_scale: float = 1.0
+    score_func: str = "sigmoid"     # or "softmax"
+    norm_topk: bool = True      # the chosen scores normalised to sum to 1
+    zero_experts: int = 0       # router columns past num_experts: identity
     dtype: Any = None
     param_dtype: Any = jnp.float32
     # None: the kernels on a TPU, ``ragged_dot`` elsewhere; True runs the
@@ -600,18 +632,23 @@ class ExpertShareMLP(nn.Module):
         B, L, D = x.shape
         E, K = self.num_experts, self.top_k
         G = self.count or E
+        width = E + self.zero_experts
+        if self.score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown score_func {self.score_func!r}")
+        score = nn.sigmoid if self.score_func == "sigmoid" else nn.softmax
         h = x.reshape(B * L, D)
         with jax.named_scope("moe_router"):
-            s = nn.sigmoid(nn.Dense(
-                E, use_bias=False, dtype=jnp.float32,
+            s = score(nn.Dense(
+                width, use_bias=False, dtype=jnp.float32,
                 precision=jax.lax.Precision.HIGHEST, name="router")(
                     h.astype(jnp.float32)))
             bias = self.param("e_score_correction_bias",
-                              nn.initializers.zeros, (E,), jnp.float32)
+                              nn.initializers.zeros, (width,), jnp.float32)
             _, chosen = jax.lax.top_k(s + bias, K)
-            picked = jnp.take_along_axis(s, chosen, axis=-1)
-            gates = picked / (jnp.sum(picked, -1, keepdims=True)
-                              + 1e-20) * self.routed_scale
+            gates = jnp.take_along_axis(s, chosen, axis=-1)
+            if self.norm_topk:
+                gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-20)
+            gates = gates * self.routed_scale
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
         stack = lambda name, a, b: self.param(             # noqa: E731
@@ -620,15 +657,23 @@ class ExpertShareMLP(nn.Module):
         w_up = stack("experts_up", D, self.hidden)
         w_down = stack("experts_down", self.hidden, D)
         dt = self.dtype or h.dtype
-        # scopes moe_dispatch, moe_experts, moe_combine inside
+        # scopes moe_dispatch, moe_experts, moe_combine inside; a choice
+        # outside the held range (a zero-computation one too) falls through
         out, sizes = gm.routed_experts(
             h.astype(dt), chosen, gates, w_gate.astype(dt), w_up.astype(dt),
-            w_down.astype(dt), first=self.first, num_experts=E,
+            w_down.astype(dt), first=self.first, num_experts=width,
             interpret=self.gmm_interpret)
         self.sow("intermediates", "moe_local_count",
                  jnp.sum(sizes).astype(jnp.float32))
         self.sow("intermediates", "moe_max_group_count",
                  jnp.max(sizes).astype(jnp.float32))
+        if self.zero_experts:
+            with jax.named_scope("moe_zero"):
+                zero = chosen >= E
+                out = out + (jnp.sum(jnp.where(zero, gates, 0.0), -1,
+                                     keepdims=True) * h).astype(out.dtype)
+                self.sow("intermediates", "moe_zero_count",
+                         jnp.sum(zero).astype(jnp.float32))
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
                 out = out + SwiGLU(D, self.shared_hidden, dtype=self.dtype,
@@ -1200,6 +1245,163 @@ class MlaMoeLite(nn.Module):
     def init_cache(self, batch: int, max_len: int):
         return tuple(self._mla().init_cache(batch, max_len)
                      for _ in range(self.depth))
+
+    def cache_kinds(self):
+        return ("kv",) * self.depth
+
+
+class ShortcutMoEBlock(nn.Module):
+    """One shortcut-connected layer: two latent-attention sublayers and two
+    dense FFNs on the residual line, and one routed layer that reads the
+    first sublayer's normed state and joins at the end of the second::
+
+        x1 = x  + MLA_0(norm_in0(x));     u = norm_post0(x1)
+        m  = MoE(u)                       the shortcut
+        x2 = x1 + SwiGLU_0(u)
+        x3 = x2 + MLA_1(norm_in1(x2));    w = norm_post1(x3)
+        y  = x3 + SwiGLU_1(w) + m
+
+    Every norm is an RMSNorm with its own scale. Nothing orders the routed
+    layer against the dense work between its call and its use: the
+    compiler may place it beside them. ``mla_0``, ``mla_1`` and ``moe`` are
+    the modules the model builds (unbound; flax adopts them under the
+    fields' names); ``cache`` is the two sublayers' latent caches."""
+
+    dim: int
+    ffn_dim: int
+    mla_0: Any
+    mla_1: Any
+    moe: Any
+    eps: float = 1e-6
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, cache=None, position=None):
+        def norm(name):
+            return nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name=name)
+
+        def mixer(mla, h, entry):
+            with jax.named_scope("attention_mixer"):
+                if entry is None:
+                    return mla(h), None
+                return mla(h, cache=entry, position=position)
+
+        def mlp(name, h):
+            with jax.named_scope("mlp"):
+                return SwiGLU(self.dim, self.ffn_dim, dtype=self.dtype,
+                              param_dtype=self.param_dtype, name=name)(h)
+
+        c0, c1 = (None, None) if cache is None else cache
+        a0, c0 = mixer(self.mla_0, norm("input_norm_0")(x), c0)
+        x = x + a0
+        u = norm("post_norm_0")(x)
+        with jax.named_scope("moe_shortcut"):
+            m = self.moe(u)
+        x = x + mlp("mlp_0", u)
+        a1, c1 = mixer(self.mla_1, norm("input_norm_1")(x), c1)
+        x = x + a1
+        x = x + mlp("mlp_1", norm("post_norm_1")(x)) + m
+        return x if cache is None else (x, (c0, c1))
+
+
+class ScMoeLite(nn.Module):
+    """Decoder-only causal LM of shortcut-connected layers
+    (:class:`ShortcutMoEBlock`): latent attention
+    (:class:`LatentAttention`, with its two latent scales ``(dim / rank) **
+    0.5`` where ``scale_q_lora`` / ``scale_kv_lora`` say so, plain rotary)
+    twice a layer, a dense SwiGLU twice, and a routed layer that holds a
+    share of the experts (:class:`ExpertShareMLP`: softmax scores over
+    ``num_experts + zero_experts`` columns, the chosen gates not
+    normalised, no shared expert); an untied head with float32 logits.
+    ``lora_rank > 0`` adds adapters on the four latent projections of both
+    sublayers; train with ``FlaxModelOps(trainable_regex="lora_")`` to
+    freeze the base. ``param_dtype`` is the type of the frozen matrices and
+    the embedding (norm scales, the router, its bias, the head and the
+    adapters stay float32).
+
+    Decoding: ``init_cache`` gives a layer's two latent caches ``((c_kv,
+    k_rope), (c_kv, k_rope))``, kind ``"kv"``."""
+
+    vocab_size: int = 8192
+    dim: int = 64
+    depth: int = 2
+    heads: int = 4
+    q_rank: int = 16
+    kv_rank: int = 8
+    nope_dim: int = 16
+    rope_dim: int = 8
+    v_dim: int = 16
+    ffn_dim: int = 0            # the dense FFNs' width; 0 = 2 x dim
+    moe_hidden: int = 32
+    num_experts: int = 16
+    zero_experts: int = 8
+    top_k: int = 4
+    experts_first: int = 0
+    experts_count: int = 0      # 0 = all of them
+    routed_scale: float = 1.0
+    scale_q_lora: bool = True
+    scale_kv_lora: bool = True
+    rope_base: float = 10000.0
+    eps: float = 1e-5
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    use_flash: Any = False
+    remat: bool = False
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+    gmm_interpret: Any = None       # see ExpertShareMLP
+
+    def _mla(self) -> LatentAttention:
+        return LatentAttention(
+            self.dim, self.heads, self.q_rank, self.kv_rank, self.nope_dim,
+            self.rope_dim, self.v_dim, rope_base=self.rope_base,
+            q_scale=((self.dim / self.q_rank) ** 0.5
+                     if self.scale_q_lora else 1.0),
+            kv_scale=((self.dim / self.kv_rank) ** 0.5
+                      if self.scale_kv_lora else 1.0),
+            eps=self.eps, lora_rank=self.lora_rank,
+            lora_alpha=self.lora_alpha, use_flash=self.use_flash,
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            parent=None)                        # the block adopts it
+
+    def _moe(self) -> ExpertShareMLP:
+        return ExpertShareMLP(
+            self.dim, self.moe_hidden, self.num_experts, self.top_k,
+            first=self.experts_first, count=self.experts_count,
+            routed_scale=self.routed_scale, score_func="softmax",
+            norm_topk=False, zero_experts=self.zero_experts,
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            gmm_interpret=self.gmm_interpret, parent=None)
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, caches=None,
+                 position=None):
+        del train                               # no dropout anywhere
+        x = nn.Embed(self.vocab_size, self.dim, dtype=self.dtype,
+                     param_dtype=self.param_dtype, name="embed")(tokens)
+        block_cls = (nn.remat(ShortcutMoEBlock)
+                     if self.remat and caches is None else ShortcutMoEBlock)
+        new_caches = []
+        for i in range(self.depth):
+            block = block_cls(self.dim, self.ffn_dim or 2 * self.dim,
+                              self._mla(), self._mla(), self._moe(),
+                              eps=self.eps, dtype=self.dtype,
+                              param_dtype=self.param_dtype,
+                              name=f"block_{i}")
+            if caches is not None:
+                x, c = block(x, cache=caches[i], position=position)
+                new_caches.append(c)
+            else:
+                x = block(x)
+        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype)(x)
+        logits = nn.Dense(self.vocab_size, use_bias=False,
+                          name="lm_head")(x.astype(jnp.float32))
+        return logits if caches is None else (logits, tuple(new_caches))
+
+    def init_cache(self, batch: int, max_len: int):
+        one = lambda: self._mla().init_cache(batch, max_len)   # noqa: E731
+        return tuple((one(), one()) for _ in range(self.depth))
 
     def cache_kinds(self):
         return ("kv",) * self.depth

@@ -95,6 +95,24 @@ def test_llama_lora_example_with_the_latent_decoder():
     assert "greedy continuation" in proc.stdout
 
 
+def test_llama_lora_example_with_the_shortcut_decoder():
+    """``--shortcut``: the shortcut-connected decoder (two latent-attention
+    sublayers, two dense FFNs and a routed layer with zero-computation
+    experts a layer) through the same federation and shipped subset, and a
+    decode that carries two latent caches a layer."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=2"}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "examples", "llama_lora.py"),
+         "--shortcut", "--learners", "2", "--rounds", "1", "--dp", "2",
+         "--tp", "1", "--lora-rank", "4"],
+        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert "completed 1 rounds" in proc.stdout
+    assert "B of adapters" in proc.stdout
+    assert "greedy continuation" in proc.stdout
+
+
 def test_ladder_rungs_execute(tmp_path):
     """BASELINE.md config ladder (VERDICT r3 #2): each rung's protocol x
     model combination actually executes and records round wall-clock. The

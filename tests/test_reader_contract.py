@@ -27,6 +27,8 @@ TRACE_ONLY = {
     "ssm_scan_roofline", "ssm_scan_share", "serve_mfu",
     "mla_moe_step_mfu", "mla_flash_roofline", "moe_experts_roofline",
     "moe_experts_share",
+    "scmoe_step_mfu", "scmoe_flash_roofline", "scmoe_experts_roofline",
+    "scmoe_experts_share",
     "device_idle_share.train", "device_idle_share.serve"}
 # what a CPU's clock leaves of each reading
 POSITIVE = {"step_ms", "decode_step_ms", "slot_ms", "prefill_ms",
@@ -82,9 +84,11 @@ def _lm(lora_rank: int):
                      lora_rank=lora_rank, dtype=jnp.float32)
 
 
-def _round_ctx(regex: str) -> dict:
+def _round_ctx(regex: str, module=None, cfg=None) -> dict:
     """Three rounds of one learner, the rounds after the first as the
-    window; ``regex`` is both what trains and what ships."""
+    window; ``regex`` is both what trains and what ships. ``module`` (32
+    token ids) and its configuration ``cfg`` stand in for the toy decoder
+    and its family."""
     from metisfl_tpu.comm.messages import TrainParams
     from metisfl_tpu.config import (EvalConfig, FederationConfig,
                                     TerminationConfig)
@@ -101,7 +105,8 @@ def _round_ctx(regex: str) -> dict:
         eval=EvalConfig(every_n_rounds=0),
         termination=TerminationConfig(federation_rounds=ROUNDS))
     fed = InProcessFederation(config)
-    engine = FlaxModelOps(_lm(lora_rank=2), x[:2], trainable_regex=regex)
+    engine = FlaxModelOps(module or _lm(lora_rank=2), x[:2],
+                          trainable_regex=regex)
     fed.add_learner(engine, ArrayDataset(x, y, seed=0))
     fed.seed_model(engine.get_variables())
     try:
@@ -116,10 +121,11 @@ def _round_ctx(regex: str) -> dict:
     window = done[1:]
     assert len(window) == ROUNDS - 1
     traffic = {"driver": "round", "ship_tensor_regex": regex,
-               "shape": {"batch": 2, "local_steps": LOCAL_STEPS,
+               "shape": {"batch": 2, "seq": int(x.shape[1]),
+                         "local_steps": LOCAL_STEPS,
                          "scan_chunk": LOCAL_STEPS}}
     return {"cell": {"name": "contract." + (regex or "whole")},
-            "cfg": {"family": "decoder_lm"}, "traffic": traffic,
+            "cfg": cfg or {"family": "decoder_lm"}, "traffic": traffic,
             "rounds": window, "learner": done[0]["selected_learners"][0],
             "window_s": window[-1]["completed_at"] - done[0]["completed_at"],
             "compiles": _compiles() - compiles_before,
@@ -136,6 +142,18 @@ def lora():
 @pytest.fixture(scope="module")
 def whole():
     return _round_ctx("")
+
+
+SCMOE_CELL = "longcat-flash-chat.lora-round"
+
+
+@pytest.fixture(scope="module")
+def scmoe():
+    """The LoRA path again with the shortcut-connected decoder at the
+    cell's toy widths (its binding builds the module), so that the round's
+    profile carries the routed layers' counters."""
+    cfg = {**spec.cell(SCMOE_CELL, rehearse=True)["cfg"], "vocab_size": 32}
+    return _round_ctx("lora_", spec.binding(cfg).build_module(cfg), cfg)
 
 
 @pytest.fixture(scope="module")
@@ -219,6 +237,45 @@ def test_reader_reads_what_the_program_emits(path, name, request):
         # ``compiles_total``: that sum must find the program's counter,
         # which had counted the warm-up's compiles when the window opened
         assert ctx["compiles_seen"] > 0
+
+
+def test_counter_readers_read_the_counters_of_a_real_round(scmoe):
+    """``moe_local_count`` and ``moe_zero_count`` ride
+    ``RoundProfile.learners[*].device`` of a real round, where the readers
+    of the shortcut-connected family look for them; given beside them what
+    a chip run alone has (a device kind with peaks, the kernels' events of
+    a traced round), each of the four reads a number."""
+    from benchmark.metrics import _scmoe
+    shape, cfg = scmoe["traffic"]["shape"], scmoe["cfg"]
+    device = scmoe["rounds"][0]["profile"]["learners"][scmoe["learner"]][
+        "device"]
+    assignments = (shape["batch"] * shape["seq"] * cfg["moe_topk"]
+                   * cfg["num_layers"])
+    local = _scmoe.device_count(scmoe, "moe_local_count")
+    zero = _scmoe.device_count(scmoe, "moe_zero_count")
+    assert 0 < device["moe_local_count"] and 0 < device["moe_zero_count"]
+    assert 0 < local and 0 < zero and local + zero < assignments
+    assert _scmoe.device_count(scmoe, "moe_absent_count") is None
+    assert _scmoe.device_count({**scmoe, "learner": "nobody"},
+                               "moe_zero_count") is None
+    assert set(cfg["program"]["trace_ops"]) == {"moe_gmm_fwd", "moe_gmm_bwd"}
+    traced = {**scmoe, "device_kind": "TPU v5 lite", "trace": {
+        "busy_s": 0.9, "window_s": 1.0,
+        "module_runs": {"jit_train_scan_steps": 1.0},
+        "kernel_ops_s": {"flash_fwd": 0.1, "flash_bwd_dq": 0.1,
+                         "moe_gmm_fwd": 0.05, "moe_gmm_bwd": 0.05},
+        "ops_s": {"fusion": 0.6}}}
+    for name in ("scmoe_step_mfu", "scmoe_flash_roofline",
+                 "scmoe_experts_roofline", "scmoe_experts_share"):
+        assert DECLARED[name] == "round"
+        value = spec.metric_reader(name).read(traced)
+        assert isinstance(value, float) and math.isfinite(value), name
+        assert value > 0, name
+        # and without the chip's part, as every run here is: nothing
+        try:
+            assert spec.metric_reader(name).read(scmoe) is None, name
+        except spec.UnknownDevice:
+            assert name == "scmoe_step_mfu"
 
 
 def test_the_readers_left_out_are_the_trace_readers_by_name(lora, serve):
