@@ -335,6 +335,24 @@ class SlotDecoder:
     behind: a Mamba block starts from zero at position 0 whatever the slot
     held (``MambaMixer``), which is the same guarantee by other means.
 
+    The cache is this object's alone, and every call consumes it: prefill
+    and step donate the whole tree (``kv`` and ``state`` leaves alike), the
+    compiled programs alias each leaf's output to its input, and a call
+    changes the buffers that are there (a step one row a slot,
+    ``zoo.transformer._write_kv``; a prefill one slot) where an undonated
+    call wrote 1.07 GB of new cache at 8 slots of 2048. The arrays a call
+    was given are deleted when it returns, so nobody else may keep them:
+    hold sizes (:func:`cache_bytes_by_kind`), not leaves. A call that fails
+    after the runtime took the buffers (an out-of-memory step, a device
+    error that surfaces at the token read) would leave deleted arrays
+    behind; the decoder then starts over with zeroed slots before it
+    re-raises, and whoever schedules the slots must empty all of them
+    (``ContinuousBatcher`` does, on any failed tick). A call that fails
+    before (the length check, a trace or compile error) consumed nothing
+    and resets nothing. ``donated_calls`` counts the calls after which the
+    input's buffers were gone (one ``is_deleted()`` a call; equal to the
+    calls made), ``cache_resets`` the times the slots were started over.
+
     ``variables`` is whatever tree the caller serves. The gateway hands
     in the module's own casts made once (serving/gateway.py
     ``_load_variables``): a leaf that prefill and step use only through a
@@ -359,13 +377,37 @@ class SlotDecoder:
         self.module = module
         self.slots = int(slots)
         self.max_len = int(max_len)
-        # slot-major, each slot the batch-1 state one solo generate(B=1)
-        # call sees
-        self.caches = jax.tree.map(
-            lambda a: jnp.zeros((self.slots,) + a.shape, a.dtype),
-            init_cache(module, 1, self.max_len))
+        self.caches = self._zeroed()
+        self.donated_calls = 0   # calls that left their input cache deleted
+        self.cache_resets = 0    # failed calls that had consumed it
         self._prefill_fns: dict = {}
         self._step_fn = None
+
+    def _zeroed(self):
+        """Slot-major state, each slot the batch-1 state one solo
+        generate(B=1) call sees."""
+        return jax.tree.map(
+            lambda a: jnp.zeros((self.slots,) + a.shape, a.dtype),
+            init_cache(self.module, 1, self.max_len))
+
+    def _call(self, fn, variables, *args):
+        """Run one donating program on the cache and read its token(s) to
+        the host: the one place the cache changes hands. The read is the
+        call's end (dispatch is asynchronous; a failure on the device
+        surfaces there), so a call that raises anywhere after the runtime
+        took the buffers starts the slots over, and one that raises before
+        (a trace or compile error) leaves them as they were."""
+        taken = self.caches
+        try:
+            self.caches, out = fn(variables, taken, *args)
+            out = np.asarray(out)
+        except Exception:
+            if any(leaf.is_deleted() for leaf in jax.tree.leaves(taken)):
+                self.caches = self._zeroed()
+                self.cache_resets += 1
+            raise
+        self.donated_calls += jax.tree.leaves(taken)[0].is_deleted()
+        return out
 
     def prefill(self, variables, slot: int, prompt) -> int:
         """Admit a prompt into ``slot``: write its K/V, return the first
@@ -396,12 +438,11 @@ class SlotDecoder:
             while len(self._prefill_fns) >= self._PREFILL_MAX:
                 self._prefill_fns.pop(next(iter(self._prefill_fns)))
             fn = self._prefill_fns[L] = _runtime.monitored_jit(
-                run, name="decode.prefill")
+                run, name="decode.prefill", donate_argnums=(1,))
         else:
             self._prefill_fns[L] = self._prefill_fns.pop(L)  # LRU refresh
-        self.caches, tok = fn(variables, self.caches, prompt,
-                              jnp.asarray(slot, jnp.int32))
-        return int(tok)
+        return int(self._call(fn, variables, prompt,
+                              jnp.asarray(slot, jnp.int32)))
 
     def step(self, variables, tokens, positions):
         """Advance EVERY slot one decode token (one fixed-shape jitted
@@ -423,9 +464,8 @@ class SlotDecoder:
                 return jax.vmap(one, in_axes=(0, 0, 0))(caches, toks,
                                                         positions)
 
-            self._step_fn = _runtime.monitored_jit(run, name="decode.step")
-        self.caches, nxt = self._step_fn(
-            variables, self.caches, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32))
-        import numpy as _np
-        return _np.asarray(nxt)
+            self._step_fn = _runtime.monitored_jit(
+                run, name="decode.step", donate_argnums=(1,))
+        return self._call(self._step_fn, variables,
+                          jnp.asarray(tokens, jnp.int32),
+                          jnp.asarray(positions, jnp.int32))

@@ -268,11 +268,11 @@ class Attention(nn.Module):
             dt = q.dtype
             q = _rotary(q, positions).astype(dt)
             k = _rotary(k, positions).astype(dt)
-        zero = jnp.zeros((), pos0.dtype)  # index dtypes must all match
-        ck = jax.lax.dynamic_update_slice(
-            ck, k.astype(ck.dtype), (zero, zero, pos0, zero))
-        cv = jax.lax.dynamic_update_slice(
-            cv, v.astype(cv.dtype), (zero, zero, pos0, zero))
+        # ``_write_kv`` (at the end of this file, so that no line above it
+        # moves): this dynamic_update_slice, and under ``vmap`` over decode
+        # slots one such write a slot, in place
+        ck = _write_kv(ck, k.astype(ck.dtype), pos0)
+        cv = _write_kv(cv, v.astype(cv.dtype), pos0)
         # grouped einsums read the cache at kv-head size (decode is
         # HBM-bound; repeating K/V to all query heads would rewrite the
         # whole cache heads/kv_heads times per step and erase the GQA
@@ -1405,3 +1405,38 @@ class ScMoeLite(nn.Module):
 
     def cache_kinds(self):
         return ("kv",) * self.depth
+
+
+@jax.custom_batching.custom_vmap
+def _write_kv(cache, new, position):
+    """``new`` (B, kv_heads, L, head_dim) into ``cache`` (B, kv_heads,
+    L_max, head_dim) from ``position`` on: one ``dynamic_update_slice``.
+
+    Batched over decode slots (``SlotDecoder.step`` vmaps the module over
+    a slot-major cache, each slot at its own position) the same values are
+    written by one ``dynamic_update_slice`` a slot on the slot-major array
+    (``_write_kv_slots``), not by the scatter ``vmap`` makes of a batched
+    start index. The chip's compiler expands that scatter into a loop over
+    a copy of the whole leaf staged in fast memory and writes the leaf back
+    whole (33.5 MB a leaf, 1.07 GB a step at 8 slots of 2048) although the
+    output is the donated input; the unrolled writes stay in the buffer
+    that is there, 2 KB a slot (PERF.md section 6, PR 36)."""
+    zero = jnp.zeros((), position.dtype)      # index dtypes must all match
+    return jax.lax.dynamic_update_slice(cache, new,
+                                        (zero, zero, position, zero))
+
+
+@_write_kv.def_vmap
+def _write_kv_slots(slots, batched, cache, new, position):
+    if not all(batched):
+        # some other batching than a decoder's slots: the stock rule
+        axes = tuple(0 if b else None for b in batched)
+        return jax.vmap(_write_kv.fun, in_axes=axes)(cache, new,
+                                                     position), True
+    zero = jnp.zeros((), position.dtype)
+    for slot in range(slots):
+        cache = jax.lax.dynamic_update_slice(
+            cache, new[slot:slot + 1],
+            (jnp.asarray(slot, position.dtype), zero, zero, position[slot],
+             zero))
+    return cache, True

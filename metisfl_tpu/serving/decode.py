@@ -73,9 +73,13 @@ PAD_ID = 0
 # ``SlotDecoder.step`` (call to host tokens), seconds inside ``prefill``,
 # the rest of the tick on the host (admission under the lock, token
 # hand-out, retirement), seconds parked with no queue and no active slot
-# (in neither side of a host share), and counts.
+# (in neither side of a host share), and counts; the last two are the
+# decoder's own (``SlotDecoder.donated_calls``, ``cache_resets``): calls
+# that updated the slots' cache in place, and failed calls after which the
+# slots were started over. ``donated_calls`` = ``steps`` + ``prefills``.
+DECODER_COUNTS = ("donated_calls", "cache_resets")
 LOOP_SUMS = ("step_s", "prefill_s", "host_s", "parked_s", "ticks",
-             "steps", "prefills", "admitted", "retired")
+             "steps", "prefills", "admitted", "retired") + DECODER_COUNTS
 LOOP_EVENT_EVERY_S = 1.0
 
 
@@ -164,8 +168,9 @@ class ContinuousBatcher:
         # the loop's own account of its time (``LOOP_SUMS``): running
         # totals the worker alone writes, read whole by describe() and as
         # differences by the once-a-second ``decode.loop`` event
-        self._sums = {k: 0.0 if k.endswith("_s") else 0 for k in LOOP_SUMS}
-        self._emitted = dict(self._sums)
+        self._sums = {k: 0.0 if k.endswith("_s") else 0 for k in LOOP_SUMS
+                      if k not in DECODER_COUNTS}
+        self._emitted = self._totals()
         self._emitted_at = time.perf_counter()
         self._worker = threading.Thread(target=self._loop, daemon=True,
                                         name=f"decode-{channel}")
@@ -175,6 +180,12 @@ class ContinuousBatcher:
     def steps(self) -> int:
         """Decode steps taken so far (test pin; one of the loop's sums)."""
         return self._sums["steps"]
+
+    def _totals(self) -> Dict[str, Any]:
+        """``LOOP_SUMS`` as they stand: the worker's sums and, read where
+        they are kept, the decoder's two counts."""
+        return dict(self._sums, **{k: getattr(self._decoder, k)
+                                   for k in DECODER_COUNTS})
 
     # -- request side --------------------------------------------------- #
 
@@ -290,7 +301,10 @@ class ContinuousBatcher:
             except Exception as exc:  # noqa: BLE001 - worker must survive
                 # one poisoned tick (bad prompt dtype, an OOM'd step)
                 # fails ITS requests only — a dead worker would hang
-                # every later Generate on this channel
+                # every later Generate on this channel. Every slot is
+                # emptied: a call that failed after it had consumed the
+                # cache left the decoder with zeroed slots
+                # (``SlotDecoder._call``), which is what empty slots need
                 logger.exception("decode tick failed")
                 with self._cv:
                     for req in admitted:
@@ -305,12 +319,13 @@ class ContinuousBatcher:
     def _emit_loop(self, now: float) -> None:
         """The ``decode.loop`` summary event: the sums' change since the
         last one, at most once a second, from the worker thread."""
-        attrs = {k: round(self._sums[k] - self._emitted[k], 6)
+        totals = self._totals()
+        attrs = {k: round(totals[k] - self._emitted[k], 6)
                  for k in LOOP_SUMS}
         attrs["channel"] = self.channel
         _ttrace.event("decode.loop", now - self._emitted_at, parent=None,
                       attrs=attrs)
-        self._emitted = dict(self._sums)
+        self._emitted = totals
         self._emitted_at = now
 
     def _tick(self, admitted: List[_GenPending]) -> float:
@@ -399,7 +414,7 @@ class ContinuousBatcher:
                     "cache_bytes": dict(self._cache_bytes),
                     # the loop's account of its own time, as totals
                     "loop": {k: round(v, 6)
-                             for k, v in self._sums.items()}}
+                             for k, v in self._totals().items()}}
 
     def close(self) -> None:
         """Drain: queued + in-flight generations still finish, then the

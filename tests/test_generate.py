@@ -256,3 +256,137 @@ def test_generate_with_top_p_runs():
                    top_p=0.9, rng=jax.random.PRNGKey(1))
     assert out.shape == (2, 6)
     assert ((0 <= np.asarray(out)) & (np.asarray(out) < 64)).all()
+
+
+# --------------------------------------------------------------------- #
+# the decode slots' cache is updated in place (SlotDecoder donates it)
+# --------------------------------------------------------------------- #
+
+FAMILIES = ("llama", "jamba")
+
+
+def _toy(family):
+    """(module, variables, a prompt) of a toy served family: ``kv`` alone
+    or ``kv`` beside recurrent ``state``."""
+    from metisfl_tpu.models.zoo import JambaLite
+
+    if family == "llama":
+        module = LlamaLite(vocab_size=61, dim=32, depth=2, heads=4,
+                           kv_heads=2)
+    else:
+        module = JambaLite(vocab_size=61, dim=32, depth=4, heads=4,
+                           kv_heads=1, ffn_dim=80, attn_period=2,
+                           attn_offset=1, d_state=8, dt_rank=4, lora_rank=2)
+    prompt = np.random.default_rng(1).integers(1, 61, (7,)).astype(np.int32)
+    variables = module.init(jax.random.PRNGKey(0), jnp.asarray(prompt[None]))
+    return module, variables, prompt
+
+
+def _all_deleted(tree):
+    return all(leaf.is_deleted() for leaf in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("call", ["prefill", "step"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_slot_decoder_call_consumes_the_cache(family, call, recwarn):
+    """Every leaf of the cache a call was given is gone after it (the
+    program wrote into those buffers), whatever kind the leaf is, and JAX
+    found every donated buffer usable."""
+    from metisfl_tpu.models.generate import SlotDecoder
+
+    module, variables, prompt = _toy(family)
+    decoder = SlotDecoder(module, slots=2, max_len=32)
+    tok = decoder.prefill(variables, 1, prompt)
+    given = decoder.caches
+    if call == "prefill":
+        decoder.prefill(variables, 0, prompt[:3])
+    else:
+        decoder.step(variables, [0, tok], [0, len(prompt)])
+    assert _all_deleted(given)
+    assert not _all_deleted(decoder.caches)
+    assert (decoder.donated_calls, decoder.cache_resets) == (2, 0)
+    assert not [w for w in recwarn if "donated" in str(w.message)]
+
+
+@pytest.mark.parametrize("how", ["deleted_under_it", "raised_after_taking"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_slot_decoder_starts_over_after_a_call_that_consumed(family, how):
+    """A call that fails once the cache's buffers are gone leaves zeroed
+    slots, not deleted arrays: the next occupant decodes what a solo
+    ``generate`` decodes, bit for bit."""
+    from metisfl_tpu.models.generate import SlotDecoder
+
+    module, variables, prompt = _toy(family)
+    decoder = SlotDecoder(module, slots=2, max_len=32)
+    tok = decoder.prefill(variables, 1, prompt)
+    if how == "deleted_under_it":
+        for leaf in jax.tree.leaves(decoder.caches):
+            leaf.delete()
+    else:
+        decoder.step(variables, [0, tok], [0, len(prompt)])  # builds it
+        real = decoder._step_fn
+
+        def fails_late(*args):
+            real(*args)                  # the runtime took the buffers
+            raise RuntimeError("the device gave up")
+
+        decoder._step_fn = fails_late
+    with pytest.raises(RuntimeError):
+        decoder.step(variables, [0, tok], [0, len(prompt)])
+    assert decoder.cache_resets == 1
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves(decoder.caches))
+    assert not any(np.asarray(leaf).any()
+                   for leaf in jax.tree.leaves(decoder.caches))
+    if how == "raised_after_taking":
+        decoder._step_fn = real
+    tok = decoder.prefill(variables, 0, prompt)
+    seq = [tok]
+    for pos in range(len(prompt), len(prompt) + 5):
+        tok = int(decoder.step(variables, [tok, 0], [pos, 0])[0])
+        seq.append(tok)
+    solo = generate(module, variables, prompt[None], 6, max_len=32)
+    assert seq == [int(t) for t in solo[0]]
+    assert decoder.cache_resets == 1
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_slot_decoder_error_before_the_call_resets_nothing(family):
+    """A prompt of ``max_len`` is refused before any program runs: the
+    cache is the very arrays it was, and nothing is counted."""
+    from metisfl_tpu.models.generate import SlotDecoder
+
+    module, variables, prompt = _toy(family)
+    decoder = SlotDecoder(module, slots=2, max_len=32)
+    decoder.prefill(variables, 0, prompt)
+    held = decoder.caches
+    with pytest.raises(ValueError):
+        decoder.prefill(variables, 1, np.ones((32,), np.int32))
+    assert all(a is b for a, b in zip(jax.tree.leaves(held),
+                                      jax.tree.leaves(decoder.caches)))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(held))
+    assert (decoder.donated_calls, decoder.cache_resets) == (1, 0)
+
+
+@pytest.mark.parametrize("batched", ["all", "cache_and_new", "position"])
+def test_write_kv_over_slots_is_the_batched_dynamic_update_slice(batched):
+    """``_write_kv`` under ``vmap``: one write a slot on the slot-major
+    array where cache, rows and position are all batched (a decoder's
+    step), the stock rule otherwise; either way the values ``vmap`` of
+    the plain ``dynamic_update_slice`` gives."""
+    from metisfl_tpu.models.zoo.transformer import _write_kv
+
+    rng = np.random.default_rng(3)
+    cache = jnp.asarray(rng.normal(size=(3, 1, 2, 8, 4)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(3, 1, 2, 1, 4)), jnp.float32)
+    position = jnp.asarray([5, 0, 7], jnp.int32)
+    axes = {"all": (0, 0, 0), "cache_and_new": (0, 0, None),
+            "position": (None, None, 0)}[batched]
+    args = tuple(a if ax == 0 else a[0]
+                 for a, ax in zip((cache, new, position), axes))
+    got = jax.jit(jax.vmap(_write_kv, in_axes=axes))(*args)
+    want = jax.vmap(_write_kv.fun, in_axes=axes)(*args)
+    assert got.shape == want.shape == cache.shape
+    assert jnp.array_equal(got, want)
+    text = jax.jit(jax.vmap(_write_kv, in_axes=axes)).lower(*args).as_text()
+    assert ("scatter" not in text) == (batched == "all")

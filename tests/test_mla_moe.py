@@ -12,6 +12,7 @@ interpret mode."""
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -284,6 +285,66 @@ def test_new_kernels_compile_for_the_chip_at_published_widths(one_chip):
     # no (tokens, experts, capacity) tensor (4096 x 384 x 107 of them
     # would be 337 MB in bfloat16 a layer, and 32 times that uncut)
     assert moe.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_decode_programs_compile_for_the_chip_with_the_cache_in_place(
+        one_chip):
+    """``SlotDecoder``'s step and prefill at the serve cell's widths (8
+    slots of 2048, 8 KV heads of 128; depth and vocabulary cut): the chip's
+    compiler aliases every cache leaf's output to its donated input, and
+    the step holds one ``dynamic_update_slice`` a slot a leaf and no loop
+    (what it makes of the scatter a batched start index gives; at the
+    cell's depth it runs that loop on a staged copy of the leaf and writes
+    the leaf back whole, PERF.md section 6, PR 36). Kept beside the other
+    compiles for the chip: one file, one worker, one load of the library."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from metisfl_tpu.models.generate import SlotDecoder
+    from metisfl_tpu.models.zoo import LlamaLite
+
+    before = {k: getattr(jax.config, k)
+              for k in ("jax_enable_x64", "jax_enable_compilation_cache")}
+    for k in before:
+        jax.config.update(k, False)
+    compilation_cache.reset_cache()
+    try:
+        on_chip = lambda tree: jax.tree.map(                # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one_chip), tree)
+        module = LlamaLite(vocab_size=4096, dim=2048, depth=2, heads=16,
+                           kv_heads=8, lora_rank=16, dtype=jnp.bfloat16)
+        variables = on_chip(jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+
+        class Probe(SlotDecoder):
+            """Shapes for arrays, and a call compiled where it would run."""
+
+            def _zeroed(self):
+                return on_chip(jax.eval_shape(super()._zeroed))
+
+            def _call(self, fn, variables, *args):
+                self.compiled = fn.__wrapped__.lower(
+                    variables, self.caches, *on_chip(args)).compile()
+                # a step's tokens, a prefill's one
+                return np.zeros((self.slots,) if args[0].ndim == 1 else (),
+                                np.int32)
+
+        decoder = Probe(module, slots=8, max_len=2048)
+        decoder.step(variables, np.zeros(8, np.int32), np.zeros(8, np.int32))
+        step = decoder.compiled
+        decoder.prefill(variables, 3, np.ones(64, np.int32))
+        prefill = decoder.compiled
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    cache_bytes = 2 * 2 * 8 * 8 * 2048 * 128 * 2
+    for program in (step, prefill):
+        assert program.memory_analysis().alias_size_in_bytes == cache_bytes
+    text = step.as_text()
+    assert " while(" not in text and " scatter(" not in text
+    leaf = r"bf16\[8,1,8,2048,128\]\{[^}]*\} dynamic-update-slice\("
+    assert len(re.findall(leaf, text)) == 4 * 8
 
 
 # --------------------------------------------------------------------- #

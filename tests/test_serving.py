@@ -20,6 +20,7 @@ from metisfl_tpu.config import (
 from metisfl_tpu.models import ArrayDataset, FlaxModelOps
 from metisfl_tpu.models.zoo import MLP
 from metisfl_tpu.serving import (
+    ContinuousBatcher,
     DirectRegistrySource,
     MicroBatcher,
     ServingClient,
@@ -405,3 +406,112 @@ def test_disabled_serving_config_is_inert():
                             [lambda: None])
     with pytest.raises(RuntimeError, match="not enabled"):
         session.serving_client()
+
+
+# --------------------------------------------------------------------- #
+# the decode loop over a cache its calls consume (SlotDecoder donates it)
+# --------------------------------------------------------------------- #
+
+def _decode_engine(family, slots=2):
+    """A ContinuousBatcher over a toy served family (``kv`` alone, or
+    ``kv`` beside recurrent ``state``) and what it needs checking."""
+    import jax
+    import jax.numpy as jnp
+
+    from metisfl_tpu.models.zoo import JambaLite, LlamaLite
+
+    if family == "llama":
+        module = LlamaLite(vocab_size=61, dim=32, depth=2, heads=4,
+                           kv_heads=2)
+    else:
+        module = JambaLite(vocab_size=61, dim=32, depth=4, heads=4,
+                           kv_heads=1, ffn_dim=80, attn_period=2,
+                           attn_offset=1, d_state=8, dt_rank=4, lora_rank=2)
+    variables = module.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    ops = FlaxModelOps(module, np.zeros((1, 8), np.int32),
+                       variables=variables)
+    engine = ContinuousBatcher(ops, 1, variables, slots=slots, max_len=32,
+                               channel=f"donate-{family}")
+    return engine, module, variables
+
+
+@pytest.mark.parametrize("family", ["llama", "jamba"])
+def test_decode_loop_survives_a_consumed_cache(family):
+    """A call that fails with the cache's buffers gone costs that tick's
+    requests and nothing after it: the decoder starts its slots over, the
+    loop has emptied them, and the next request decodes what a solo
+    ``generate`` decodes, bit for bit."""
+    import jax
+
+    from metisfl_tpu.models.generate import generate
+
+    engine, module, variables = _decode_engine(family)
+    try:
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(1, 61, (n,)).astype(np.int32)
+                   for n in (6, 4, 9)]
+        engine.submit(prompts[0], 4).result(timeout=120)      # warm, idle
+        for leaf in jax.tree.leaves(engine._decoder.caches):
+            leaf.delete()         # what a call that died mid-way leaves
+        with pytest.raises(RuntimeError):
+            engine.submit(prompts[1], 4).result(timeout=120)
+        assert engine.describe()["loop"]["cache_resets"] == 1
+        assert engine.active() == 0
+        for prompt in prompts:
+            got, version = engine.submit(prompt, 7).result(timeout=120)
+            want = generate(module, variables, prompt[None], 7, max_len=32)
+            assert version == 1 and list(got) == [int(t) for t in want[0]]
+        loop = engine.describe()["loop"]
+        assert loop["cache_resets"] == 1
+        assert loop["donated_calls"] == loop["steps"] + loop["prefills"]
+    finally:
+        engine.close()
+
+
+@pytest.mark.parametrize("family", ["llama", "jamba"])
+def test_decode_loop_counts_every_call_as_donated(family, clean_telemetry):
+    """After a small closed run every prefill and every step updated the
+    cache in place: ``donated_calls`` = ``steps`` + ``prefills`` in
+    ``describe()`` and summed over the ``decode.loop`` events, no reset,
+    and the engine holds sizes of the cache, not its arrays."""
+    import jax
+
+    from metisfl_tpu.telemetry import trace as ttrace
+
+    ttrace.configure(enabled=True, service="test", dir="")
+    ttrace.configure_ring(4096)
+    _, cursor, _ = ttrace.spans_since(0)
+    engine, _, _ = _decode_engine(family)
+    try:
+        rng = np.random.default_rng(7)
+        asks = [(rng.integers(1, 61, (int(n),)).astype(np.int32), int(o))
+                for n, o in zip(rng.integers(2, 9, 6), rng.integers(2, 8, 6))]
+
+        def client(mine):
+            for prompt, out_len in mine:
+                engine.submit(prompt, out_len).result(timeout=120)
+
+        threads = [threading.Thread(target=client, args=(asks[i::3],))
+                   for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        loop = engine.describe()["loop"]
+        assert loop["prefills"] == 6 and loop["steps"] > 0
+        assert loop["donated_calls"] == loop["steps"] + loop["prefills"]
+        assert loop["cache_resets"] == 0
+        held = [v for v in vars(engine).values()
+                if any(isinstance(leaf, jax.Array)
+                       for leaf in jax.tree.leaves(v))]
+        assert held == [engine._pair]       # the weights, never the cache
+    finally:
+        engine.close()
+    events = [s["attrs"] for s in ttrace.spans_since(cursor)[0]
+              if s["name"] == "decode.loop"
+              and s["attrs"]["channel"] == f"donate-{family}"]
+    ttrace.configure_ring(0)
+    assert events
+    assert sum(a["donated_calls"] for a in events) == loop["donated_calls"]
+    assert sum(a["cache_resets"] for a in events) == 0
