@@ -31,6 +31,7 @@ carries that state as a pytree and looks inside none of it.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, List, Optional, Tuple
 
 import jax
@@ -353,6 +354,16 @@ class SlotDecoder:
     input's buffers were gone (one ``is_deleted()`` a call; equal to the
     calls made), ``cache_resets`` the times the slots were started over.
 
+    Each call is cut in two on the host's clock, with no sync added: the
+    *launch*, from its entry (before the inputs are placed) to the jitted
+    call's return (placed and enqueued), and the *read*, from there to the
+    tokens on the host (the one point where the host waits for the
+    device, and the call's inputs let go: the donated tree's and the
+    placed inputs' arrays are freed as the call returns). ``step_launch_s``,
+    ``step_read_s``, ``prefill_launch_s`` and ``prefill_read_s`` sum them;
+    ``last_call`` holds the three ``perf_counter`` stamps (entry, enqueued,
+    returned) of the latest call.
+
     ``variables`` is whatever tree the caller serves. The gateway hands
     in the module's own casts made once (serving/gateway.py
     ``_load_variables``): a leaf that prefill and step use only through a
@@ -380,6 +391,10 @@ class SlotDecoder:
         self.caches = self._zeroed()
         self.donated_calls = 0   # calls that left their input cache deleted
         self.cache_resets = 0    # failed calls that had consumed it
+        self.step_launch_s = self.step_read_s = 0.0
+        self.prefill_launch_s = self.prefill_read_s = 0.0
+        self.last_call = (0.0, 0.0, 0.0)
+        self._entry = self._enqueued = 0.0
         self._prefill_fns: dict = {}
         self._step_fn = None
 
@@ -400,6 +415,7 @@ class SlotDecoder:
         taken = self.caches
         try:
             self.caches, out = fn(variables, taken, *args)
+            self._enqueued = time.perf_counter()
             out = np.asarray(out)
         except Exception:
             if any(leaf.is_deleted() for leaf in jax.tree.leaves(taken)):
@@ -409,11 +425,26 @@ class SlotDecoder:
         self.donated_calls += jax.tree.leaves(taken)[0].is_deleted()
         return out
 
+    def _returned(self, prefill: bool) -> None:
+        """A call's end, once ``_call`` has returned and its inputs are let
+        go: its stamps (``_entry`` from the caller, before it placed the
+        inputs; ``_enqueued`` from ``_call``) and its two halves summed."""
+        returned = time.perf_counter()
+        entry, enqueued = self._entry, self._enqueued
+        if prefill:
+            self.prefill_launch_s += enqueued - entry
+            self.prefill_read_s += returned - enqueued
+        else:
+            self.step_launch_s += enqueued - entry
+            self.step_read_s += returned - enqueued
+        self.last_call = (entry, enqueued, returned)
+
     def prefill(self, variables, slot: int, prompt) -> int:
         """Admit a prompt into ``slot``: write its K/V, return the first
         greedy token. The prompt runs at its EXACT length (no padding) —
         the same program a solo generate's prefill compiles — which is
         what keeps slot outputs bit-identical to single-request decode."""
+        self._entry = time.perf_counter()
         prompt = jnp.asarray(prompt, jnp.int32).reshape(1, -1)
         L = int(prompt.shape[1])
         if L < 1 or L >= self.max_len:
@@ -441,8 +472,10 @@ class SlotDecoder:
                 run, name="decode.prefill", donate_argnums=(1,))
         else:
             self._prefill_fns[L] = self._prefill_fns.pop(L)  # LRU refresh
-        return int(self._call(fn, variables, prompt,
-                              jnp.asarray(slot, jnp.int32)))
+        tok = int(self._call(fn, variables, prompt,
+                             jnp.asarray(slot, jnp.int32)))
+        self._returned(prefill=True)
+        return tok
 
     def step(self, variables, tokens, positions):
         """Advance EVERY slot one decode token (one fixed-shape jitted
@@ -450,6 +483,7 @@ class SlotDecoder:
         slots pass any value (their lanes compute garbage that is never
         read, and their cache writes land at positions a future prefill
         overwrites). Returns the (slots,) next greedy tokens."""
+        self._entry = time.perf_counter()
         if self._step_fn is None:
             module = self.module
 
@@ -466,6 +500,8 @@ class SlotDecoder:
 
             self._step_fn = _runtime.monitored_jit(
                 run, name="decode.step", donate_argnums=(1,))
-        return self._call(self._step_fn, variables,
-                          jnp.asarray(tokens, jnp.int32),
-                          jnp.asarray(positions, jnp.int32))
+        out = self._call(self._step_fn, variables,
+                         jnp.asarray(tokens, jnp.int32),
+                         jnp.asarray(positions, jnp.int32))
+        self._returned(prefill=False)
+        return out

@@ -56,11 +56,6 @@ _M_DECODE_SLOTS = _REG.gauge(
 _M_DECODE_TOKENS = _REG.counter(
     _tel.M_SERVING_DECODE_TOKENS_TOTAL,
     "Tokens emitted by the continuous-batching decode loop", ("channel",))
-_M_DECODE_TPS = _REG.gauge(
-    _tel.M_SERVING_DECODE_TOKENS_PER_SEC,
-    "EWMA decode throughput (tokens/s across all active slots), per "
-    "channel", ("channel",))
-
 _M_DECODE_CACHE = _REG.gauge(
     _tel.M_SERVING_DECODE_CACHE_BYTES,
     "Device bytes the decode slots' state holds, per channel and kind "
@@ -70,16 +65,20 @@ _M_DECODE_CACHE = _REG.gauge(
 PAD_ID = 0
 
 # what the decode loop keeps of its own time, per tick: seconds inside
-# ``SlotDecoder.step`` (call to host tokens), seconds inside ``prefill``,
-# the rest of the tick on the host (admission under the lock, token
-# hand-out, retirement), seconds parked with no queue and no active slot
-# (in neither side of a host share), and counts; the last two are the
-# decoder's own (``SlotDecoder.donated_calls``, ``cache_resets``): calls
-# that updated the slots' cache in place, and failed calls after which the
-# slots were started over. ``donated_calls`` = ``steps`` + ``prefills``.
-DECODER_COUNTS = ("donated_calls", "cache_resets")
+# ``SlotDecoder.step`` (entry to tokens on the host, on the decoder's own
+# stamps, ``last_call``), seconds inside ``prefill``, the rest of the tick
+# on the host (admission under the lock, token hand-out, retirement),
+# seconds parked with no queue and no active slot (in neither side of a
+# host share), and counts; the rest are the decoder's own: calls that
+# updated the slots' cache in place (``donated_calls`` = ``steps`` +
+# ``prefills``), failed calls after which the slots were started over, and
+# each kind of call cut in two, launch (entry to enqueued) and read (to the
+# tokens on the host): ``step_launch_s`` + ``step_read_s`` = ``step_s``,
+# and the same for prefill.
+DECODER_SUMS = ("donated_calls", "cache_resets", "step_launch_s",
+                "step_read_s", "prefill_launch_s", "prefill_read_s")
 LOOP_SUMS = ("step_s", "prefill_s", "host_s", "parked_s", "ticks",
-             "steps", "prefills", "admitted", "retired") + DECODER_COUNTS
+             "steps", "prefills", "admitted", "retired") + DECODER_SUMS
 LOOP_EVENT_EVERY_S = 1.0
 
 
@@ -164,14 +163,17 @@ class ContinuousBatcher:
         self._slots: List[Optional[_Slot]] = [None] * self.slots
         self._closed = False
         self.tokens_emitted = 0
-        self._tps_ewma = 0.0
         # the loop's own account of its time (``LOOP_SUMS``): running
         # totals the worker alone writes, read whole by describe() and as
         # differences by the once-a-second ``decode.loop`` event
         self._sums = {k: 0.0 if k.endswith("_s") else 0 for k in LOOP_SUMS
-                      if k not in DECODER_COUNTS}
+                      if k not in DECODER_SUMS}
         self._emitted = self._totals()
         self._emitted_at = time.perf_counter()
+        # with the tracer on, the worker stamps each tick (``_stamp``)
+        # and the next ``decode.loop`` event carries them as ``stamps``
+        self._ticks: list = []
+        self._anchor = (time.time(), self._emitted_at)
         self._worker = threading.Thread(target=self._loop, daemon=True,
                                         name=f"decode-{channel}")
         self._worker.start()
@@ -183,9 +185,9 @@ class ContinuousBatcher:
 
     def _totals(self) -> Dict[str, Any]:
         """``LOOP_SUMS`` as they stand: the worker's sums and, read where
-        they are kept, the decoder's two counts."""
+        they are kept, the decoder's."""
         return dict(self._sums, **{k: getattr(self._decoder, k)
-                                   for k in DECODER_COUNTS})
+                                   for k in DECODER_SUMS})
 
     # -- request side --------------------------------------------------- #
 
@@ -287,8 +289,12 @@ class ContinuousBatcher:
                     self._pair = self._pending_pair
                     self._pending_pair = None
                 admitted = self._admit_locked()
+            # the tick's stamps: its start, the lock released, then one
+            # (kind, entry, enqueued, returned) a call (``_stamp``)
+            stamp = ([tick_at, time.perf_counter()] if _ttrace.enabled()
+                     else None)
             try:
-                called_s = self._tick(admitted)
+                called_s = self._tick(admitted, stamp)
                 # a tick boundary: what of it was neither parked nor
                 # inside prefill or step is the host's
                 now = time.perf_counter()
@@ -296,6 +302,8 @@ class ContinuousBatcher:
                 sums["admitted"] += len(admitted)
                 sums["parked_s"] += parked
                 sums["host_s"] += now - tick_at - parked - called_s
+                if stamp is not None:
+                    self._stamp(stamp, now)
                 if now - self._emitted_at >= LOOP_EVENT_EVERY_S:
                     self._emit_loop(now)
             except Exception as exc:  # noqa: BLE001 - worker must survive
@@ -316,40 +324,65 @@ class ContinuousBatcher:
                                 slot.req.future.set_exception(exc)
                             self._slots[idx] = None
 
+    def _stamp(self, stamp: list, end: float) -> None:
+        """Keep one tick's stamps as whole microseconds after the batch's
+        anchor: ``[start, released, [[kind, entry, enqueued, returned],
+        ...], end]``, kind ``s`` a step and ``p<L>`` a prefill of L
+        tokens (the calls' stamps are ``SlotDecoder.last_call``)."""
+        base = self._anchor[1]
+        start, released, *calls = stamp
+        self._ticks.append(
+            [round((start - base) * 1e6), round((released - base) * 1e6),
+             [[kind] + [round((t - base) * 1e6) for t in times]
+              for kind, *times in calls],
+             round((end - base) * 1e6)])
+
     def _emit_loop(self, now: float) -> None:
         """The ``decode.loop`` summary event: the sums' change since the
-        last one, at most once a second, from the worker thread."""
+        last one, at most once a second, from the worker thread; with the
+        ticks' stamps of the same interval under ``stamps`` (``anchor``:
+        ``time.time()`` and ``perf_counter()`` read together, the origin
+        of the offsets; one record in ``ticks`` a tick)."""
         totals = self._totals()
         attrs = {k: round(totals[k] - self._emitted[k], 6)
                  for k in LOOP_SUMS}
         attrs["channel"] = self.channel
+        if self._ticks:
+            attrs["stamps"] = {"anchor": list(self._anchor),
+                               "ticks": self._ticks}
+            self._ticks = []
         _ttrace.event("decode.loop", now - self._emitted_at, parent=None,
                       attrs=attrs)
         self._emitted = totals
         self._emitted_at = now
+        self._anchor = (time.time(), time.perf_counter())
 
-    def _tick(self, admitted: List[_GenPending]) -> float:
+    def _tick(self, admitted: List[_GenPending],
+              stamp: Optional[list]) -> float:
         """One iteration of the loop; returns the seconds it spent inside
-        the decoder's ``prefill`` and ``step`` calls."""
+        the decoder's ``prefill`` and ``step`` calls, and appends each
+        call's stamps to ``stamp`` where it is given."""
         version, variables = self._pair
         sums = self._sums
         called_s = 0.0
+        emitted = 0
         # 1. prefill admissions between decode steps (step granularity:
         #    the running batch did NOT have to finish first)
         for req in admitted:
             idx = next(i for i, s in enumerate(self._slots) if s is None)
-            t0 = time.perf_counter()
             first = self._decoder.prefill(variables, idx, req.prompt)
-            prefill_s = time.perf_counter() - t0
-            req.wait_ms = (t0 - req.enqueued_at) * 1e3
+            entry, _, returned = call = self._decoder.last_call
+            if stamp is not None:
+                stamp.append((f"p{req.prompt.size}",) + call)
+            prefill_s = returned - entry
+            req.wait_ms = (entry - req.enqueued_at) * 1e3
             req.prefill_ms = prefill_s * 1e3
             sums["prefill_s"] += prefill_s
             sums["prefills"] += 1
             called_s += prefill_s
             req.admitted_step = self.steps
             slot = _Slot(req, first, int(req.prompt.size), version)
-            self.tokens_emitted += 1
-            _M_DECODE_TOKENS.inc(channel=self.channel)
+            emitted += 1
             if ((req.eos_id is not None and first == req.eos_id)
                     or req.max_new == 1):
                 self._retire(idx, slot)
@@ -358,36 +391,33 @@ class ContinuousBatcher:
         active = [(i, s) for i, s in enumerate(self._slots)
                   if s is not None]
         _M_DECODE_SLOTS.set(len(active), channel=self.channel)
-        if not active:
-            return called_s
-        # 2. one decode step for the whole in-flight batch (one program;
-        #    free lanes carry zeros and are never read)
-        t0 = time.perf_counter()
-        toks = np.zeros((self.slots,), np.int32)
-        poss = np.zeros((self.slots,), np.int32)
-        for i, s in active:
-            toks[i], poss[i] = s.last_tok, s.position
-        t_call = time.perf_counter()
-        nxt = self._decoder.step(variables, toks, poss)
-        now = time.perf_counter()
-        sums["step_s"] += now - t_call
-        sums["steps"] += 1
-        called_s += now - t_call
-        step_s = max(now - t0, 1e-9)
-        self._tps_ewma = (0.8 * self._tps_ewma
-                          + 0.2 * (len(active) / step_s))
-        _M_DECODE_TPS.set(round(self._tps_ewma, 3), channel=self.channel)
-        for i, s in active:
-            tok = int(nxt[i])
-            s.tokens.append(tok)
-            s.last_tok = tok
-            s.position += 1
-            self.tokens_emitted += 1
-            _M_DECODE_TOKENS.inc(channel=self.channel)
-            done = (len(s.tokens) >= s.req.max_new
-                    or (s.req.eos_id is not None and tok == s.req.eos_id))
-            if done:
-                self._retire(i, s)
+        if active:
+            # 2. one decode step for the whole in-flight batch (one
+            #    program; free lanes carry zeros and are never read)
+            toks = np.zeros((self.slots,), np.int32)
+            poss = np.zeros((self.slots,), np.int32)
+            for i, s in active:
+                toks[i], poss[i] = s.last_tok, s.position
+            nxt = self._decoder.step(variables, toks, poss)
+            entry, _, returned = call = self._decoder.last_call
+            if stamp is not None:
+                stamp.append(("s",) + call)
+            sums["step_s"] += returned - entry
+            sums["steps"] += 1
+            called_s += returned - entry
+            for i, s in active:
+                tok = int(nxt[i])
+                s.tokens.append(tok)
+                s.last_tok = tok
+                s.position += 1
+                emitted += 1
+                done = (len(s.tokens) >= s.req.max_new
+                        or (s.req.eos_id is not None
+                            and tok == s.req.eos_id))
+                if done:
+                    self._retire(i, s)
+        self.tokens_emitted += emitted
+        _M_DECODE_TOKENS.inc(emitted, channel=self.channel)
         return called_s
 
     # -- status --------------------------------------------------------- #
@@ -407,7 +437,6 @@ class ContinuousBatcher:
                     "active": sum(1 for s in self._slots if s is not None),
                     "steps": self.steps,
                     "tokens_emitted": self.tokens_emitted,
-                    "tokens_per_sec": round(self._tps_ewma, 3),
                     "version": self._pair[0],
                     "swap_pending": self._pending_pair is not None,
                     # the slots' device state by kind (kv, state)
@@ -425,6 +454,5 @@ class ContinuousBatcher:
         self._worker.join(timeout=60.0)
         _M_DECODE_QUEUE.remove(channel=self.channel)
         _M_DECODE_SLOTS.remove(channel=self.channel)
-        _M_DECODE_TPS.remove(channel=self.channel)
         for kind in self._cache_bytes:
             _M_DECODE_CACHE.remove(channel=self.channel, kind=kind)

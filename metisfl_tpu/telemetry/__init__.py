@@ -185,7 +185,6 @@ M_SERVING_QUEUE_DEPTH = "serving_queue_depth"
 M_SERVING_DECODE_QUEUE_DEPTH = "serving_decode_queue_depth"
 M_SERVING_DECODE_ACTIVE_SLOTS = "serving_decode_active_slots"
 M_SERVING_DECODE_TOKENS_TOTAL = "serving_decode_tokens_total"
-M_SERVING_DECODE_TOKENS_PER_SEC = "serving_decode_tokens_per_sec"
 M_SERVING_DECODE_CACHE_BYTES = "serving_decode_cache_bytes"
 # serving fleet: router + autoscaler (serving/fleet.py + driver/session.py)
 M_ROUTER_REQUESTS_TOTAL = "serving_router_requests_total"

@@ -410,6 +410,12 @@ def set_enabled(value: bool) -> None:
                       dir=_TRACER.dir)
 
 
+def enabled() -> bool:
+    """Whether spans and events are recorded (``telemetry.enabled``): a
+    caller that gathers an event's attrs itself skips the work when not."""
+    return _TRACER.enabled
+
+
 def flush() -> None:
     _TRACER.flush()
 

@@ -29,10 +29,12 @@ TRACE_ONLY = {
     "moe_experts_share",
     "scmoe_step_mfu", "scmoe_flash_roofline", "scmoe_experts_roofline",
     "scmoe_experts_share",
-    "device_idle_share.train", "device_idle_share.serve"}
+    "device_idle_share.train", "device_idle_share.serve",
+    "decode_gap_read_ms", "decode_gap_loop_ms", "decode_gap_launch_ms",
+    "decode_gap_named_share"}
 # what a CPU's clock leaves of each reading
 POSITIVE = {"step_ms", "decode_step_ms", "slot_ms", "prefill_ms",
-            "encode_ms", "load_ms"}
+            "encode_ms", "load_ms", "decode_launch_ms"}
 DIFFERENCES = {"uplink_ms", "report_ms"}
 
 LOCAL_STEPS = 4

@@ -306,7 +306,7 @@ def test_decode_slot_and_loop_account_for_their_time(span_ring,
         before = engine.describe()
         assert set(before) == {
             "slots", "max_len", "queued", "active", "steps",
-            "tokens_emitted", "tokens_per_sec", "version", "swap_pending",
+            "tokens_emitted", "version", "swap_pending",
             "cache_bytes", "loop"}
         assert set(before["cache_bytes"]) == {"kv"}     # an all-attention LM
         assert set(before["loop"]) == set(decode.LOOP_SUMS)
@@ -340,6 +340,179 @@ def test_decode_slot_and_loop_account_for_their_time(span_ring,
             after["loop"][key] - before["loop"][key], abs=1e-4), key
     assert total["step_s"] > 0 and total["prefill_s"] > 0
     assert total["host_s"] >= 0 and total["parked_s"] >= 0
+
+
+@pytest.fixture(scope="module")
+def stamped_run():
+    """Four requests over two slots with the tracer on, events every
+    10 ms: the decode loop's sums and ticks as a gateway's sink has them."""
+    from metisfl_tpu.serving import decode
+
+    ttrace.configure(enabled=True, service="test", dir="")
+    ttrace.configure_ring(8192)
+    _, cursor, _ = ttrace.spans_since(0)
+    every, decode.LOOP_EVENT_EVERY_S = decode.LOOP_EVENT_EVERY_S, 0.01
+    ops = _lm_ops()
+    engine = decode.ContinuousBatcher(ops, 1, ops.get_variables(),
+                                      slots=2, max_len=32)
+    prompts = [np.arange(1, 4 + i, dtype=np.int32) for i in range(4)]
+    try:
+        before = engine.describe()
+        futures = [engine.submit(p, 6 + i) for i, p in enumerate(prompts)]
+        for fut in futures:
+            fut.result(timeout=120.0)
+    finally:
+        engine.close()
+        decode.LOOP_EVENT_EVERY_S = every
+    spans = ttrace.spans_since(cursor)[0]
+    ttrace.configure_ring(0)
+    return {"before": before, "after": engine.describe(),
+            "loops": [s for s in spans if s["name"] == "decode.loop"],
+            "prompts": prompts}
+
+
+def test_decode_calls_are_cut_into_launch_and_read(stamped_run):
+    from metisfl_tpu.serving import decode
+
+    new = ("step_launch_s", "step_read_s", "prefill_launch_s",
+           "prefill_read_s")
+    assert set(new) <= set(decode.DECODER_SUMS) <= set(decode.LOOP_SUMS)
+    before, after = stamped_run["before"], stamped_run["after"]
+    assert set(new) <= set(after["loop"])
+    loops = [rec["attrs"] for rec in stamped_run["loops"]]
+    assert loops and all(set(new) <= set(a) for a in loops)
+    total = {k: sum(a[k] for a in loops) for k in decode.LOOP_SUMS}
+    for key in new:
+        assert total[key] == pytest.approx(
+            after["loop"][key] - before["loop"][key], abs=1e-4), key
+    # the two halves of a call are the call (no sync, no gap between)
+    for kind in ("step", "prefill"):
+        assert total[f"{kind}_launch_s"] > 0 and total[f"{kind}_read_s"] > 0
+        assert total[f"{kind}_launch_s"] + total[f"{kind}_read_s"] == \
+            pytest.approx(total[f"{kind}_s"], rel=0.01), kind
+
+
+def test_decode_ticks_are_stamped_in_order(stamped_run):
+    loops = [rec["attrs"] for rec in stamped_run["loops"]]
+    kinds, last = [], None
+    for attrs in loops:
+        stamps = attrs["stamps"]
+        wall, perf = stamps["anchor"]
+        assert wall > 1e9 and perf > 0
+        assert len(stamps["ticks"]) == attrs["ticks"] > 0
+        for start, released, calls, end in stamps["ticks"]:
+            flat = [start, released]
+            for kind, entry, enqueued, returned in calls:
+                kinds.append(kind)
+                flat += [entry, enqueued, returned]
+            flat.append(end)
+            assert all(isinstance(t, int) for t in flat)
+            assert flat == sorted(flat)
+            # ticks follow one another across batches too
+            at = [perf + t / 1e6 for t in (flat[0], flat[-1])]
+            assert last is None or at[0] >= last - 1e-6
+            last = at[1]
+    assert kinds.count("s") == sum(a["steps"] for a in loops)
+    assert sorted(k for k in kinds if k != "s") == sorted(
+        f"p{p.size}" for p in stamped_run["prompts"])
+
+
+def test_nothing_is_stamped_with_the_tracer_off(monkeypatch):
+    from metisfl_tpu.serving import decode
+
+    monkeypatch.setattr(decode, "LOOP_EVENT_EVERY_S", 0.01)
+    ttrace.configure(enabled=False, service="test", dir="")
+    ops = _lm_ops()
+    engine = decode.ContinuousBatcher(ops, 1, ops.get_variables(),
+                                      slots=2, max_len=32)
+    try:
+        engine.submit(np.arange(1, 5, dtype=np.int32), 5).result(
+            timeout=120.0)
+        assert engine._ticks == []
+    finally:
+        engine.close()
+        ttrace.configure(enabled=True, service="test", dir="")
+    assert engine._ticks == []
+    # the sums are the loop's own and run all the same
+    loop = engine.describe()["loop"]
+    assert loop["steps"] > 0 and loop["step_launch_s"] > 0
+
+
+def _plane_from(calls, offset, lags):
+    """A device plane of one operation a call, from the call's enqueue to
+    its return less ``lags[i]``, shifted by ``offset``."""
+    modules = []
+    for (kind, _, enqueued, returned), lag in zip(calls, lags):
+        name = "jit_decode_step(7)" if kind == "s" else "jit_decode_prefill(9)"
+        modules.append((enqueued + offset, returned + offset - lag, name))
+    return {"name": "/device:TPU:0", "modules": modules,
+            "ops": [(s, e, "%fusion.1") for s, e, _ in modules]}
+
+
+def test_ticks_reader_recovers_offset_and_idle_of_a_made_plane(stamped_run):
+    from benchmark.metrics import _ticks
+
+    calls = _ticks.host_calls(stamped_run["loops"])
+    loops = [rec["attrs"] for rec in stamped_run["loops"]]
+    assert [c[0] for c in calls].count("s") == sum(a["steps"]
+                                                   for a in loops)
+    assert [c[0] for c in calls].count("p") == 4
+    ms = 1e6
+    offset = 5 * ms - calls[0][1]        # the first entry 5 ms in
+    # every second program ends half its read before the tokens come back
+    lags = [(r - q) / 2 if i % 2 else 0.0
+            for i, (_, _, q, r) in enumerate(calls)]
+    plane = _plane_from(calls, offset, lags)
+    window = calls[-1][3] + offset + 5 * ms
+    got = _ticks.split(calls, plane, window)
+    assert got["offset_ns"] == pytest.approx(offset, abs=1.0)
+    launch = sum(q - e for _, e, q, _ in calls) / 1e9
+    loop = sum(b[1] - a[3] for a, b in zip(calls, calls[1:])) / 1e9
+    assert got["launch_s"] == pytest.approx(launch, abs=1e-9)
+    assert got["read_s"] == pytest.approx(sum(lags) / 1e9, abs=1e-9)
+    assert got["loop_s"] == pytest.approx(loop, abs=1e-9)
+    # the 5 ms before the first call and after the last are nobody's
+    assert got["idle_s"] == pytest.approx(launch + loop + sum(lags) / 1e9
+                                          + 0.010, abs=1e-9)
+    assert got["step_runs"] == sum(a["steps"] for a in loops)
+    # a window that cuts the calls short cuts their idle with them
+    half = _ticks.split(calls, plane, window / 2)
+    assert 0 < half["loop_s"] < got["loop_s"]
+    assert half["step_runs"] < got["step_runs"]
+
+
+def test_ticks_reader_reads_nothing_it_cannot_line_up(stamped_run,
+                                                       tmp_path):
+    from benchmark.metrics import _ticks
+
+    calls = _ticks.host_calls(stamped_run["loops"])
+    plane = _plane_from(calls, 0.0, [0.0] * len(calls))
+    # a kind sequence the host never ran: five prefills in a row
+    prefills = {**plane, "modules": [(0.0, 1.0, "jit_decode_prefill(9)")] * 5}
+    assert _ticks.split(calls, prefills, 1e12) is None
+    # the right kinds, each run 1 ms long and 2 ms after the last, whatever
+    # the host did: the runs do not fall inside their calls
+    ms = 1e6
+    even = {**plane, "modules": [(2 * ms * i, 2 * ms * i + ms, n)
+                                 for i, (_, _, n) in enumerate(
+                                     plane["modules"])]}
+    assert _ticks.split(calls, even, 1e12) is None
+    # no trace (off the chip), and no stamps (a program that does not
+    # stamp its ticks): nothing, and nothing raised
+    assert _ticks.read({"trace": None, "telemetry_dir": str(tmp_path)}) \
+        is None
+    traced = {"trace": {"window_s": 1.0, "busy_s": 0.5},
+              "telemetry_dir": str(tmp_path / "telemetry"),
+              "window": (0.0, 1e12)}
+    (tmp_path / "telemetry").mkdir()
+    (tmp_path / "telemetry" / "serving-1.jsonl").write_text(json.dumps(
+        {"name": "decode.loop", "start": 5.0, "dur_ms": 1.0,
+         "attrs": {"steps": 3, "step_s": 0.1}}) + "\n")
+    assert _ticks.read(traced) is None
+    for name in ("decode_gap_read_ms", "decode_gap_loop_ms",
+                 "decode_gap_launch_ms", "decode_gap_named_share"):
+        from benchmark.lib import spec
+        assert spec.metric_reader(name).read(traced) is None, name
 
 
 # --------------------------------------------------------------------- #
@@ -405,10 +578,10 @@ def _serve_ctx(tmp_path):
          "attrs": {"tokens": 3}},
         {"name": "decode.loop", "start": 100.0, "dur_ms": 1000.0,
          "attrs": {"step_s": 0.6, "prefill_s": 0.1, "host_s": 0.3,
-                   "parked_s": 5.0, "steps": 30}},
+                   "parked_s": 5.0, "steps": 30, "step_launch_s": 0.15}},
         {"name": "decode.loop", "start": 101.0, "dur_ms": 1000.0,
          "attrs": {"step_s": 0.7, "prefill_s": 0.2, "host_s": 0.1,
-                   "parked_s": 0.0, "steps": 32}},
+                   "parked_s": 0.0, "steps": 32, "step_launch_s": 0.17}},
         {"name": "decode.loop", "start": 10.0, "dur_ms": 1000.0,
          "attrs": {"step_s": 0.0, "prefill_s": 0.0, "host_s": 9.0}},
     ]
@@ -429,6 +602,8 @@ READERS = [
     ("admit_wait_ms", "serve", 1000.0),
     ("prefill_ms", "serve", 40.0),
     ("decode_host_share", "serve", 20.0),
+    # the step calls' launch: 0.32 s over 62 steps
+    ("decode_launch_ms", "serve", 320.0 / 62),
 ]
 
 
