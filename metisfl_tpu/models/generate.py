@@ -304,6 +304,23 @@ def decode_call(module) -> Tuple[Callable, tuple]:
                      caches, jax.ShapeDtypeStruct((), jnp.int32))
 
 
+class DecodeCall:
+    """One launched :class:`SlotDecoder` call: its tokens stay on the
+    device until :meth:`SlotDecoder.read`. Stamps on the host's
+    ``perf_counter``: ``entry`` (before anything is placed), ``enqueued``
+    (the jitted call returned), ``read_at`` (the read began) and
+    ``returned`` (the tokens on the host)."""
+
+    __slots__ = ("out", "prefill", "entry", "enqueued", "read_at",
+                 "returned")
+
+    def __init__(self, out, prefill: bool, entry: float, enqueued: float):
+        self.out = out
+        self.prefill = prefill
+        self.entry, self.enqueued = entry, enqueued
+        self.read_at = self.returned = 0.0
+
+
 class SlotDecoder:
     """Fixed-slot KV-cache decode programs for continuous batching (Orca,
     Yu et al. OSDI 2022 — iteration-level scheduling over an in-flight
@@ -336,33 +353,46 @@ class SlotDecoder:
     behind: a Mamba block starts from zero at position 0 whatever the slot
     held (``MambaMixer``), which is the same guarantee by other means.
 
-    The cache is this object's alone, and every call consumes it: prefill
-    and step donate the whole tree (``kv`` and ``state`` leaves alike), the
-    compiled programs alias each leaf's output to its input, and a call
-    changes the buffers that are there (a step one row a slot,
-    ``zoo.transformer._write_kv``; a prefill one slot) where an undonated
-    call wrote 1.07 GB of new cache at 8 slots of 2048. The arrays a call
-    was given are deleted when it returns, so nobody else may keep them:
-    hold sizes (:func:`cache_bytes_by_kind`), not leaves. A call that fails
-    after the runtime took the buffers (an out-of-memory step, a device
-    error that surfaces at the token read) would leave deleted arrays
-    behind; the decoder then starts over with zeroed slots before it
-    re-raises, and whoever schedules the slots must empty all of them
-    (``ContinuousBatcher`` does, on any failed tick). A call that fails
-    before (the length check, a trace or compile error) consumed nothing
-    and resets nothing. ``donated_calls`` counts the calls after which the
-    input's buffers were gone (one ``is_deleted()`` a call; equal to the
-    calls made), ``cache_resets`` the times the slots were started over.
+    The decoder's ``state`` is ``(caches, tokens, positions)``: the cache,
+    each lane's last token and the position its next token is written at,
+    (slots,) int32 each. A prefill writes its first token and its prompt's
+    length into its lane; a step reads both vectors, advances every lane
+    and writes the next tokens and positions back (a free lane keeps
+    decoding what it held, at a position held below ``max_len``; nothing
+    reads it). So a step needs nothing from the host, and the two halves of
+    a call are separate: ``launch_prefill`` / ``launch_step`` enqueue the
+    program and return a :class:`DecodeCall`, whose tokens (an output of
+    their own, not part of the state) ``read`` fetches later. A caller can
+    launch step k+1 before it reads step k (``ContinuousBatcher`` does);
+    ``prefill`` and ``step`` are the two halves back to back, and ``step``
+    places the tokens and positions it is given first.
+
+    The state is this object's alone, and every call consumes it: prefill
+    and step donate the whole tree (``kv`` and ``state`` leaves and the two
+    vectors alike), the compiled programs alias each leaf's output to its
+    input, and a call changes the buffers that are there (a step one row a
+    slot, ``zoo.transformer._write_kv``; a prefill one slot) where an
+    undonated call wrote 1.07 GB of new cache at 8 slots of 2048. The
+    arrays a call was given are deleted when it is enqueued, so nobody else
+    may keep them: hold sizes (:func:`cache_bytes_by_kind`), not leaves. A
+    launch that fails after the runtime took the buffers (an out-of-memory
+    step), and any read that fails (a device error surfaces there, and the
+    state has been handed on to whatever was launched since), would leave
+    deleted or poisoned arrays behind; the decoder then starts over with
+    zeroed slots before it re-raises, and whoever schedules the slots must
+    empty all of them and fail every call in flight (``ContinuousBatcher``
+    does, on any failed tick). A launch that fails before (the length
+    check, a trace or compile error) consumed nothing and resets nothing.
+    ``donated_calls`` counts the launches after which the input's buffers
+    were gone (one ``is_deleted()`` a call; equal to the calls made),
+    ``cache_resets`` the times the slots were started over.
 
     Each call is cut in two on the host's clock, with no sync added: the
-    *launch*, from its entry (before the inputs are placed) to the jitted
-    call's return (placed and enqueued), and the *read*, from there to the
-    tokens on the host (the one point where the host waits for the
-    device, and the call's inputs let go: the donated tree's and the
-    placed inputs' arrays are freed as the call returns). ``step_launch_s``,
-    ``step_read_s``, ``prefill_launch_s`` and ``prefill_read_s`` sum them;
-    ``last_call`` holds the three ``perf_counter`` stamps (entry, enqueued,
-    returned) of the latest call.
+    *launch*, from its entry (before any input is placed) to the jitted
+    call's return (enqueued, the input state let go, the tokens' copy to
+    the host started), and the *read*, the time the host blocks on the
+    tokens. ``step_launch_s``, ``step_read_s``, ``prefill_launch_s`` and
+    ``prefill_read_s`` sum them; the :class:`DecodeCall` holds the stamps.
 
     ``variables`` is whatever tree the caller serves. The gateway hands
     in the module's own casts made once (serving/gateway.py
@@ -388,63 +418,81 @@ class SlotDecoder:
         self.module = module
         self.slots = int(slots)
         self.max_len = int(max_len)
-        self.caches = self._zeroed()
-        self.donated_calls = 0   # calls that left their input cache deleted
+        self.state = self._zeroed()
+        self.donated_calls = 0   # calls that left their input state deleted
         self.cache_resets = 0    # failed calls that had consumed it
         self.step_launch_s = self.step_read_s = 0.0
         self.prefill_launch_s = self.prefill_read_s = 0.0
-        self.last_call = (0.0, 0.0, 0.0)
-        self._entry = self._enqueued = 0.0
         self._prefill_fns: dict = {}
         self._step_fn = None
 
+    @property
+    def caches(self):
+        """The slots' per-block state, one entry a block as the module
+        lays it out (the first part of ``state``)."""
+        return self.state[0]
+
     def _zeroed(self):
-        """Slot-major state, each slot the batch-1 state one solo
-        generate(B=1) call sees."""
-        return jax.tree.map(
+        """``(caches, tokens, positions)`` of empty slots: slot-major
+        caches, each slot the batch-1 state one solo generate(B=1) call
+        sees, and zeroed lanes."""
+        caches = jax.tree.map(
             lambda a: jnp.zeros((self.slots,) + a.shape, a.dtype),
             init_cache(self.module, 1, self.max_len))
+        return (caches, jnp.zeros((self.slots,), jnp.int32),
+                jnp.zeros((self.slots,), jnp.int32))
 
-    def _call(self, fn, variables, *args):
-        """Run one donating program on the cache and read its token(s) to
-        the host: the one place the cache changes hands. The read is the
-        call's end (dispatch is asynchronous; a failure on the device
-        surfaces there), so a call that raises anywhere after the runtime
-        took the buffers starts the slots over, and one that raises before
-        (a trace or compile error) leaves them as they were."""
-        taken = self.caches
+    def _start_over(self) -> None:
+        self.state = self._zeroed()
+        self.cache_resets += 1
+
+    def _launch(self, fn, variables, *args, prefill: bool,
+                entry: float) -> DecodeCall:
+        """Enqueue one donating program on the state: the one place the
+        state changes hands. A launch that raises after the runtime took
+        the buffers starts the slots over; one that raises before (a trace
+        or compile error) leaves them as they were."""
+        taken = self.state
         try:
-            self.caches, out = fn(variables, taken, *args)
-            self._enqueued = time.perf_counter()
-            out = np.asarray(out)
+            self.state, out = fn(variables, taken, *args)
+            out.copy_to_host_async()
         except Exception:
             if any(leaf.is_deleted() for leaf in jax.tree.leaves(taken)):
-                self.caches = self._zeroed()
-                self.cache_resets += 1
+                self._start_over()
             raise
+        enqueued = time.perf_counter()
         self.donated_calls += jax.tree.leaves(taken)[0].is_deleted()
-        return out
-
-    def _returned(self, prefill: bool) -> None:
-        """A call's end, once ``_call`` has returned and its inputs are let
-        go: its stamps (``_entry`` from the caller, before it placed the
-        inputs; ``_enqueued`` from ``_call``) and its two halves summed."""
-        returned = time.perf_counter()
-        entry, enqueued = self._entry, self._enqueued
         if prefill:
             self.prefill_launch_s += enqueued - entry
-            self.prefill_read_s += returned - enqueued
         else:
             self.step_launch_s += enqueued - entry
-            self.step_read_s += returned - enqueued
-        self.last_call = (entry, enqueued, returned)
+        return DecodeCall(out, prefill, entry, enqueued)
 
-    def prefill(self, variables, slot: int, prompt) -> int:
-        """Admit a prompt into ``slot``: write its K/V, return the first
-        greedy token. The prompt runs at its EXACT length (no padding) —
-        the same program a solo generate's prefill compiles — which is
-        what keeps slot outputs bit-identical to single-request decode."""
-        self._entry = time.perf_counter()
+    def read(self, call: DecodeCall) -> np.ndarray:
+        """Block on ``call``'s tokens: a step's (slots,), a prefill's one.
+        A device failure surfaces here, after later launches may have
+        consumed the state it poisoned: any failed read starts the slots
+        over before it re-raises."""
+        call.read_at = time.perf_counter()
+        try:
+            out = np.asarray(call.out)
+        except Exception:
+            self._start_over()
+            raise
+        call.returned = time.perf_counter()
+        if call.prefill:
+            self.prefill_read_s += call.returned - call.read_at
+        else:
+            self.step_read_s += call.returned - call.read_at
+        return out
+
+    def launch_prefill(self, variables, slot: int, prompt) -> DecodeCall:
+        """Enqueue a prompt's admission into ``slot``: its K/V, its first
+        greedy token and its length into the slot's lane. The prompt runs
+        at its EXACT length (no padding) — the same program a solo
+        generate's prefill compiles — which is what keeps slot outputs
+        bit-identical to single-request decode."""
+        entry = time.perf_counter()
         prompt = jnp.asarray(prompt, jnp.int32).reshape(1, -1)
         L = int(prompt.shape[1])
         if L < 1 or L >= self.max_len:
@@ -454,7 +502,8 @@ class SlotDecoder:
         if fn is None:
             module = self.module
 
-            def run(variables, caches, prompt, slot):
+            def run(variables, state, prompt, slot):
+                caches, toks, poss = state
                 sub = jax.tree.map(
                     lambda c: jax.lax.dynamic_index_in_dim(
                         c, slot, 0, keepdims=False), caches)
@@ -464,7 +513,10 @@ class SlotDecoder:
                     lambda c, s: jax.lax.dynamic_update_index_in_dim(
                         c, s, slot, 0), caches, sub)
                 tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-                return caches, tok[0]
+                toks = jax.lax.dynamic_update_index_in_dim(toks, tok, slot, 0)
+                poss = jax.lax.dynamic_update_index_in_dim(
+                    poss, jnp.full((1,), L, jnp.int32), slot, 0)
+                return (caches, toks, poss), tok[0]
 
             while len(self._prefill_fns) >= self._PREFILL_MAX:
                 self._prefill_fns.pop(next(iter(self._prefill_fns)))
@@ -472,22 +524,22 @@ class SlotDecoder:
                 run, name="decode.prefill", donate_argnums=(1,))
         else:
             self._prefill_fns[L] = self._prefill_fns.pop(L)  # LRU refresh
-        tok = int(self._call(fn, variables, prompt,
-                             jnp.asarray(slot, jnp.int32)))
-        self._returned(prefill=True)
-        return tok
+        return self._launch(fn, variables, prompt,
+                            jnp.asarray(slot, jnp.int32), prefill=True,
+                            entry=entry)
 
-    def step(self, variables, tokens, positions):
-        """Advance EVERY slot one decode token (one fixed-shape jitted
-        program). ``tokens``/``positions`` are (slots,) int arrays; free
-        slots pass any value (their lanes compute garbage that is never
-        read, and their cache writes land at positions a future prefill
-        overwrites). Returns the (slots,) next greedy tokens."""
-        self._entry = time.perf_counter()
+    def launch_step(self, variables) -> DecodeCall:
+        """Enqueue one step of EVERY lane (one fixed-shape jitted program)
+        from the tokens and positions the state holds; free lanes compute
+        garbage that is never read, and their cache writes land at
+        positions a future prefill overwrites."""
+        entry = time.perf_counter()
         if self._step_fn is None:
-            module = self.module
+            module, last = self.module, self.max_len - 1
 
-            def run(variables, caches, toks, positions):
+            def run(variables, state):
+                caches, toks, poss = state
+
                 def one(sub, tok, pos):
                     logits, sub = module.apply(
                         variables, tok.reshape(1, 1), caches=sub,
@@ -495,13 +547,26 @@ class SlotDecoder:
                     nxt = jnp.argmax(logits[:, -1], axis=-1)
                     return sub, nxt.astype(jnp.int32)[0]
 
-                return jax.vmap(one, in_axes=(0, 0, 0))(caches, toks,
-                                                        positions)
+                caches, nxt = jax.vmap(one, in_axes=(0, 0, 0))(caches, toks,
+                                                               poss)
+                # an occupant never passes ``last``; a free lane stays there
+                return (caches, nxt, jnp.minimum(poss + 1, last)), nxt
 
             self._step_fn = _runtime.monitored_jit(
                 run, name="decode.step", donate_argnums=(1,))
-        out = self._call(self._step_fn, variables,
-                         jnp.asarray(tokens, jnp.int32),
-                         jnp.asarray(positions, jnp.int32))
-        self._returned(prefill=False)
-        return out
+        return self._launch(self._step_fn, variables, prefill=False,
+                            entry=entry)
+
+    def prefill(self, variables, slot: int, prompt) -> int:
+        """Admit a prompt into ``slot`` and return its first greedy token
+        (:meth:`launch_prefill`, then :meth:`read`)."""
+        return int(self.read(self.launch_prefill(variables, slot, prompt)))
+
+    def step(self, variables, tokens, positions):
+        """Advance EVERY slot one decode token from ``tokens``/``positions``,
+        (slots,) int arrays placed as the lanes' own; free slots pass any
+        value. Returns the (slots,) next greedy tokens
+        (:meth:`launch_step`, then :meth:`read`)."""
+        self.state = (self.caches, jnp.asarray(tokens, jnp.int32),
+                      jnp.asarray(positions, jnp.int32))
+        return self.read(self.launch_step(variables))

@@ -10,6 +10,14 @@ finish. The decode step itself stays ONE jitted program at fixed slot
 shapes (:class:`~metisfl_tpu.models.generate.SlotDecoder`), so
 admission and retirement never recompile anything.
 
+The loop runs one step ahead of its reads: each lane's last token lives on
+the device, so step k+1 is launched from step k's outputs before the host
+blocks on step k's tokens, and whenever the host waits, the device has the
+next program queued behind the one it is reading. A request that ends by
+its token count leaves its lane before its last token is read; one that
+emits ``eos_id`` at step k has already been launched into step k+1, whose
+token for it is thrown away (``late_lanes``).
+
 Hot-swap follows the gateway's zero-drop contract: a ``swap()`` marks a
 pending (version, variables) pair; the in-flight batch FINISHES on the
 pair it captured (one shared-variables program cannot mix versions
@@ -65,20 +73,24 @@ _M_DECODE_CACHE = _REG.gauge(
 PAD_ID = 0
 
 # what the decode loop keeps of its own time, per tick: seconds inside
-# ``SlotDecoder.step`` (entry to tokens on the host, on the decoder's own
-# stamps, ``last_call``), seconds inside ``prefill``, the rest of the tick
-# on the host (admission under the lock, token hand-out, retirement),
-# seconds parked with no queue and no active slot (in neither side of a
-# host share), and counts; the rest are the decoder's own: calls that
-# updated the slots' cache in place (``donated_calls`` = ``steps`` +
-# ``prefills``), failed calls after which the slots were started over, and
-# each kind of call cut in two, launch (entry to enqueued) and read (to the
-# tokens on the host): ``step_launch_s`` + ``step_read_s`` = ``step_s``,
-# and the same for prefill.
+# the decoder's step calls (their launches and the time the host blocked
+# on their tokens), the same for prefill, the rest of the tick on the host
+# (admission under the lock, token hand-out, retirement), seconds parked
+# with nothing queued, no slot active and nothing in flight (in neither
+# side of a host share), and counts: ``overlapped_steps`` the steps
+# launched while the previous step's tokens were still unread,
+# ``late_lanes`` the lane-steps computed for a request that had already
+# emitted eos, whose tokens were thrown away. The rest are the decoder's
+# own: calls that updated the slots' state in place (``donated_calls`` =
+# ``steps`` + ``prefills``), failed calls after which the slots were
+# started over, and each kind of call cut in two, launch (entry to
+# enqueued) and read (blocked on the tokens): ``step_launch_s`` +
+# ``step_read_s`` = ``step_s``, and the same for prefill.
 DECODER_SUMS = ("donated_calls", "cache_resets", "step_launch_s",
                 "step_read_s", "prefill_launch_s", "prefill_read_s")
 LOOP_SUMS = ("step_s", "prefill_s", "host_s", "parked_s", "ticks",
-             "steps", "prefills", "admitted", "retired") + DECODER_SUMS
+             "steps", "prefills", "admitted", "retired", "overlapped_steps",
+             "late_lanes") + DECODER_SUMS
 LOOP_EVENT_EVERY_S = 1.0
 
 
@@ -105,17 +117,17 @@ class _GenPending:
 
 
 class _Slot:
-    """One occupied decode slot's host-side state."""
+    """One admitted request's host-side state: the tokens read so far, and
+    how many its launched calls will have given (``launched``)."""
 
-    __slots__ = ("req", "tokens", "position", "last_tok", "version")
+    __slots__ = ("req", "tokens", "launched", "version", "done")
 
-    def __init__(self, req: _GenPending, first_tok: int, position: int,
-                 version: int):
+    def __init__(self, req: _GenPending, version: int):
         self.req = req
-        self.tokens: List[int] = [first_tok]
-        self.position = position         # next cache write position
-        self.last_tok = first_tok
+        self.tokens: List[int] = []
+        self.launched = 1                # its prefill's token
         self.version = version
+        self.done = False
 
 
 class ContinuousBatcher:
@@ -126,10 +138,11 @@ class ContinuousBatcher:
     construction: the tree as the gateway holds it, cast once at install
     to what the programs use (serving/gateway.py ``_load_variables``).
     One worker thread owns the decode loop: each
-    iteration admits queued prompts into free slots (prefill), then
-    advances every active slot one token through the single jitted step
-    program. Per-request ``max_new_tokens`` retire sequences
-    independently — nobody waits for the slowest request in the batch.
+    iteration admits queued prompts into free slots (prefill), launches
+    the next step of every active slot through the single jitted step
+    program, and only then reads the step launched the iteration before.
+    Per-request ``max_new_tokens`` retire sequences independently —
+    nobody waits for the slowest request in the batch.
     """
 
     def __init__(self, model_ops, version: int, variables: Any,
@@ -161,13 +174,17 @@ class ContinuousBatcher:
         # measured, the worker's wait() park is queue occupancy
         self._cv = threading.Condition(_prof.lock("serving.decode"))
         self._slots: List[Optional[_Slot]] = [None] * self.slots
+        # the step launched and not yet read, with the lanes it advanced;
+        # and every admitted request whose reply is not out yet
+        self._inflight: Optional[tuple] = None
+        self._open: set = set()
         self._closed = False
         self.tokens_emitted = 0
         # the loop's own account of its time (``LOOP_SUMS``): running
         # totals the worker alone writes, read whole by describe() and as
         # differences by the once-a-second ``decode.loop`` event
         self._sums = {k: 0.0 if k.endswith("_s") else 0 for k in LOOP_SUMS
-                      if k not in DECODER_SUMS}
+                      if k not in DECODER_SUMS + ("step_s", "prefill_s")}
         self._emitted = self._totals()
         self._emitted_at = time.perf_counter()
         # with the tracer on, the worker stamps each tick (``_stamp``)
@@ -185,9 +202,17 @@ class ContinuousBatcher:
 
     def _totals(self) -> Dict[str, Any]:
         """``LOOP_SUMS`` as they stand: the worker's sums and, read where
-        they are kept, the decoder's."""
-        return dict(self._sums, **{k: getattr(self._decoder, k)
-                                   for k in DECODER_SUMS})
+        they are kept, the decoder's (``step_s`` and ``prefill_s`` are its
+        launches and reads)."""
+        d = self._decoder
+        return dict(self._sums, step_s=d.step_launch_s + d.step_read_s,
+                    prefill_s=d.prefill_launch_s + d.prefill_read_s,
+                    **{k: getattr(d, k) for k in DECODER_SUMS})
+
+    def _idle(self) -> bool:
+        """No slot occupied and nothing in flight (called under the lock
+        or by the worker)."""
+        return self._inflight is None and all(s is None for s in self._slots)
 
     # -- request side --------------------------------------------------- #
 
@@ -244,7 +269,10 @@ class ContinuousBatcher:
         return admitted
 
     def _retire(self, idx: int, slot: _Slot) -> None:
-        self._slots[idx] = None
+        slot.done = True
+        if self._slots[idx] is slot:
+            self._slots[idx] = None
+        self._open.discard(slot)
         req = slot.req
         out = np.full((req.max_new,), PAD_ID, np.int32)
         out[: len(slot.tokens)] = slot.tokens
@@ -271,21 +299,19 @@ class ContinuousBatcher:
             tick_at = time.perf_counter()
             parked = 0.0
             with self._cv:
-                while (not self._queue
-                       and all(s is None for s in self._slots)
+                while (not self._queue and self._idle()
                        and self._pending_pair is None
                        and not self._closed):
                     t0 = time.perf_counter()
                     self._cv.wait(0.1)
                     parked += time.perf_counter() - t0
-                if (self._closed and not self._queue
-                        and all(s is None for s in self._slots)):
+                if self._closed and not self._queue and self._idle():
                     if sums["ticks"] > self._emitted["ticks"]:
                         self._emit_loop(time.perf_counter())
                     return
-                if (self._pending_pair is not None
-                        and all(s is None for s in self._slots)):
-                    # drained: install the new pair, resume admission
+                if self._pending_pair is not None and self._idle():
+                    # drained, the last step read: install the new pair,
+                    # resume admission
                     self._pair = self._pending_pair
                     self._pending_pair = None
                 admitted = self._admit_locked()
@@ -307,28 +333,36 @@ class ContinuousBatcher:
                 if now - self._emitted_at >= LOOP_EVENT_EVERY_S:
                     self._emit_loop(now)
             except Exception as exc:  # noqa: BLE001 - worker must survive
-                # one poisoned tick (bad prompt dtype, an OOM'd step)
-                # fails ITS requests only — a dead worker would hang
-                # every later Generate on this channel. Every slot is
-                # emptied: a call that failed after it had consumed the
-                # cache left the decoder with zeroed slots
-                # (``SlotDecoder._call``), which is what empty slots need
+                # one poisoned tick (bad prompt dtype, an OOM'd step, a
+                # device error surfacing at a read) fails the requests in
+                # flight only — a dead worker would hang every later
+                # Generate on this channel. Every slot is emptied and the
+                # pipeline dropped: a call that failed after it had
+                # consumed the state left the decoder with zeroed slots
+                # (``SlotDecoder._launch``, ``read``), which is what empty
+                # slots need
                 logger.exception("decode tick failed")
                 with self._cv:
                     for req in admitted:
                         if not req.future.done():
                             req.future.set_exception(exc)
-                    for idx, slot in enumerate(self._slots):
-                        if slot is not None:
-                            if not slot.req.future.done():
-                                slot.req.future.set_exception(exc)
-                            self._slots[idx] = None
+                    for slot in self._open:
+                        slot.done = True
+                        if not slot.req.future.done():
+                            slot.req.future.set_exception(exc)
+                    self._open.clear()
+                    self._slots = [None] * self.slots
+                    self._inflight = None
 
     def _stamp(self, stamp: list, end: float) -> None:
         """Keep one tick's stamps as whole microseconds after the batch's
         anchor: ``[start, released, [[kind, entry, enqueued, returned],
         ...], end]``, kind ``s`` a step and ``p<L>`` a prefill of L
-        tokens (the calls' stamps are ``SlotDecoder.last_call``)."""
+        tokens: the calls READ in the tick, in entry order (the
+        :class:`~metisfl_tpu.models.generate.DecodeCall` stamps). A step
+        read here was entered in the tick before, so its entry precedes
+        ``start``, and its ``returned`` follows the next step's
+        ``enqueued``."""
         base = self._anchor[1]
         start, released, *calls = stamp
         self._ticks.append(
@@ -359,66 +393,89 @@ class ContinuousBatcher:
 
     def _tick(self, admitted: List[_GenPending],
               stamp: Optional[list]) -> float:
-        """One iteration of the loop; returns the seconds it spent inside
-        the decoder's ``prefill`` and ``step`` calls, and appends each
-        call's stamps to ``stamp`` where it is given."""
+        """One iteration of the loop, one step ahead of its reads: launch
+        the admitted prompts' prefills into free lanes and step k+1 over
+        every occupied lane, and only then block on step k's tokens (the
+        last tick's launch) and the prefills' first tokens, hand them out
+        and retire. Returns the seconds spent inside the decoder's calls
+        (launches and reads), and appends each call read to ``stamp``
+        where it is given."""
         version, variables = self._pair
-        sums = self._sums
+        decoder, sums = self._decoder, self._sums
         called_s = 0.0
-        emitted = 0
         # 1. prefill admissions between decode steps (step granularity:
         #    the running batch did NOT have to finish first)
+        prefills = []
         for req in admitted:
-            idx = next(i for i, s in enumerate(self._slots) if s is None)
-            first = self._decoder.prefill(variables, idx, req.prompt)
-            entry, _, returned = call = self._decoder.last_call
-            if stamp is not None:
-                stamp.append((f"p{req.prompt.size}",) + call)
-            prefill_s = returned - entry
-            req.wait_ms = (entry - req.enqueued_at) * 1e3
-            req.prefill_ms = prefill_s * 1e3
-            sums["prefill_s"] += prefill_s
-            sums["prefills"] += 1
-            called_s += prefill_s
+            idx = self._slots.index(None)
+            call = decoder.launch_prefill(variables, idx, req.prompt)
+            called_s += call.enqueued - call.entry
+            req.wait_ms = (call.entry - req.enqueued_at) * 1e3
             req.admitted_step = self.steps
-            slot = _Slot(req, first, int(req.prompt.size), version)
-            emitted += 1
-            if ((req.eos_id is not None and first == req.eos_id)
-                    or req.max_new == 1):
-                self._retire(idx, slot)
-            else:
-                self._slots[idx] = slot
+            slot = self._slots[idx] = _Slot(req, version)
+            self._open.add(slot)
+            prefills.append((idx, slot, call))
+            sums["prefills"] += 1
+        # 2. step k+1 over the lanes whose request wants more tokens, from
+        #    the tokens and positions on the device (one program; free
+        #    lanes are never read)
+        previous, self._inflight = self._inflight, None
         active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None]
+                  if s is not None and s.launched < s.req.max_new]
         _M_DECODE_SLOTS.set(len(active), channel=self.channel)
         if active:
-            # 2. one decode step for the whole in-flight batch (one
-            #    program; free lanes carry zeros and are never read)
-            toks = np.zeros((self.slots,), np.int32)
-            poss = np.zeros((self.slots,), np.int32)
-            for i, s in active:
-                toks[i], poss[i] = s.last_tok, s.position
-            nxt = self._decoder.step(variables, toks, poss)
-            entry, _, returned = call = self._decoder.last_call
-            if stamp is not None:
-                stamp.append(("s",) + call)
-            sums["step_s"] += returned - entry
+            call = decoder.launch_step(variables)
+            called_s += call.enqueued - call.entry
             sums["steps"] += 1
-            called_s += returned - entry
-            for i, s in active:
-                tok = int(nxt[i])
-                s.tokens.append(tok)
-                s.last_tok = tok
-                s.position += 1
-                emitted += 1
-                done = (len(s.tokens) >= s.req.max_new
-                        or (s.req.eos_id is not None
-                            and tok == s.req.eos_id))
-                if done:
-                    self._retire(i, s)
+            sums["overlapped_steps"] += previous is not None
+            for _, s in active:
+                s.launched += 1
+            self._inflight = (call, active)
+        self._free_counted()
+        # 3. only now block: step k's tokens, then the prefills' first
+        #    tokens, with step k+1 queued behind them on the device
+        emitted = 0
+        read = []
+        if previous is not None:
+            call, lanes = previous
+            toks = decoder.read(call)
+            read.append(("s", call))
+            for i, s in lanes:
+                if s.done:           # emitted eos at step k-1 or prefill
+                    sums["late_lanes"] += 1
+                else:
+                    self._hand_out(i, s, int(toks[i]))
+                    emitted += 1
+        for idx, slot, call in prefills:
+            tok = int(decoder.read(call))
+            slot.req.prefill_ms = (call.returned - call.entry) * 1e3
+            read.append((f"p{slot.req.prompt.size}", call))
+            self._hand_out(idx, slot, tok)
+            emitted += 1
+        for kind, call in read:
+            called_s += call.returned - call.read_at
+            if stamp is not None:
+                stamp.append((kind, call.entry, call.enqueued,
+                              call.returned))
         self.tokens_emitted += emitted
         _M_DECODE_TOKENS.inc(emitted, channel=self.channel)
         return called_s
+
+    def _free_counted(self) -> None:
+        """Free the lanes whose request the launched calls complete by its
+        token count: its last tokens are read later, and the next tick may
+        admit into the lane (its prefill runs after them on the device)."""
+        for i, s in enumerate(self._slots):
+            if s is not None and s.launched >= s.req.max_new:
+                self._slots[i] = None
+
+    def _hand_out(self, idx: int, slot: _Slot, tok: int) -> None:
+        """One token read for ``slot``; retire it on its count or eos."""
+        slot.tokens.append(tok)
+        req = slot.req
+        if (len(slot.tokens) >= req.max_new
+                or (req.eos_id is not None and tok == req.eos_id)):
+            self._retire(idx, slot)
 
     # -- status --------------------------------------------------------- #
 

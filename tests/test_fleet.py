@@ -422,6 +422,111 @@ def test_decode_swap_finishes_in_flight_on_captured_pair():
         engine.close()
 
 
+# the loop runs one step ahead of its reads: step k+1 is launched from the
+# device's tokens before step k's are read
+
+def test_decode_slot_reuse_over_residue_matches_solo_generate():
+    """More requests than slots, of mixed lengths: lanes are freed by count
+    before their last token is read and taken by the next prompt over the
+    residue the last occupant left (a cache row past its frontier, the
+    lane's token and position); every reply is a solo ``generate``'s, and
+    the steps overlapped their reads."""
+    from metisfl_tpu.models.generate import generate
+
+    ops = _lm_ops()
+    variables = ops.get_variables()
+    engine = ContinuousBatcher(ops, 1, variables, slots=2, max_len=32)
+    try:
+        rng = np.random.default_rng(3)
+        asks = [(rng.integers(1, 97, size=(n,)).astype(np.int32), m)
+                for n, m in ((9, 12), (3, 2), (5, 7), (2, 1), (7, 9),
+                             (4, 3))]
+        futs = [engine.submit(p, m) for p, m in asks]
+        for (p, m), fut in zip(asks, futs):
+            tokens, _ = fut.result(timeout=60.0)
+            ref = np.asarray(generate(ops.module, variables, p[None], m,
+                                      max_len=32))[0]
+            np.testing.assert_array_equal(tokens, ref)
+    finally:
+        engine.close()
+    loop = engine.describe()["loop"]
+    assert loop["prefills"] == 6 and loop["retired"] == 6
+    assert 0 < loop["overlapped_steps"] < loop["steps"]
+    assert loop["late_lanes"] == 0
+    assert loop["donated_calls"] == loop["steps"] + loop["prefills"]
+
+
+@pytest.mark.parametrize("at", [0, 3], ids=["prefill", "step"])
+def test_decode_eos_late_lane_is_thrown_away(at):
+    """A request that emits eos at token ``at`` has been launched into the
+    next step before its eos is read: that lane's token is thrown away
+    (``late_lanes`` 1), the reply is ``PAD_ID`` after eos exactly as
+    ``generate`` pads it, and a neighbour decoding on stays bit-identical."""
+    from metisfl_tpu.models.generate import generate
+
+    ops = _lm_ops()
+    variables = ops.get_variables()
+    prompt = np.array([3, 5, 7], np.int32)
+    other = np.array([8, 1, 6, 2], np.int32)
+    ref = np.asarray(generate(ops.module, variables, prompt[None], 12,
+                              max_len=32))[0]
+    eos = int(ref[at])
+    assert eos not in ref[:at]
+    ref_eos = np.asarray(generate(ops.module, variables, prompt[None], 12,
+                                  max_len=32, eos_id=eos))[0]
+    ref_other = np.asarray(generate(ops.module, variables, other[None], 10,
+                                    max_len=32))[0]
+    engine = ContinuousBatcher(ops, 1, variables, slots=2, max_len=32)
+    try:
+        fut_other = engine.submit(other, 10)
+        tokens, _ = engine.submit(prompt, 12, eos_id=eos).result(
+            timeout=60.0)
+        np.testing.assert_array_equal(tokens, ref_eos)
+        assert tokens[at] == eos and not tokens[at + 1:].any()
+        np.testing.assert_array_equal(fut_other.result(timeout=60.0)[0],
+                                      ref_other)
+    finally:
+        engine.close()
+    loop = engine.describe()["loop"]
+    assert loop["late_lanes"] == 1
+    assert loop["donated_calls"] == loop["steps"] + loop["prefills"]
+
+
+def test_decode_swap_drains_the_pipeline_on_the_captured_pair():
+    """A swap while a step is in flight: every step of the running request,
+    the one launched before the swap was seen included, runs on the pair
+    it was admitted under (its reply is that pair's solo ``generate`` to
+    the last token), and the queued request decodes on the new pair."""
+    import jax
+
+    from metisfl_tpu.models.generate import generate
+
+    ops = _lm_ops()
+    v1 = ops.get_variables()
+    v2 = jax.tree.map(lambda a: np.asarray(a) * 1.5, v1)
+    a_prompt, b_prompt = (np.array([3, 5, 7], np.int32),
+                          np.array([9, 4], np.int32))
+    engine = ContinuousBatcher(ops, 1, v1, slots=2, max_len=64)
+    try:
+        fut_a = engine.submit(a_prompt, 30)
+        deadline = time.time() + 30.0
+        while engine.steps < 2 and time.time() < deadline:
+            time.sleep(0.002)
+        engine.swap(2, v2)
+        fut_b = engine.submit(b_prompt, 6)
+        toks_a, ver_a = fut_a.result(timeout=60.0)
+        toks_b, ver_b = fut_b.result(timeout=60.0)
+    finally:
+        engine.close()
+    assert (ver_a, ver_b) == (1, 2)
+    np.testing.assert_array_equal(toks_a, np.asarray(generate(
+        ops.module, v1, a_prompt[None], 30, max_len=64))[0])
+    np.testing.assert_array_equal(toks_b, np.asarray(generate(
+        ops.module, v2, b_prompt[None], 6, max_len=64))[0])
+    # B was admitted only once A's last step had been read
+    assert fut_b.request.admitted_step == engine.steps - 5
+
+
 def test_gateway_generate_routes_swaps_and_describes(clean_telemetry):
     ops = _lm_ops()
     cfg = ServingConfig(enabled=True,

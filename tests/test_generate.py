@@ -308,12 +308,23 @@ def test_slot_decoder_call_consumes_the_cache(family, call, recwarn):
     assert not [w for w in recwarn if "donated" in str(w.message)]
 
 
-@pytest.mark.parametrize("how", ["deleted_under_it", "raised_after_taking"])
+class _Unreadable:
+    """Tokens whose device failed: the failure surfaces at the read."""
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        raise RuntimeError("the device gave up")
+
+
+@pytest.mark.parametrize("how", ["deleted_under_it", "raised_after_taking",
+                                 "failed_at_read"])
 @pytest.mark.parametrize("family", FAMILIES)
 def test_slot_decoder_starts_over_after_a_call_that_consumed(family, how):
-    """A call that fails once the cache's buffers are gone leaves zeroed
-    slots, not deleted arrays: the next occupant decodes what a solo
-    ``generate`` decodes, bit for bit."""
+    """A call that fails once the cache's buffers are gone, or whose read
+    fails, leaves zeroed slots, not deleted or poisoned arrays: the next
+    occupant decodes what a solo ``generate`` decodes, bit for bit."""
     from metisfl_tpu.models.generate import SlotDecoder
 
     module, variables, prompt = _toy(family)
@@ -330,15 +341,20 @@ def test_slot_decoder_starts_over_after_a_call_that_consumed(family, how):
             real(*args)                  # the runtime took the buffers
             raise RuntimeError("the device gave up")
 
-        decoder._step_fn = fails_late
+        def fails_at_read(variables, state):
+            state, _ = real(variables, state)    # the state is handed on
+            return state, _Unreadable()
+
+        decoder._step_fn = (fails_late if how == "raised_after_taking"
+                            else fails_at_read)
     with pytest.raises(RuntimeError):
         decoder.step(variables, [0, tok], [0, len(prompt)])
     assert decoder.cache_resets == 1
     assert not any(leaf.is_deleted()
-                   for leaf in jax.tree.leaves(decoder.caches))
+                   for leaf in jax.tree.leaves(decoder.state))
     assert not any(np.asarray(leaf).any()
-                   for leaf in jax.tree.leaves(decoder.caches))
-    if how == "raised_after_taking":
+                   for leaf in jax.tree.leaves(decoder.state))
+    if how != "deleted_under_it":
         decoder._step_fn = real
     tok = decoder.prefill(variables, 0, prompt)
     seq = [tok]
@@ -348,6 +364,32 @@ def test_slot_decoder_starts_over_after_a_call_that_consumed(family, how):
     solo = generate(module, variables, prompt[None], 6, max_len=32)
     assert seq == [int(t) for t in solo[0]]
     assert decoder.cache_resets == 1
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_slot_decoder_steps_ahead_of_its_reads(family):
+    """Each lane's token and position live on the device: five steps
+    launched back to back, none read, each from the last one's outputs,
+    decode what a solo ``generate`` decodes; every call donated the whole
+    state, and each read came after its launch."""
+    from metisfl_tpu.models.generate import SlotDecoder
+
+    module, variables, prompt = _toy(family)
+    decoder = SlotDecoder(module, slots=2, max_len=32)
+    first = decoder.launch_prefill(variables, 1, prompt)
+    calls = [decoder.launch_step(variables) for _ in range(5)]
+    seq = [int(decoder.read(first))] + [int(decoder.read(c)[1])
+                                         for c in calls]
+    solo = generate(module, variables, prompt[None], 6, max_len=32)
+    assert seq == [int(t) for t in solo[0]]
+    assert (decoder.donated_calls, decoder.cache_resets) == (6, 0)
+    caches, tokens, positions = decoder.state
+    assert caches is decoder.caches
+    assert int(tokens[1]) == seq[-1]
+    assert int(positions[1]) == len(prompt) + 5
+    for call in [first] + calls:
+        assert call.entry <= call.enqueued <= call.read_at <= call.returned
+    assert decoder.step_read_s > 0 and decoder.prefill_launch_s > 0
 
 
 @pytest.mark.parametrize("family", FAMILIES)

@@ -299,7 +299,7 @@ def test_decode_programs_compile_for_the_chip_with_the_cache_in_place(
     compiles for the chip: one file, one worker, one load of the library."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    from metisfl_tpu.models.generate import SlotDecoder
+    from metisfl_tpu.models.generate import DecodeCall, SlotDecoder
     from metisfl_tpu.models.zoo import LlamaLite
 
     before = {k: getattr(jax.config, k)
@@ -322,12 +322,12 @@ def test_decode_programs_compile_for_the_chip_with_the_cache_in_place(
             def _zeroed(self):
                 return on_chip(jax.eval_shape(super()._zeroed))
 
-            def _call(self, fn, variables, *args):
+            def _launch(self, fn, variables, *args, prefill, entry):
                 self.compiled = fn.__wrapped__.lower(
-                    variables, self.caches, *on_chip(args)).compile()
-                # a step's tokens, a prefill's one
-                return np.zeros((self.slots,) if args[0].ndim == 1 else (),
-                                np.int32)
+                    variables, on_chip(self.state), *on_chip(args)).compile()
+                # a prefill's token, a step's
+                return DecodeCall(np.zeros(() if prefill else (self.slots,),
+                                           np.int32), prefill, entry, entry)
 
         decoder = Probe(module, slots=8, max_len=2048)
         decoder.step(variables, np.zeros(8, np.int32), np.zeros(8, np.int32))
@@ -338,9 +338,11 @@ def test_decode_programs_compile_for_the_chip_with_the_cache_in_place(
         for k, v in before.items():
             jax.config.update(k, v)
         compilation_cache.reset_cache()
-    cache_bytes = 2 * 2 * 8 * 8 * 2048 * 128 * 2
+    # the cache, and the lanes' tokens and positions: (8,) int32 each,
+    # laid out in the 128 lanes of a tile row
+    state_bytes = 2 * 2 * 8 * 8 * 2048 * 128 * 2 + 2 * 128 * 4
     for program in (step, prefill):
-        assert program.memory_analysis().alias_size_in_bytes == cache_bytes
+        assert program.memory_analysis().alias_size_in_bytes == state_bytes
     text = step.as_text()
     assert " while(" not in text and " scatter(" not in text
     leaf = r"bf16\[8,1,8,2048,128\]\{[^}]*\} dynamic-update-slice\("

@@ -502,6 +502,10 @@ def test_decode_loop_counts_every_call_as_donated(family, clean_telemetry):
         assert loop["prefills"] == 6 and loop["steps"] > 0
         assert loop["donated_calls"] == loop["steps"] + loop["prefills"]
         assert loop["cache_resets"] == 0
+        # under load each step after a stretch's first was launched
+        # before the last one was read; no eos, so no lane ran late
+        assert 0 < loop["overlapped_steps"] < loop["steps"]
+        assert loop["late_lanes"] == 0
         held = [v for v in vars(engine).values()
                 if any(isinstance(leaf, jax.Array)
                        for leaf in jax.tree.leaves(v))]
@@ -515,3 +519,55 @@ def test_decode_loop_counts_every_call_as_donated(family, clean_telemetry):
     assert events
     assert sum(a["donated_calls"] for a in events) == loop["donated_calls"]
     assert sum(a["cache_resets"] for a in events) == 0
+
+
+class _Unreadable:
+    """A step's tokens whose device failed: enqueued, copy started, and
+    the failure surfaces at the read."""
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        raise RuntimeError("the device gave up")
+
+
+@pytest.mark.parametrize("family", ["llama", "jamba"])
+def test_decode_loop_fails_everything_in_flight_on_a_failed_read(family):
+    """A step whose failure surfaces at its read, a tick after the next
+    step consumed the state: every request in flight fails, the decoder
+    starts its slots over once, and the next requests decode what a solo
+    ``generate`` decodes, bit for bit."""
+    from metisfl_tpu.models.generate import generate
+
+    engine, module, variables = _decode_engine(family)
+    try:
+        rng = np.random.default_rng(9)
+        prompts = [rng.integers(1, 61, (n,)).astype(np.int32)
+                   for n in (5, 8, 3)]
+        engine.submit(prompts[0], 4).result(timeout=120)      # warm, idle
+        decoder = engine._decoder
+        real = decoder._step_fn
+
+        def fails_at_read(variables, state):
+            state, _ = real(variables, state)   # the state is handed on
+            return state, _Unreadable()
+
+        decoder._step_fn = fails_at_read
+        failed = [engine.submit(p, 6) for p in prompts[:2]]
+        for fut in failed:
+            with pytest.raises(RuntimeError, match="gave up"):
+                fut.result(timeout=120)
+        loop = engine.describe()["loop"]
+        assert loop["cache_resets"] == 1
+        assert engine.active() == 0 and engine._inflight is None
+        decoder._step_fn = real
+        for prompt in prompts:
+            got, version = engine.submit(prompt, 7).result(timeout=120)
+            want = generate(module, variables, prompt[None], 7, max_len=32)
+            assert version == 1 and list(got) == [int(t) for t in want[0]]
+        loop = engine.describe()["loop"]
+        assert loop["cache_resets"] == 1
+        assert loop["donated_calls"] == loop["steps"] + loop["prefills"]
+    finally:
+        engine.close()

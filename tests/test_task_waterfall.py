@@ -393,28 +393,57 @@ def test_decode_calls_are_cut_into_launch_and_read(stamped_run):
 
 
 def test_decode_ticks_are_stamped_in_order(stamped_run):
+    """The loop runs one step ahead of its reads: a tick's record holds the
+    calls READ in it, a step entered in the tick before and the prefills
+    entered in it. Within a call entry <= enqueued <= returned; the calls,
+    tick after tick, come in entry order, which is the order they were
+    dispatched in; a step launched while the last one was unread
+    (``overlapped_steps``) is enqueued before the last one's tokens return;
+    and the ticks' time is the sums' to the microsecond."""
     loops = [rec["attrs"] for rec in stamped_run["loops"]]
-    kinds, last = [], None
+    kinds, entries, steps, last = [], [], [], None
+    ticked = summed = 0.0
     for attrs in loops:
         stamps = attrs["stamps"]
         wall, perf = stamps["anchor"]
         assert wall > 1e9 and perf > 0
         assert len(stamps["ticks"]) == attrs["ticks"] > 0
+        summed += sum(attrs[k] for k in ("step_s", "prefill_s", "host_s",
+                                         "parked_s"))
+        assert attrs["host_s"] >= 0 and attrs["parked_s"] >= 0
         for start, released, calls, end in stamps["ticks"]:
-            flat = [start, released]
+            assert all(isinstance(t, int) for t in (start, released, end))
+            assert start <= released <= end
+            ticked += (end - start) / 1e6
             for kind, entry, enqueued, returned in calls:
+                assert all(isinstance(t, int)
+                           for t in (entry, enqueued, returned))
+                assert entry <= enqueued <= returned
+                assert released <= returned <= end     # read in this tick
+                if kind == "s":     # launched in an earlier tick
+                    assert entry < start
+                    steps.append((perf + enqueued / 1e6,
+                                  perf + returned / 1e6))
+                else:
+                    assert released <= entry
                 kinds.append(kind)
-                flat += [entry, enqueued, returned]
-            flat.append(end)
-            assert all(isinstance(t, int) for t in flat)
-            assert flat == sorted(flat)
+                entries.append(perf + entry / 1e6)
             # ticks follow one another across batches too
-            at = [perf + t / 1e6 for t in (flat[0], flat[-1])]
+            at = [perf + t / 1e6 for t in (start, end)]
             assert last is None or at[0] >= last - 1e-6
             last = at[1]
+    assert entries == sorted(entries)
     assert kinds.count("s") == sum(a["steps"] for a in loops)
-    assert sorted(k for k in kinds if k != "s") == sorted(
-        f"p{p.size}" for p in stamped_run["prompts"])
+    # admitted first come, first served: the prefills in submission order
+    assert [k for k in kinds if k != "s"] == [
+        f"p{p.size}" for p in stamped_run["prompts"]]
+    overlapped = sum(1 for (_, returned), (enqueued, _) in zip(steps,
+                                                              steps[1:])
+                     if enqueued < returned)
+    assert overlapped == sum(a["overlapped_steps"] for a in loops) > 0
+    # the sums cut the ticks' wall time whole (stamps are whole µs)
+    assert summed == pytest.approx(ticked, abs=2e-6 * len(kinds) + 1e-6
+                                   * sum(a["ticks"] for a in loops))
 
 
 def test_nothing_is_stamped_with_the_tracer_off(monkeypatch):
@@ -439,17 +468,36 @@ def test_nothing_is_stamped_with_the_tracer_off(monkeypatch):
 
 
 def _plane_from(calls, offset, lags):
-    """A device plane of one operation a call, from the call's enqueue to
-    its return less ``lags[i]``, shifted by ``offset``."""
-    modules = []
+    """A device plane of one operation a call, one after another as a
+    device runs them: from the call's enqueue (or the last run's end, if
+    later) to its return less ``lags[i]`` of what is left, shifted by
+    ``offset``."""
+    modules, free = [], float("-inf")
     for (kind, _, enqueued, returned), lag in zip(calls, lags):
         name = "jit_decode_step(7)" if kind == "s" else "jit_decode_prefill(9)"
-        modules.append((enqueued + offset, returned + offset - lag, name))
+        start = max(enqueued + offset, free)
+        free = returned + offset - lag * (returned + offset - start)
+        modules.append((start, free, name))
     return {"name": "/device:TPU:0", "modules": modules,
             "ops": [(s, e, "%fusion.1") for s, e, _ in modules]}
 
 
+def _idle_of(runs, window):
+    """Idle of ``runs`` (sorted, apart) in ``[a, b]`` clipped to the
+    window: the plain sum the reader's prefix sums stand for."""
+    def idle(a, b):
+        a, b = max(a, 0.0), min(b, window)
+        if b <= a:
+            return 0.0
+        return (b - a) - sum(max(0.0, min(b, e) - max(a, s))
+                             for s, e, _ in runs)
+    return idle
+
+
 def test_ticks_reader_recovers_offset_and_idle_of_a_made_plane(stamped_run):
+    """The reader over the stamps of a real pipelined run and a plane whose
+    programs run one after another inside their calls: the offset back,
+    and each phase the idle of its intervals, summed call by call."""
     from benchmark.metrics import _ticks
 
     calls = _ticks.host_calls(stamped_run["loops"])
@@ -459,26 +507,59 @@ def test_ticks_reader_recovers_offset_and_idle_of_a_made_plane(stamped_run):
     assert [c[0] for c in calls].count("p") == 4
     ms = 1e6
     offset = 5 * ms - calls[0][1]        # the first entry 5 ms in
-    # every second program ends half its read before the tokens come back
-    lags = [(r - q) / 2 if i % 2 else 0.0
-            for i, (_, _, q, r) in enumerate(calls)]
+    # every second program ends half its time before the tokens come back
+    lags = [0.5 if i % 2 else 0.0 for i in range(len(calls))]
     plane = _plane_from(calls, offset, lags)
+    runs = plane["modules"]
     window = calls[-1][3] + offset + 5 * ms
     got = _ticks.split(calls, plane, window)
     assert got["offset_ns"] == pytest.approx(offset, abs=1.0)
-    launch = sum(q - e for _, e, q, _ in calls) / 1e9
-    loop = sum(b[1] - a[3] for a, b in zip(calls, calls[1:])) / 1e9
-    assert got["launch_s"] == pytest.approx(launch, abs=1e-9)
-    assert got["read_s"] == pytest.approx(sum(lags) / 1e9, abs=1e-9)
-    assert got["loop_s"] == pytest.approx(loop, abs=1e-9)
+    idle = _idle_of(runs, window)
+    shifted = [(e + offset, r + offset) for _, e, _, r in calls]
+    launch = sum(idle(e, s) for (e, _), (s, _, _) in zip(shifted, runs))
+    read = sum(idle(max(d, e), r)
+               for (e, r), (_, d, _) in zip(shifted, runs))
+    loop = sum(idle(a[1], b[0]) for a, b in zip(shifted, shifted[1:]))
+    assert got["launch_s"] == pytest.approx(launch / 1e9, abs=1e-9)
+    assert got["read_s"] == pytest.approx(read / 1e9, abs=1e-9)
+    assert got["loop_s"] == pytest.approx(loop / 1e9, abs=1e-9)
+    assert got["read_s"] > 0 and got["launch_s"] > 0
     # the 5 ms before the first call and after the last are nobody's
-    assert got["idle_s"] == pytest.approx(launch + loop + sum(lags) / 1e9
-                                          + 0.010, abs=1e-9)
+    busy = sum(e - s for s, e, _ in runs)
+    assert got["idle_s"] == pytest.approx((window - busy) / 1e9, abs=1e-9)
+    assert got["idle_s"] >= 0.010 - 1e-9
     assert got["step_runs"] == sum(a["steps"] for a in loops)
     # a window that cuts the calls short cuts their idle with them
     half = _ticks.split(calls, plane, window / 2)
-    assert 0 < half["loop_s"] < got["loop_s"]
+    assert half["launch_s"] + half["read_s"] + half["loop_s"] < \
+        got["launch_s"] + got["read_s"] + got["loop_s"]
     assert half["step_runs"] < got["step_runs"]
+
+
+def test_ticks_reader_counts_overlapped_calls_idle_twice():
+    """Two steps, the second launched before the first is read (ms): step
+    1 entry 0, enqueued 1, returned 10, runs 1.5-6; step 2 entry 2,
+    enqueued 3, returned 12, runs 8-12. The idle 6-8 lies in step 1's read
+    and in step 2's launch, and the reader counts it in both: under
+    overlap the phases bound the idle rather than cut it."""
+    from benchmark.metrics import _ticks
+
+    ms = 1e6
+    calls = [("s", 0.0, 1 * ms, 10 * ms), ("s", 2 * ms, 3 * ms, 12 * ms)]
+    runs = [(1.5 * ms, 6 * ms, "jit_decode_step(7)"),
+            (8 * ms, 12 * ms, "jit_decode_step(7)")]
+    plane = {"name": "/device:TPU:0", "modules": runs,
+             "ops": [(s, e, "%fusion.1") for s, e, _ in runs]}
+    got = _ticks.split(calls, plane, 20 * ms)
+    assert got["offset_ns"] == 0.0       # step 2's tokens back at its end
+    assert got["launch_s"] == pytest.approx(3.5e-3)    # 0-1.5, then 6-8
+    assert got["read_s"] == pytest.approx(2e-3)        # 6-8, and 12-12
+    assert got["loop_s"] == 0.0          # step 2 entered before 1 returned
+    assert got["idle_s"] == pytest.approx(11.5e-3)     # 0-1.5, 6-8, 12-20
+    named = got["launch_s"] + got["read_s"] + got["loop_s"]
+    assert named == pytest.approx(3.5e-3 + 2e-3)       # 6-8 twice
+    assert named > 1.5e-3 + 2e-3         # the idle up to the last return
+    assert got["step_runs"] == 2
 
 
 def test_ticks_reader_reads_nothing_it_cannot_line_up(stamped_run,
