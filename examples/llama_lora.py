@@ -22,7 +22,11 @@ the four latent projections; the frozen base held in bfloat16).
 two latent-attention sublayers and two dense FFNs a layer beside one
 routed layer whose softmax router chooses 4 of 16 routed and 8
 zero-computation experts, 4 routed ones held; two latent caches a layer in
-the decode).
+the decode). ``--sparse`` swaps it for the decoder with learned sparse
+attention (``DsaMoeLite``: GQA with QK-norm whose queries attend to the 8
+keys a lightning indexer chooses, beside a softmax router over 16 experts
+of which 4 are held); it has no cached decode (that needs an indexer
+cache), so the run ends after the rounds.
 """
 
 from __future__ import annotations
@@ -56,6 +60,11 @@ def main() -> int:
                              "two dense FFNs a layer beside a routed layer "
                              "with zero-computation experts, a bfloat16 "
                              "base) in LlamaLite's place")
+    parser.add_argument("--sparse", action="store_true",
+                        help="DsaMoeLite (an indexer chooses the keys each "
+                             "query attends to, routed experts of which a "
+                             "share is held, a bfloat16 base) in "
+                             "LlamaLite's place")
     parser.add_argument("--scan-chunk", type=int, default=1,
                         help="fuse this many local steps into one compiled "
                              "scan program (dispatch amortization on TPU)")
@@ -74,8 +83,9 @@ def main() -> int:
                                     FederationConfig, TerminationConfig)
     from metisfl_tpu.driver import InProcessFederation
     from metisfl_tpu.models import ArrayDataset, FlaxModelOps
-    from metisfl_tpu.models.zoo import (TRANSFORMER_RULES, JambaLite,
-                                        LlamaLite, MlaMoeLite, ScMoeLite)
+    from metisfl_tpu.models.zoo import (TRANSFORMER_RULES, DsaMoeLite,
+                                        JambaLite, LlamaLite, MlaMoeLite,
+                                        ScMoeLite)
     from metisfl_tpu.parallel.mesh import MeshConfig, build_mesh
 
     mesh = build_mesh(MeshConfig(("dp", "tp"), (args.dp, args.tp)))
@@ -122,6 +132,16 @@ def main() -> int:
                            zero_experts=8, top_k=4, experts_count=4,
                            routed_scale=6.0, lora_rank=args.lora_rank,
                            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    elif args.sparse:
+        import jax.numpy as jnp
+        module = DsaMoeLite(vocab_size=args.vocab, dim=args.dim,
+                            depth=args.depth, heads=args.heads,
+                            kv_heads=max(1, args.heads // 2),
+                            moe_hidden=args.dim // 2, num_experts=16,
+                            top_k=4, experts_count=4, index_heads=4,
+                            index_head_dim=16, index_top_k=8,
+                            lora_rank=args.lora_rank, dtype=jnp.bfloat16,
+                            param_dtype=jnp.bfloat16)
     else:
         module = LlamaLite(vocab_size=args.vocab, dim=args.dim,
                            depth=args.depth, heads=args.heads,
@@ -182,6 +202,10 @@ def main() -> int:
         final = named_tensors_to_pytree(merged, template)
     else:
         final = template
+    if args.sparse:
+        print("no greedy continuation: cached decode with a sparse indexer "
+              "needs an indexer cache")
+        return 0
     gen_ops = FlaxModelOps(module, sample, variables=final)
     prompt = np.arange(1, 9, dtype=np.int32)[None, :]
     tokens = gen_ops.generate(prompt, max_new_tokens=8)

@@ -5,7 +5,8 @@ TPU-native counterparts of the reference's example model zoo
 small federated workloads (MLP, CNNs, LSTM) plus the scale-ladder models
 from BASELINE.md (ResNet-20, ViT, BERT, Llama+LoRA) and the attention /
 state-space hybrid JambaLite (Mamba mixer + MQA attention, tied head), the
-latent-attention decoders MlaMoeLite and ScMoeLite.
+latent-attention decoders MlaMoeLite and ScMoeLite, and DsaMoeLite
+(learned sparse attention over GQA beside routed experts).
 """
 
 from metisfl_tpu.models.zoo.mlp import MLP, HousingMLP
@@ -15,6 +16,7 @@ from metisfl_tpu.models.zoo.rnn import LSTMClassifier
 from metisfl_tpu.models.zoo.transformer import (
     TRANSFORMER_RULES,
     BertLite,
+    DsaMoeLite,
     ExpertShareMLP,
     JambaLite,
     LatentAttention,
@@ -25,6 +27,7 @@ from metisfl_tpu.models.zoo.transformer import (
     MoEMLP,
     ScMoeLite,
     ShortcutMoEBlock,
+    SparseIndexer,
     ViTLite,
 )
 
@@ -33,7 +36,7 @@ __all__ = [
     "BrainAge3DCNN", "LSTMClassifier",
     "ViTLite", "BertLite", "LlamaLite", "JambaLite", "MambaMixer",
     "MlaMoeLite", "LatentAttention", "ExpertShareMLP",
-    "ScMoeLite", "ShortcutMoEBlock",
+    "ScMoeLite", "ShortcutMoEBlock", "DsaMoeLite", "SparseIndexer",
     "LoRADense", "MoEMLP",
     "TRANSFORMER_RULES",
 ]

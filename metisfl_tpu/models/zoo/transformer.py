@@ -2,7 +2,9 @@
 attention/state-space hybrid Jamba-lite, the latent-attention decoder
 with a share of its routed experts, MlaMoe-lite, and the shortcut-connected
 decoder ScMoe-lite (two latent-attention sublayers and two dense FFNs a
-layer beside one routed layer with zero-computation experts).
+layer beside one routed layer with zero-computation experts), and the
+decoder with learned sparse attention and routed experts, DsaMoe-lite
+(GQA whose queries attend to the keys a lightning indexer chooses).
 
 The BASELINE.md scale ladder (ViT-B/16 semi-sync, BERT async + secure,
 Llama-3-8B-LoRA with in-learner sharding) needs transformer workloads the
@@ -93,13 +95,20 @@ class LoRADense(nn.Module):
         return y
 
 
-def _rotary(x, positions, freqs=None):
+def _rotary(x, positions, freqs=None, sections=()):
     """Rotary position embedding over the last (head) dimension, half-split;
-    ``freqs`` (half the width) replaces the base-10000 ladder."""
+    ``freqs`` (half the width) replaces the base-10000 ladder. With
+    ``sections`` (M-RoPE: how many frequency pairs each position stream
+    turns, in order) ``positions`` is (streams, L) and pair ``i`` turns
+    with the stream its section names."""
     half = x.shape[-1] // 2
     if freqs is None:
         freqs = 1.0 / (10000 ** (np.arange(0, half) / half))
-    angles = positions[..., None] * freqs  # (..., L, half)
+    if sections:
+        stream = np.repeat(np.arange(len(sections)), sections)
+        angles = jnp.swapaxes(positions[stream], 0, 1) * freqs  # (L, half)
+    else:
+        angles = positions[..., None] * freqs  # (..., L, half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
@@ -142,11 +151,40 @@ class Attention(nn.Module):
     # the dense path broadcasts K/V across each group's query heads at
     # compute time. 0 → kv_heads = heads (plain MHA); 1 = MQA.
     kv_heads: int = 0
+    head_dim: int = 0           # 0 = dim // heads; q is heads x head_dim
+    rope_base: float = 10000.0
+    # M-RoPE: the frequency pairs each position stream (temporal, height,
+    # width) turns; text positions make the streams equal
+    rope_sections: tuple = ()
+    qk_norm: bool = False       # per-head RMSNorm on q and k before rotary
+    eps: float = 1e-6
+    # a SparseIndexer (unbound; adopted as "indexer"): each query attends
+    # to the keys it chooses
+    indexer: Any = None
+    param_dtype: Any = jnp.float32
+
+    def _rotate(self, x, positions):
+        """Rotary at this attention's base and sections, in ``x``'s type."""
+        if self.rope_sections:
+            positions = jnp.broadcast_to(
+                positions, (len(self.rope_sections),) + positions.shape)
+        half = x.shape[-1] // 2
+        freqs = 1.0 / (self.rope_base ** (np.arange(0, half) / half))
+        return _rotary(x, positions, freqs,
+                       self.rope_sections).astype(x.dtype)
+
+    def _kernels(self) -> bool:
+        """Whether the indexer's choice and the attention over it take the
+        Pallas kernels: ``"auto"`` takes them on a TPU."""
+        if self.use_flash == "auto":
+            return jax.default_backend() == "tpu"
+        return bool(self.use_flash)
 
     @nn.compact
     def __call__(self, x, train: bool = False, cache=None, position=None):
         B, L, _ = x.shape
-        head_dim = self.dim // self.heads
+        head_dim = self.head_dim or self.dim // self.heads
+        width = self.heads * head_dim
         kv_heads = self.kv_heads or self.heads
         if kv_heads <= 0 or self.heads % kv_heads:
             raise ValueError(
@@ -161,26 +199,48 @@ class Attention(nn.Module):
 
         def proj(name, features, rank=0):
             return LoRADense(features, rank=rank, use_bias=False,
-                             dtype=self.dtype, name=name)
+                             dtype=self.dtype, param_dtype=self.param_dtype,
+                             name=name)
 
+        def out_proj(out):
+            out = out.transpose(0, 2, 1, 3).reshape(B, L, width)
+            return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name="wo")(out)
+
+        words = None
+        if self.indexer is not None:
+            if cache is not None:
+                raise ValueError(
+                    "cached decode with a sparse indexer needs an indexer "
+                    "cache (the indexer's keys of past positions), which "
+                    "this attention does not keep")
+            words = self.indexer(x, kernel=self._kernels())
         # LoRA on q/v only (standard practice)
-        q = proj("wq", self.dim, self.lora_rank)(x)
+        q = proj("wq", width, self.lora_rank)(x)
         k = proj("wk", kv_heads * head_dim)(x)
         v = proj("wv", kv_heads * head_dim, self.lora_rank)(x)
         q = q.reshape(B, L, self.heads, head_dim).transpose(0, 2, 1, 3)
         k = k.reshape(B, L, kv_heads, head_dim).transpose(0, 2, 1, 3)
         v = v.reshape(B, L, kv_heads, head_dim).transpose(0, 2, 1, 3)
+        if self.qk_norm:
+            norm = functools.partial(nn.RMSNorm, epsilon=self.eps,
+                                     dtype=self.dtype)
+            q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
         if cache is not None:
             out, cache = self._cached_attention(q, k, v, cache, position,
                                                 head_dim)
-            out = out.transpose(0, 2, 1, 3).reshape(B, L, self.dim)
-            return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
-                            name="wo")(out), cache
+            return out_proj(out), cache
         if self.rotary:
             positions = jnp.arange(L, dtype=jnp.float32)
-            dt = q.dtype
-            q = _rotary(q, positions).astype(dt)
-            k = _rotary(k, positions).astype(dt)
+            q = self._rotate(q, positions)
+            k = self._rotate(k, positions)
+        if words is not None:
+            from metisfl_tpu.ops import sparse_attention as sa
+            with jax.named_scope("attn"):
+                out = (sa.selected_attention(q, k, v, words)
+                       if self._kernels()
+                       else sa.attend_plain(q, k, v, words))
+            return out_proj(out)
         if (kv_heads != self.heads and self.sp_mesh is None
                 and not self.use_flash):
             # dense path only: broadcast each KV group across its query
@@ -241,9 +301,7 @@ class Attention(nn.Module):
             weights = nn.Dropout(self.dropout,
                                  deterministic=not train)(weights)
             out = jnp.einsum("bhqk,bhkd->bhqd", weights, v)
-        out = out.transpose(0, 2, 1, 3).reshape(B, L, self.dim)
-        return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
-                        name="wo")(out)
+        return out_proj(out)
 
     def _cached_attention(self, q, k, v, cache, position, head_dim):
         """Incremental attention against a KV cache (autoregressive decode).
@@ -265,9 +323,8 @@ class Attention(nn.Module):
         pos0 = jnp.asarray(position, jnp.int32)
         if self.rotary:
             positions = (pos0 + jnp.arange(L)).astype(jnp.float32)
-            dt = q.dtype
-            q = _rotary(q, positions).astype(dt)
-            k = _rotary(k, positions).astype(dt)
+            q = self._rotate(q, positions)
+            k = self._rotate(k, positions)
         # ``_write_kv`` (at the end of this file, so that no line above it
         # moves): this dynamic_update_slice, and under ``vmap`` over decode
         # slots one such write a slot, in place
@@ -682,6 +739,67 @@ class ExpertShareMLP(nn.Module):
         return out.reshape(B, L, D)
 
 
+class SparseIndexer(nn.Module):
+    """The lightning indexer of DeepSeek-V3.2's sparse attention, on the
+    block's normed input ``h`` (there is no query latent)::
+
+        q_j = rotary(W_q,j h_t)                j = 1 .. heads, head_dim wide
+        k   = rotary(LayerNorm(W_k h_s))       one head, shared by all
+        w_j = (W_w h_t)_j * heads^-0.5 * head_dim^-0.5
+        I[t, s] = sum_j w_j relu(q_j . k),     s <= t
+
+    Query ``t`` attends to the ``top_k`` keys of largest ``I[t, s]`` (every
+    causal key while ``t < top_k``); one choice serves every attention
+    head. The products take operands in ``dtype`` and accumulate in
+    float32; the choice is made in float32, exactly, without a sort
+    (:mod:`metisfl_tpu.ops.sparse_attention`). The indexer is frozen (no
+    adapter) and the choice carries no gradient.
+
+    Returns the choice bit-packed, ``(B, L / 32, L)`` int32, under the
+    checkpoint name ``dsa_mask`` (a remat policy that saves it keeps the
+    backward from choosing again), and sows under ``intermediates``
+    ``dsa_selected_count`` (chosen pairs) and ``dsa_live_tiles_count``
+    ((query block, key block) pairs that hold one)."""
+
+    heads: int = 16
+    head_dim: int = 64
+    top_k: int = 2048
+    rope_base: float = 10000.0
+    eps: float = 1e-6           # the key's LayerNorm
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, kernel: bool = False):
+        from jax.ad_checkpoint import checkpoint_name
+
+        from metisfl_tpu.ops import sparse_attention as sa
+        B, L, _ = h.shape
+        H, D = self.heads, self.head_dim
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                                  param_dtype=self.param_dtype)
+        dt = self.dtype or jnp.float32
+        with jax.named_scope("dsa_indexer"):
+            q = dense(H * D, name="wq")(h).reshape(B, L, H, D)
+            k = nn.LayerNorm(epsilon=self.eps, dtype=jnp.float32,
+                             name="k_norm")(dense(D, name="wk")(h))
+            w = dense(H, name="weights_proj")(h).astype(jnp.float32) * (
+                H ** -0.5 * D ** -0.5)
+            positions = jnp.arange(L, dtype=jnp.float32)
+            freqs = 1.0 / (self.rope_base ** (np.arange(0, D // 2) / (D // 2)))
+            q = jnp.swapaxes(_rotary(jnp.swapaxes(q, 1, 2), positions, freqs),
+                             1, 2).astype(dt)
+            k = _rotary(k, positions, freqs).astype(dt)
+        q, k, w = (jax.lax.stop_gradient(t) for t in (q, k, w))
+        with jax.named_scope("dsa_select"):
+            words = (sa.select(q, k, w, self.top_k) if kernel
+                     else sa.select_plain(q, k, w, self.top_k))
+            words = checkpoint_name(words, "dsa_mask")
+        for name, value in sa.counts(words, sa.block_edge(L)).items():
+            self.sow("intermediates", name, value)
+        return words
+
+
 class GeluMLP(nn.Module):
     dim: int
     hidden: int
@@ -868,6 +986,8 @@ class DecoderBlock(nn.Module):
     # and an FFN module in SwiGLU's or MoEMLP's ("ffn")
     mla: Any = None
     ffn: Any = None
+    # an Attention (unbound) in the attention mixer's place ("attention")
+    attention: Any = None
 
     @nn.compact
     def __call__(self, x, train: bool = False, cache=None, position=None):
@@ -880,6 +1000,11 @@ class DecoderBlock(nn.Module):
             with jax.named_scope("attention_mixer"):
                 a = (self.mla(normed) if cache is None
                      else self.mla(normed, cache=cache, position=position))
+        elif self.attention is not None:
+            with jax.named_scope("attention_mixer"):
+                a = (self.attention(normed, train=train) if cache is None
+                     else self.attention(normed, train=train, cache=cache,
+                                         position=position))
         else:
             attn = Attention(self.dim, self.heads, causal=True,
                              rotary=self.rotary,
@@ -1405,6 +1530,98 @@ class ScMoeLite(nn.Module):
 
     def cache_kinds(self):
         return ("kv",) * self.depth
+
+
+class DsaMoeLite(nn.Module):
+    """Decoder-only causal LM with learned sparse attention and routed
+    experts (the Qwen3-MoE layer under DeepSeek-V3.2's indexer): every
+    block is pre-RMSNorm GQA (``heads`` query heads of ``head_dim`` over
+    ``kv_heads``, a per-head RMSNorm on q and k, rotary at ``rope_base``
+    over M-RoPE's ``rope_sections`` with text positions) whose queries
+    attend to the ``index_top_k`` keys a :class:`SparseIndexer` chooses,
+    then an :class:`ExpertShareMLP` holding ``experts_count`` of the
+    router's ``num_experts`` (softmax scores, the chosen ``top_k`` gates
+    normalised to sum to 1, no shared expert); an untied head with
+    float32 logits. ``lora_rank > 0`` adds adapters on ``wq`` and ``wv``;
+    train with ``FlaxModelOps(trainable_regex="lora_")`` to freeze the
+    base. ``param_dtype`` is the type of the frozen matrices and the
+    embedding (norm scales, the router, the head and the adapters stay
+    float32). Under ``remat`` a block saves its choice (``dsa_mask``) and
+    recomputes the rest.
+
+    ``use_flash`` (or ``"auto"`` on a TPU) runs the choice
+    and the attention in the Pallas kernels
+    (:mod:`metisfl_tpu.ops.sparse_attention`), else in plain JAX. Cached
+    decode is not supported: it needs an indexer cache."""
+
+    vocab_size: int = 8192
+    dim: int = 64
+    depth: int = 2
+    heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 0           # 0 = dim // heads
+    moe_hidden: int = 32
+    num_experts: int = 16
+    top_k: int = 4
+    experts_first: int = 0
+    experts_count: int = 0      # 0 = all of them
+    index_heads: int = 4
+    index_head_dim: int = 16
+    index_top_k: int = 16
+    rope_base: float = 10000.0
+    rope_sections: tuple = ()
+    eps: float = 1e-6
+    lora_rank: int = 0
+    use_flash: Any = False
+    remat: bool = False
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+    gmm_interpret: Any = None       # see ExpertShareMLP
+
+    def _attention(self) -> Attention:
+        indexer = SparseIndexer(
+            self.index_heads, self.index_head_dim, self.index_top_k,
+            rope_base=self.rope_base, eps=self.eps, dtype=self.dtype,
+            param_dtype=self.param_dtype, parent=None)
+        return Attention(
+            self.dim, self.heads, causal=True, rotary=True,
+            lora_rank=self.lora_rank, use_flash=self.use_flash,
+            dtype=self.dtype, kv_heads=self.kv_heads, head_dim=self.head_dim,
+            rope_base=self.rope_base, rope_sections=tuple(self.rope_sections),
+            qk_norm=True, eps=self.eps, indexer=indexer,
+            param_dtype=self.param_dtype, parent=None)  # the block adopts it
+
+    def _moe(self) -> ExpertShareMLP:
+        return ExpertShareMLP(
+            self.dim, self.moe_hidden, self.num_experts, self.top_k,
+            first=self.experts_first, count=self.experts_count,
+            score_func="softmax", norm_topk=True, dtype=self.dtype,
+            param_dtype=self.param_dtype, gmm_interpret=self.gmm_interpret,
+            parent=None)
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, caches=None,
+                 position=None):
+        if caches is not None:
+            self.init_cache(tokens.shape[0], tokens.shape[1])
+        x = nn.Embed(self.vocab_size, self.dim, dtype=self.dtype,
+                     param_dtype=self.param_dtype, name="embed")(tokens)
+        block_cls = (nn.remat(DecoderBlock, static_argnums=(2,),
+                              policy=jax.checkpoint_policies
+                              .save_only_these_names("dsa_mask"))
+                     if self.remat else DecoderBlock)
+        for i in range(self.depth):
+            x = block_cls(self.dim, self.heads, dtype=self.dtype,
+                          eps=self.eps, attention=self._attention(),
+                          ffn=self._moe(), name=f"block_{i}")(x, train)
+        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype)(x)
+        return nn.Dense(self.vocab_size, use_bias=False,
+                        name="lm_head")(x.astype(jnp.float32))
+
+    def init_cache(self, batch: int, max_len: int):
+        raise ValueError(
+            "DsaMoeLite has no cached decode: it needs an indexer cache "
+            "(the indexer's keys of past positions) beside the KV cache")
 
 
 @jax.custom_batching.custom_vmap

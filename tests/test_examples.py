@@ -113,6 +113,23 @@ def test_llama_lora_example_with_the_shortcut_decoder():
     assert "greedy continuation" in proc.stdout
 
 
+def test_llama_lora_example_with_the_sparse_attention_decoder():
+    """``--sparse``: the decoder with learned sparse attention (an indexer
+    chooses the keys, routed experts of which a share is held) through the
+    same federation and shipped subset; it has no cached decode."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=2"}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "examples", "llama_lora.py"),
+         "--sparse", "--learners", "2", "--rounds", "1", "--dp", "2",
+         "--tp", "1", "--lora-rank", "4"],
+        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert "completed 1 rounds" in proc.stdout
+    assert "B of adapters" in proc.stdout
+    assert "needs an indexer cache" in proc.stdout
+
+
 def test_ladder_rungs_execute(tmp_path):
     """BASELINE.md config ladder (VERDICT r3 #2): each rung's protocol x
     model combination actually executes and records round wall-clock. The

@@ -29,6 +29,8 @@ TRACE_ONLY = {
     "moe_experts_share",
     "scmoe_step_mfu", "scmoe_flash_roofline", "scmoe_experts_roofline",
     "scmoe_experts_share",
+    "dsa_step_mfu", "dsa_flash_roofline", "dsa_index_roofline",
+    "dsa_index_share", "dsa_experts_roofline",
     "device_idle_share.train", "device_idle_share.serve",
     "decode_gap_read_ms", "decode_gap_loop_ms", "decode_gap_launch_ms",
     "decode_gap_named_share"}
@@ -86,11 +88,11 @@ def _lm(lora_rank: int):
                      lora_rank=lora_rank, dtype=jnp.float32)
 
 
-def _round_ctx(regex: str, module=None, cfg=None) -> dict:
+def _round_ctx(regex: str, module=None, cfg=None, seq: int = 8) -> dict:
     """Three rounds of one learner, the rounds after the first as the
     window; ``regex`` is both what trains and what ships. ``module`` (32
-    token ids) and its configuration ``cfg`` stand in for the toy decoder
-    and its family."""
+    token ids, rows of ``seq``) and its configuration ``cfg`` stand in for
+    the toy decoder and its family."""
     from metisfl_tpu.comm.messages import TrainParams
     from metisfl_tpu.config import (EvalConfig, FederationConfig,
                                     TerminationConfig)
@@ -98,7 +100,7 @@ def _round_ctx(regex: str, module=None, cfg=None) -> dict:
     from metisfl_tpu.models import ArrayDataset, FlaxModelOps
 
     rng = np.random.default_rng(30)
-    tokens = rng.integers(0, 32, (16, 9)).astype(np.int32)
+    tokens = rng.integers(0, 32, (16, seq + 1)).astype(np.int32)
     x, y = tokens[:, :-1], tokens[:, 1:]
     config = FederationConfig(
         train=TrainParams(batch_size=2, local_steps=LOCAL_STEPS,
@@ -156,6 +158,19 @@ def scmoe():
     profile carries the routed layers' counters."""
     cfg = {**spec.cell(SCMOE_CELL, rehearse=True)["cfg"], "vocab_size": 32}
     return _round_ctx("lora_", spec.binding(cfg).build_module(cfg), cfg)
+
+
+DSA_CELL = "keye-vl-2.0-30b-a3b.lora-round-16k"
+
+
+@pytest.fixture(scope="module")
+def dsa():
+    """The LoRA path with the sparse-attention decoder at the cell's toy
+    widths, rows of 32 tokens (the choice packs 32 query rows a word), so
+    that the round's profile carries the choice's counters."""
+    cfg = {**spec.cell(DSA_CELL, rehearse=True)["cfg"], "vocab_size": 32}
+    return _round_ctx("lora_", spec.binding(cfg).build_module(cfg), cfg,
+                      seq=32)
 
 
 @pytest.fixture(scope="module")
@@ -278,6 +293,43 @@ def test_counter_readers_read_the_counters_of_a_real_round(scmoe):
             assert spec.metric_reader(name).read(scmoe) is None, name
         except spec.UnknownDevice:
             assert name == "scmoe_step_mfu"
+
+
+def test_dsa_readers_read_the_counters_of_a_real_round(dsa):
+    """``dsa_selected_count``, ``dsa_live_tiles_count`` and
+    ``moe_local_count`` ride ``RoundProfile.learners[*].device`` of a real
+    round; given what a chip run alone has beside them, each of the
+    family's readers and the experts' share reads a number, and without it
+    nothing."""
+    from benchmark.lib import flops_dsa_moe
+    from benchmark.metrics import _scmoe
+    shape, cfg = dsa["traffic"]["shape"], dsa["cfg"]
+    layers = cfg["num_hidden_layers"]
+    pairs = shape["batch"] * layers * flops_dsa_moe.selected_pairs(
+        shape["seq"], cfg["sa_config"]["topk"])
+    selected = _scmoe.device_count(dsa, "dsa_selected_count")
+    assert pairs <= selected <= 1.05 * pairs
+    assert _scmoe.device_count(dsa, "dsa_live_tiles_count") == \
+        shape["batch"] * layers
+    assert 0 < _scmoe.device_count(dsa, "moe_local_count")
+    traced = {**dsa, "device_kind": "TPU v5 lite", "trace": {
+        "busy_s": 0.9, "window_s": 1.0,
+        "module_runs": {"jit_train_scan_steps": 1.0},
+        "kernel_ops_s": {"flash_fwd_sel": 0.1, "flash_bwd_dq_sel": 0.1,
+                         "flash_bwd_dkv_sel": 0.1, "dsa_select": 0.05,
+                         "moe_gmm_fwd": 0.02, "moe_gmm_bwd": 0.03},
+        "ops_s": {"fusion": 0.6}}}
+    for name in ("dsa_step_mfu", "dsa_flash_roofline", "dsa_index_roofline",
+                 "dsa_index_share", "dsa_experts_roofline",
+                 "moe_experts_share"):
+        assert DECLARED[name] == "round"
+        value = spec.metric_reader(name).read(traced)
+        assert isinstance(value, float) and math.isfinite(value), name
+        assert value > 0, name
+        try:
+            assert spec.metric_reader(name).read(dsa) is None, name
+        except spec.UnknownDevice:
+            assert name == "dsa_step_mfu"
 
 
 def test_the_readers_left_out_are_the_trace_readers_by_name(lora, serve):
