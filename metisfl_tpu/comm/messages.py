@@ -232,7 +232,9 @@ class TrainTask(Message):
     round_id: int = 0
     global_iteration: int = 0
     model: bytes = b""          # ModelBlob wire bytes (community model)
-    _VIEW_FIELDS = ("model",)   # read by _load_model, then let go
+    # read by _load_model; under a ``model_ref`` also held by the learner
+    # until a newer TrainTask's bytes replace it (Learner.run_task)
+    _VIEW_FIELDS = ("model",)
     params: TrainParams = field(default_factory=TrainParams)
     # SCAFFOLD (aggregation.rule='scaffold'): ``scaffold`` marks the task
     # as control-variate-corrected (the learner must report a delta even
@@ -244,6 +246,10 @@ class TrainTask(Message):
     # mismatch against the epoch the learner joined under triggers
     # learner-side re-attach before the task runs
     controller_epoch: str = ""
+    # names the ``model`` bytes this task carries, so that an EvalTask of
+    # the same blob can name them instead of carrying them again ("" = the
+    # bytes are not named: the learner holds nothing for a later EvalTask)
+    model_ref: str = ""
 
 
 @dataclass
@@ -316,6 +322,34 @@ class EvalTask(Message):
     ship_tensor_regex: str = ""
     # controller incarnation id (see JoinReply.controller_epoch)
     controller_epoch: str = ""
+    # with an empty ``model``: the ``model_ref`` of the TrainTask whose
+    # bytes to evaluate, which the learner resolves or refuses with
+    # ModelRefMissing ("" = evaluate ``model``)
+    model_ref: str = ""
+
+
+class ModelRefMissing(LookupError):
+    """The learner does not hold the bytes an EvalTask's ``model_ref``
+    names: an earlier evaluation took them, a newer TrainTask's replaced
+    them, or they did not arrive within the learner's bound. Crosses gRPC as FAILED_PRECONDITION with
+    :data:`MARK` in its details; the sender answers it by sending the
+    evaluation again with its bytes."""
+
+    MARK = "model_ref missing"
+
+    def __init__(self, ref: str, why: str):
+        super().__init__(f"{self.MARK}: {ref!r} {why}")
+
+    def code(self):
+        # RpcError-shaped, so BytesService aborts with this status
+        import grpc
+        return grpc.StatusCode.FAILED_PRECONDITION
+
+    @classmethod
+    def raised(cls, exc: BaseException) -> bool:
+        """Whether an RPC failure is this error, answered by a learner."""
+        details = getattr(exc, "details", None)
+        return callable(details) and cls.MARK in (details() or "")
 
 
 @dataclass

@@ -158,8 +158,10 @@ class LearnerProxy(Protocol):
         """Fire-and-forget local-training dispatch."""
         ...
 
-    def evaluate(self, task: EvalTask, callback: Callable[[EvalResult], None]) -> None:
-        """Non-blocking evaluation; ``callback`` runs on completion."""
+    def evaluate(self, task: EvalTask,
+                 callback: Callable[[EvalResult], None]) -> Optional[int]:
+        """Non-blocking evaluation; ``callback`` runs on completion. May
+        return the model bytes it sent (None: ``task.model`` whole)."""
         ...
 
     def shutdown(self) -> None:
@@ -193,6 +195,18 @@ class LearnerRecord:
     ewma_train_s: float = 0.0
     ewma_eval_s: float = 0.0
     proxy: Optional[LearnerProxy] = None
+
+
+@dataclass
+class _RoundEvaluation:
+    """A round's community evaluation, bound when the round closes and
+    sent after the next dispatch (Controller._send_eval_tasks)."""
+
+    blob: bytes
+    iteration: int
+    meta: RoundMetadata
+    entry: Dict[str, Any]
+    round_span: Any
 
 
 @dataclass
@@ -1717,13 +1731,16 @@ class Controller:
                       selected=len(selected), duration_ms=round(agg_ms, 3))
         # round close: everything between the aggregate landing and the
         # round counter advancing (health fold, version registration,
-        # eval dispatch, lineage bookkeeping) — the last measured phase
+        # binding the evaluation, lineage bookkeeping) — the last measured phase
         # of the cost-profile waterfall, and a real span in the trace
         close_sp = _ttrace.span("round.close", parent=self._round_span)
         self._fold_round_health()
         self._register_round_version()
         self._note_round_telemetry()
-        self._send_eval_tasks()
+        # bound to this round here, sent after the next dispatch: the
+        # RunTask that carries the same blob goes first, and the
+        # evaluation can name it instead of streaming it again
+        evaluation = self._round_evaluation()
         close_ms = close_sp.end()
         _M_PHASE.observe(close_ms / 1e3, phase="close")
         profile_record = None
@@ -1759,25 +1776,34 @@ class Controller:
             self._profile.persist(profile_record)
         _M_ROUND_DURATION.observe(round_wall_s)
         _M_ROUNDS.inc()
-        ckpt = self.config.checkpoint
-        if ckpt.dir and self.global_iteration % max(1, ckpt.every_n_rounds) == 0:
-            try:
-                self.save_checkpoint()
-            except Exception:
-                logger.exception("checkpoint save failed")
-        self._maybe_recompute_semisync()
-        if self._shutdown.is_set():
-            return
-        if self._scheduler.name.startswith("asynchronous"):
-            # async: re-dispatch only the reporting learner(s). Buffered
-            # async re-dispatched most reporters the moment they uplinked
-            # (redispatch_on_completion) — only the fill-triggering
-            # reporter is still idle here, so filter out the busy ones
-            # (plain async cohorts are never in flight at this point).
-            next_ids = self._idle_reporters(cohort)
-        else:
-            next_ids = self._sample_cohort()
-        self._dispatch_train(next_ids)
+        try:
+            ckpt = self.config.checkpoint
+            if (ckpt.dir and self.global_iteration
+                    % max(1, ckpt.every_n_rounds) == 0):
+                try:
+                    self.save_checkpoint()
+                except Exception:
+                    logger.exception("checkpoint save failed")
+            self._maybe_recompute_semisync()
+            if self._shutdown.is_set():
+                return
+            if self._scheduler.name.startswith("asynchronous"):
+                # async: re-dispatch only the reporting learner(s).
+                # Buffered async re-dispatched most reporters the moment
+                # they uplinked (redispatch_on_completion) — only the
+                # fill-triggering reporter is still idle here, so filter
+                # out the busy ones (plain async cohorts are never in
+                # flight at this point).
+                next_ids = self._idle_reporters(cohort)
+            else:
+                next_ids = self._sample_cohort()
+            self._dispatch_train(next_ids)
+        finally:
+            # after the dispatch, or in its place (shutdown, a raise):
+            # the round's evaluation is sent either way, with its bytes
+            # where no RunTask just carried them
+            if evaluation is not None:
+                self._send_eval_tasks(evaluation)
 
     def _note_round_telemetry(self) -> None:
         """Round-close hook for the telemetry-at-scale plane: one
@@ -2688,31 +2714,46 @@ class Controller:
                     attempt, failed_id, replacement)
         self._dispatch_train([replacement], restart_deadline=False)
 
-    def _send_eval_tasks(self) -> None:
-        """SendEvaluationTasks (controller.cc:571-647) + digest callback."""
+    def _round_evaluation(self) -> Optional[_RoundEvaluation]:
+        """The closing round's evaluation, when one is due, bound to that
+        round: its community blob, iteration, RoundMetadata and round
+        span, and its ``community_evaluations`` entry (appended now, so a
+        checkpoint taken before the send holds it)."""
         cfg = self.config.eval
         if cfg.every_n_rounds <= 0:
-            return
+            return None
         if (self.global_iteration + 1) % cfg.every_n_rounds != 0:
-            return
+            return None
         blob = self._dispatch_blob()
+        if blob is None:
+            return None
         with self._lock:
-            learners = list(self._learners.values())
-            iteration = self.global_iteration
+            entry: Dict[str, Any] = {"global_iteration": self.global_iteration,
+                                     "evaluations": {}}
+            self.community_evaluations.append(entry)
             # bind eval timestamps to the SUBMITTING round's metadata — the
-            # digest callback may fire after _complete_round swapped
+            # digest callback fires after _complete_round swapped
             # _current_meta, and the received_at must land in the same round
             # record as its submitted_at (the reference keeps this lineage
             # clean, controller.cc:582-586, :673-675)
-            meta = self._current_meta
-        if blob is None:
-            return
-        entry: Dict[str, Any] = {"global_iteration": iteration, "evaluations": {}}
+            return _RoundEvaluation(blob=blob, iteration=self.global_iteration,
+                                    meta=self._current_meta, entry=entry,
+                                    round_span=self._round_span)
+
+    def _send_eval_tasks(self, evaluation: _RoundEvaluation) -> None:
+        """SendEvaluationTasks (controller.cc:571-647) + digest callback,
+        for the round ``evaluation`` was bound to. A proxy that just sent
+        the same blob in a RunTask sends the evaluation by reference and
+        says so by the bytes it reports (``RpcLearnerProxy.evaluate``)."""
+        cfg = self.config.eval
+        blob, iteration = evaluation.blob, evaluation.iteration
+        meta, entry = evaluation.meta, evaluation.entry
         with self._lock:
-            self.community_evaluations.append(entry)
+            learners = list(self._learners.values())
         eval_sp = _ttrace.span("round.eval_dispatch",
-                               parent=self._round_span,
-                               attrs={"learners": len(learners)})
+                               parent=evaluation.round_span,
+                               attrs={"learners": len(learners),
+                                      "round": iteration})
         for record in learners:
             task = EvalTask(
                 task_id=uuid.uuid4().hex,
@@ -2748,18 +2789,20 @@ class Controller:
 
             try:
                 with eval_sp.activate():
-                    record.proxy.evaluate(task, _digest)
+                    sent = record.proxy.evaluate(task, _digest)
                 if self._profile is not None:
                     # eval broadcasts are downlink wire bytes too — under
                     # the lock with a membership re-check (same posture
                     # as _M_UPLINK): leave() prunes the series strictly
                     # after deleting the record, so attributing only to
                     # a still-registered learner cannot resurrect a
-                    # pruned series
+                    # pruned series. A proxy that does not say what it
+                    # sent passed the blob whole.
                     with self._lock:
                         if record.learner_id in self._learners:
                             self._profile.note_downlink(
-                                record.learner_id, len(blob))
+                                record.learner_id,
+                                len(blob) if sent is None else sent)
             except Exception:
                 logger.exception("eval dispatch to %s failed", record.learner_id)
         eval_sp.end()

@@ -8,11 +8,14 @@ completed, replace/get community model, statistics lineage, health, shutdown.
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import threading
 import time
+import uuid
 from typing import Callable, Optional
 
+from metisfl_tpu import telemetry as _tel
 from metisfl_tpu.comm import codec as _codec
 from metisfl_tpu.comm.codec import dumps, loads
 from metisfl_tpu.comm.messages import (
@@ -20,15 +23,28 @@ from metisfl_tpu.comm.messages import (
     EvalTask,
     JoinReply,
     JoinRequest,
+    ModelRefMissing,
     TaskResult,
     TrainTask,
 )
 from metisfl_tpu.comm.rpc import (BytesService, RpcClient, RpcServer,
                                   StopOnce)
 from metisfl_tpu.controller.core import Controller, LearnerRecord
+from metisfl_tpu.telemetry import metrics as _tmetrics
 from metisfl_tpu.telemetry import profile as _tprofile
+from metisfl_tpu.telemetry import trace as _trace
 
 logger = logging.getLogger("metisfl_tpu.controller.service")
+
+_REG = _tmetrics.registry()
+_M_EVAL_REF = _REG.counter(
+    _tel.M_EVAL_MODEL_REF_TOTAL,
+    "Evaluations that named the blob the learner's last RunTask carried, "
+    "by outcome (sent | resent: the learner missed it, the bytes went)",
+    ("outcome",))
+_M_EVAL_REF_SAVED = _REG.counter(
+    _tel.M_EVAL_MODEL_REF_SAVED_BYTES_TOTAL,
+    "Model bytes that evaluations answered by reference did not send")
 
 CONTROLLER_SERVICE = "metisfl_tpu.Controller"
 LEARNER_SERVICE = "metisfl_tpu.Learner"
@@ -62,6 +78,10 @@ class RpcLearnerProxy:
                                  ssl=ssl,
                                  peer=record.learner_id if profiled else "",
                                  **_comm_kwargs(comm))
+        # (blob, model_ref) of the last RunTask on this channel: an
+        # evaluation of that very object names it (evaluate)
+        self._sent_lock = threading.Lock()
+        self._sent: Optional[tuple] = None
 
     @staticmethod
     def _to_wire_attributed(task):
@@ -80,17 +100,81 @@ class RpcLearnerProxy:
 
     def run_task_with_callback(self, task: TrainTask, on_error) -> None:
         """Dispatch + failure notification: feeds the controller's learner
-        liveness tracking (consecutive failed dispatches)."""
+        liveness tracking (consecutive failed dispatches). The task names
+        its blob (``model_ref``), and the proxy remembers which object it
+        was until the call fails."""
+        ref = uuid.uuid4().hex
+        task = dataclasses.replace(task, model_ref=ref)
         payload = self._to_wire_attributed(task)
+
+        def _failed(exc: Exception) -> None:
+            self._forget(ref)
+            on_error(exc)
+
+        with self._sent_lock:
+            self._sent = (task.model, ref)
         # RunTask acks immediately (non-blocking learner dispatch):
         # wait_ready=False surfaces UNAVAILABLE from a dead endpoint at once
         # (liveness counts in seconds, not 60 s deadlines), and the timeout
         # bounds a connected-but-unresponsive peer.
-        self._client.call_async("RunTask", payload,
-                                error_callback=on_error, timeout=60.0,
-                                wait_ready=False)
+        try:
+            self._client.call_async("RunTask", payload,
+                                    error_callback=_failed, timeout=60.0,
+                                    wait_ready=False)
+        except Exception:
+            self._forget(ref)
+            raise
 
-    def evaluate(self, task: EvalTask, callback: Callable[[EvalResult], None]) -> None:
+    def _forget(self, ref: str) -> None:
+        with self._sent_lock:
+            if self._sent is not None and self._sent[1] == ref:
+                self._sent = None
+
+    def evaluate(self, task: EvalTask,
+                 callback: Callable[[EvalResult], None]) -> int:
+        """Returns the model bytes put on the wire: 0 where the last
+        RunTask carried ``task.model`` (the very object) and the task
+        names it instead. A learner that no longer holds it answers
+        :class:`ModelRefMissing`, and the evaluation goes once more with
+        its bytes."""
+        with self._sent_lock:
+            sent = self._sent
+            named = sent is not None and task.model is sent[0]
+            if named:
+                self._sent = None       # one evaluation per RunTask
+        if not named:
+            self._evaluate(task, callback)
+            return len(task.model)
+        nbytes = len(task.model)
+        ctx = _trace.current_context()
+
+        def _answered(raw: bytes) -> None:
+            _M_EVAL_REF_SAVED.inc(nbytes)
+            callback(EvalResult.from_wire(raw))
+
+        def _failed(exc: Exception) -> None:
+            if not ModelRefMissing.raised(exc):
+                logger.warning("evaluation by reference on %s failed: %s",
+                               self._client.target, exc)
+                return
+            _M_EVAL_REF.inc(outcome="resent")
+            collector = _tprofile.collector()
+            if self._client.peer and collector is not None:
+                # the bytes cross after all (peer attribution is cleared
+                # on leave, as for the transport's own series)
+                collector.note_downlink(self._client.peer, nbytes)
+            with _trace.use_context(ctx):
+                self._evaluate(task, callback)
+
+        _M_EVAL_REF.inc(outcome="sent")
+        by_ref = dataclasses.replace(task, model=b"", model_ref=sent[1])
+        self._client.call_async("EvaluateModel",
+                                self._to_wire_attributed(by_ref),
+                                callback=_answered, error_callback=_failed)
+        return 0
+
+    def _evaluate(self, task: EvalTask,
+                  callback: Callable[[EvalResult], None]) -> None:
         self._client.call_async(
             "EvaluateModel", self._to_wire_attributed(task),
             callback=lambda raw: callback(EvalResult.from_wire(raw)))

@@ -28,6 +28,7 @@ cancel-on-new-task, run evaluations, ship results back. Redesigned:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import logging
 import re
@@ -45,6 +46,7 @@ from metisfl_tpu.comm.messages import (
     InferTask,
     JoinReply,
     JoinRequest,
+    ModelRefMissing,
     TaskResult,
     TrainTask,
 )
@@ -84,6 +86,68 @@ _M_MASK_GEN = _REG.histogram(
     _tel.M_SECURE_MASK_GEN_SECONDS,
     "Secure-uplink encode time per train task: fixed-point encoding + "
     "pairwise mask stream generation (secure/distributed.py)")
+_M_MODEL_REF = _REG.counter(
+    _tel.M_LEARNER_MODEL_REF_TOTAL,
+    "Evaluations that named a train task's model by reference, by outcome "
+    "(resolved | missed)", ("outcome",))
+_M_MODEL_REF_WAIT = _REG.histogram(
+    _tel.M_LEARNER_MODEL_REF_WAIT_SECONDS,
+    "Time a resolved reference waited for its train task's bytes")
+
+# how long an evaluation that names a model waits for the TrainTask that
+# carries it (the two share one channel, and the eval's request is tiny):
+# the controller gives that RunTask 60 s before it counts it as failed
+MODEL_REF_WAIT_S = 60.0
+
+
+class ModelRefHolder:
+    """The last model bytes a TrainTask named (``TrainTask.model_ref``),
+    for the one EvalTask that names them in place of carrying them. One
+    entry, taken by the evaluation that resolves it, so the bytes live no
+    longer than the two tasks that read them; a newer blob replaces it.
+    A reference to bytes taken or replaced misses at once; one not yet
+    published waits up to a bound."""
+
+    # refs published before: a waiter for one of these (not the current)
+    # knows its bytes are gone and will never arrive
+    _HISTORY = 16
+
+    def __init__(self):
+        self._cond = threading.Condition()
+        self._entry: Optional[tuple] = None   # (ref, model)
+        self._seen: "collections.deque[str]" = collections.deque(
+            maxlen=self._HISTORY)
+
+    def publish(self, ref: str, model) -> None:
+        with self._cond:
+            self._entry = (ref, model)
+            self._seen.append(ref)
+            self._cond.notify_all()
+
+    def resolve(self, ref: str, wait_s: float) -> tuple:
+        """``(model, waited seconds)``, or :class:`ModelRefMissing`."""
+        t0 = time.perf_counter()
+        deadline = t0 + wait_s
+        with self._cond:
+            while True:
+                if self._entry is not None and self._entry[0] == ref:
+                    model = self._entry[1]
+                    self._entry = None
+                    break
+                if ref in self._seen:
+                    _M_MODEL_REF.inc(outcome="missed")
+                    raise ModelRefMissing(
+                        ref, "is no longer held (replaced or taken)")
+                left = deadline - time.perf_counter()
+                if left <= 0:
+                    _M_MODEL_REF.inc(outcome="missed")
+                    raise ModelRefMissing(
+                        ref, f"did not arrive within {wait_s:g} s")
+                self._cond.wait(left)
+        waited = time.perf_counter() - t0
+        _M_MODEL_REF.inc(outcome="resolved")
+        _M_MODEL_REF_WAIT.observe(waited)
+        return model, waited
 
 
 class ControllerProxy(Protocol):
@@ -130,6 +194,10 @@ class Learner:
         # bounded reattach loop (tests tighten these)
         self.reattach_retries = 10
         self.reattach_backoff_s = 1.0
+        # the last TrainTask's named model, for evaluations by reference
+        # (the bound is tightened by tests)
+        self._named_model = ModelRefHolder()
+        self.model_ref_wait_s = MODEL_REF_WAIT_S
         # deliberate departure: a straggling completion rejected AFTER
         # leave_federation must not re-register us behind the operator's
         # back (reset by the next explicit join)
@@ -588,6 +656,10 @@ class Learner:
         """Non-blocking: cancels any running training, schedules this one."""
         if self._shutdown.is_set():
             return
+        if task.model_ref:
+            # before training is scheduled: an evaluation of these bytes
+            # may already be waiting for them (ModelRefHolder.resolve)
+            self._named_model.publish(task.model_ref, task.model)
         # capture the dispatch-time span context (the controller's round
         # span — via gRPC metadata cross-process, via the live contextvar
         # in-process): the train executor thread has its own contextvars
@@ -951,9 +1023,16 @@ class Learner:
             # earlier configuration instead of silently reactivating
             # subset semantics on a full blob.
             self._ship_regex = task.ship_tensor_regex
+            model = task.model
+            if task.model_ref and not len(model):
+                # the bytes a TrainTask carried, named in place of sent
+                model, waited = self._named_model.resolve(
+                    task.model_ref, self.model_ref_wait_s)
+                eval_sp.set_attr("model_ref", task.model_ref)
+                eval_sp.set_attr("ref_wait_ms", round(waited * 1e3, 3))
             # Evaluate on an explicit variables tree so a concurrently running
             # training task never races on the engine's model slot.
-            variables = self._load_model(task.model)
+            variables = self._load_model(model)
             evaluations: Dict[str, Dict[str, float]] = {}
             for name in task.datasets:
                 ds = self.datasets.get(name)

@@ -110,6 +110,11 @@ M_LEARNER_STEP_MILLISECONDS = "learner_step_milliseconds"
 M_LEARNER_TASKS_TOTAL = "learner_tasks_total"
 M_LEARNER_EVAL_DURATION_SECONDS = "learner_eval_duration_seconds"
 M_LEARNER_REATTACH_TOTAL = "learner_reattach_total"
+M_LEARNER_MODEL_REF_TOTAL = "learner_model_ref_total"
+M_LEARNER_MODEL_REF_WAIT_SECONDS = "learner_model_ref_wait_seconds"
+# evaluations by reference (controller/service.py RpcLearnerProxy)
+M_EVAL_MODEL_REF_TOTAL = "eval_model_ref_total"
+M_EVAL_MODEL_REF_SAVED_BYTES_TOTAL = "eval_model_ref_saved_bytes_total"
 # RPC transport (comm/rpc.py)
 M_RPC_PEER_BYTES_TOTAL = "rpc_peer_bytes_total"
 M_RPC_CLIENT_CALLS_TOTAL = "rpc_client_calls_total"
