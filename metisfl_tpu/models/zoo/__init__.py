@@ -5,8 +5,9 @@ TPU-native counterparts of the reference's example model zoo
 small federated workloads (MLP, CNNs, LSTM) plus the scale-ladder models
 from BASELINE.md (ResNet-20, ViT, BERT, Llama+LoRA) and the attention /
 state-space hybrid JambaLite (Mamba mixer + MQA attention, tied head), the
-latent-attention decoders MlaMoeLite and ScMoeLite, and DsaMoeLite
-(learned sparse attention over GQA beside routed experts).
+latent-attention decoders MlaMoeLite and ScMoeLite, DsaMoeLite
+(learned sparse attention over GQA beside routed experts) and KdaMoeLite
+(Kimi Delta Attention and sparse latent attention under four-stream mHC).
 """
 
 from metisfl_tpu.models.zoo.mlp import MLP, HousingMLP
@@ -16,9 +17,12 @@ from metisfl_tpu.models.zoo.rnn import LSTMClassifier
 from metisfl_tpu.models.zoo.transformer import (
     TRANSFORMER_RULES,
     BertLite,
+    DeltaMixer,
     DsaMoeLite,
     ExpertShareMLP,
+    HyperConnection,
     JambaLite,
+    KdaMoeLite,
     LatentAttention,
     LlamaLite,
     LoRADense,
@@ -37,6 +41,7 @@ __all__ = [
     "ViTLite", "BertLite", "LlamaLite", "JambaLite", "MambaMixer",
     "MlaMoeLite", "LatentAttention", "ExpertShareMLP",
     "ScMoeLite", "ShortcutMoEBlock", "DsaMoeLite", "SparseIndexer",
+    "KdaMoeLite", "DeltaMixer", "HyperConnection",
     "LoRADense", "MoEMLP",
     "TRANSFORMER_RULES",
 ]

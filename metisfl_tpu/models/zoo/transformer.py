@@ -4,7 +4,10 @@ with a share of its routed experts, MlaMoe-lite, and the shortcut-connected
 decoder ScMoe-lite (two latent-attention sublayers and two dense FFNs a
 layer beside one routed layer with zero-computation experts), and the
 decoder with learned sparse attention and routed experts, DsaMoe-lite
-(GQA whose queries attend to the keys a lightning indexer chooses).
+(GQA whose queries attend to the keys a lightning indexer chooses), and
+the hybrid KdaMoe-lite (Kimi Delta Attention and sparse latent attention
+under four-stream manifold-constrained hyper-connections, beside routed
+experts).
 
 The BASELINE.md scale ladder (ViT-B/16 semi-sync, BERT async + secure,
 Llama-3-8B-LoRA with in-learner sharding) needs transformer workloads the
@@ -176,9 +179,7 @@ class Attention(nn.Module):
     def _kernels(self) -> bool:
         """Whether the indexer's choice and the attention over it take the
         Pallas kernels: ``"auto"`` takes them on a TPU."""
-        if self.use_flash == "auto":
-            return jax.default_backend() == "tpu"
-        return bool(self.use_flash)
+        return _use_kernels(self.use_flash)
 
     @nn.compact
     def __call__(self, x, train: bool = False, cache=None, position=None):
@@ -359,6 +360,9 @@ class SwiGLU(nn.Module):
     hidden: int
     dtype: Any = None
     param_dtype: Any = jnp.float32
+    # > 0 clamps the two branches (``swiglu_limit``): the gate's product
+    # from above at ``limit``, the up product to [-limit, limit]
+    limit: float = 0.0
 
     @nn.compact
     def __call__(self, x):
@@ -366,6 +370,9 @@ class SwiGLU(nn.Module):
                                   param_dtype=self.param_dtype)
         gate = dense(self.hidden, name="gate")(x)
         up = dense(self.hidden, name="up")(x)
+        if self.limit:
+            gate = jnp.minimum(gate, self.limit)
+            up = jnp.clip(up, -self.limit, self.limit)
         return dense(self.dim, name="down")(nn.silu(gate) * up)
 
 
@@ -525,6 +532,9 @@ class LatentAttention(nn.Module):
     use_flash: Any = False
     dtype: Any = None
     param_dtype: Any = jnp.float32
+    # a SparseIndexer (unbound; adopted as "indexer") that reads the
+    # query latent c_q: each query attends to the keys it chooses
+    indexer: Any = None
 
     def rotary_frequencies(self) -> np.ndarray:
         return yarn_frequencies(self.rope_dim, self.rope_base,
@@ -579,6 +589,17 @@ class LatentAttention(nn.Module):
                 # the latent scaled
                 c_kv = (c_kv.astype(jnp.float32)
                         * self.kv_scale).astype(c_kv.dtype)
+            if not rope:
+                # no rotary columns: the keys are the latent's up-projection
+                # alone, and nothing zero-wide is traced
+                if cache is not None:
+                    raise ValueError(
+                        "latent attention without rotary columns has no "
+                        "cached path")
+                return self._nope(h, q, c_q, c_kv, proj)
+            if self.indexer is not None:
+                raise ValueError("the indexer's choice is wired for heads "
+                                 "with no rotary columns (rope_dim 0)")
             k_rope = kva[..., None, self.kv_rank:]          # (B, L, 1, rope)
             pos0 = (jnp.zeros((), jnp.int32) if cache is None
                     else jnp.asarray(position, jnp.int32))
@@ -626,6 +647,38 @@ class LatentAttention(nn.Module):
                            param_dtype=self.param_dtype, name="o_proj")(out)
         return out if cache is None else (out, cache)
 
+    def _kernels(self) -> bool:
+        return _use_kernels(self.use_flash)
+
+    def _nope(self, h, q, c_q, c_kv, proj):
+        """Attention of heads with no rotary columns (``rope_dim`` 0) over
+        every causal key or, with an ``indexer``, over the keys it chooses
+        from the query latent ``c_q``."""
+        B, L, H, nope = q.shape
+        vd = self.v_dim
+        words = (None if self.indexer is None
+                 else self.indexer(h, kernel=self._kernels(), q_in=c_q))
+        kv = proj("kv_b_proj", H * (nope + vd))(c_kv).reshape(
+            B, L, H, nope + vd)
+        q, k, v = (jnp.swapaxes(t, 1, 2)
+                   for t in (q, kv[..., :nope], kv[..., nope:]))
+        scale = self.softmax_scale()
+        if words is not None:
+            from metisfl_tpu.ops import sparse_attention as sa
+            with jax.named_scope("attn"):
+                out = (sa.selected_attention(q, k, v, words, scale)
+                       if self._kernels()
+                       else sa.attend_plain(q, k, v, words, scale))
+        elif self._kernels():
+            from metisfl_tpu.ops import flash_attention
+            out = flash_attention(q, k, v, True, None, None, None, scale)
+        else:
+            from metisfl_tpu.ops.flash_attention import _dense_attention
+            out = _dense_attention(q, k, v, True, scale)
+        out = jnp.swapaxes(out, 1, 2).reshape(B, L, H * vd)
+        return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="o_proj")(out)
+
 
 class ExpertShareMLP(nn.Module):
     """A routed-expert FFN that holds ``count`` of the router's
@@ -637,7 +690,9 @@ class ExpertShareMLP(nn.Module):
         g_e = s_e / (sum_chosen s + 1e-20) * routed_scale
         out = shared(h) + sum_{e chosen and held} g_e expert_e(h)
 
-    each expert and the shared one ``W_down(silu(W_gate h) * W_up h)``.
+    each expert and the shared one ``W_down(silu(W_gate h) * W_up h)``
+    (with ``swiglu_limit`` > 0, ``silu(min(W_gate h, limit)) *
+    clip(W_up h, -limit, limit)`` inside).
     ``score_func="softmax"`` scores by a softmax over the router's columns
     in the sigmoid's place, and ``norm_topk=False`` leaves the chosen
     scores as they are (``g_e = s_e * routed_scale``). With
@@ -682,6 +737,9 @@ class ExpertShareMLP(nn.Module):
     # None: the kernels on a TPU, ``ragged_dot`` elsewhere; True runs the
     # kernels in Pallas's interpreter (CPU tests)
     gmm_interpret: Any = None
+    # > 0 clamps every expert's and the shared expert's two branches (see
+    # SwiGLU.limit)
+    swiglu_limit: float = 0.0
 
     @nn.compact
     def __call__(self, x):
@@ -719,7 +777,7 @@ class ExpertShareMLP(nn.Module):
         out, sizes = gm.routed_experts(
             h.astype(dt), chosen, gates, w_gate.astype(dt), w_up.astype(dt),
             w_down.astype(dt), first=self.first, num_experts=width,
-            interpret=self.gmm_interpret)
+            interpret=self.gmm_interpret, limit=self.swiglu_limit)
         self.sow("intermediates", "moe_local_count",
                  jnp.sum(sizes).astype(jnp.float32))
         self.sow("intermediates", "moe_max_group_count",
@@ -735,6 +793,7 @@ class ExpertShareMLP(nn.Module):
             with jax.named_scope("moe_shared"):
                 out = out + SwiGLU(D, self.shared_hidden, dtype=self.dtype,
                                    param_dtype=self.param_dtype,
+                                   limit=self.swiglu_limit,
                                    name="shared")(h)
         return out.reshape(B, L, D)
 
@@ -759,7 +818,16 @@ class SparseIndexer(nn.Module):
     checkpoint name ``dsa_mask`` (a remat policy that saves it keeps the
     backward from choosing again), and sows under ``intermediates``
     ``dsa_selected_count`` (chosen pairs) and ``dsa_live_tiles_count``
-    ((query block, key block) pairs that hold one)."""
+    ((query block, key block) pairs that hold one).
+
+    ``q_in`` (a query latent) replaces ``h`` as the queries' input;
+    ``rotary=False`` leaves both sides unturned. ``key_pool`` P > 1 scores
+    the means of the keys over groups of P positions (``kbar_j``, groups
+    complete at ``t``) and takes the keys of the ``top_k / P`` best groups
+    (of every complete group while there are no more) and the keys ``<= t``
+    of the query's own group while it is incomplete; that choice is made
+    in XLA
+    (:func:`~metisfl_tpu.ops.sparse_attention.select_pooled`)."""
 
     heads: int = 16
     head_dim: int = 64
@@ -768,9 +836,11 @@ class SparseIndexer(nn.Module):
     eps: float = 1e-6           # the key's LayerNorm
     dtype: Any = None
     param_dtype: Any = jnp.float32
+    rotary: bool = True
+    key_pool: int = 1
 
     @nn.compact
-    def __call__(self, h, kernel: bool = False):
+    def __call__(self, h, kernel: bool = False, q_in=None):
         from jax.ad_checkpoint import checkpoint_name
 
         from metisfl_tpu.ops import sparse_attention as sa
@@ -780,20 +850,28 @@ class SparseIndexer(nn.Module):
                                   param_dtype=self.param_dtype)
         dt = self.dtype or jnp.float32
         with jax.named_scope("dsa_indexer"):
-            q = dense(H * D, name="wq")(h).reshape(B, L, H, D)
+            q = dense(H * D, name="wq")(h if q_in is None else q_in).reshape(
+                B, L, H, D)
             k = nn.LayerNorm(epsilon=self.eps, dtype=jnp.float32,
                              name="k_norm")(dense(D, name="wk")(h))
             w = dense(H, name="weights_proj")(h).astype(jnp.float32) * (
                 H ** -0.5 * D ** -0.5)
-            positions = jnp.arange(L, dtype=jnp.float32)
-            freqs = 1.0 / (self.rope_base ** (np.arange(0, D // 2) / (D // 2)))
-            q = jnp.swapaxes(_rotary(jnp.swapaxes(q, 1, 2), positions, freqs),
-                             1, 2).astype(dt)
-            k = _rotary(k, positions, freqs).astype(dt)
+            if self.rotary:
+                positions = jnp.arange(L, dtype=jnp.float32)
+                freqs = 1.0 / (self.rope_base
+                               ** (np.arange(0, D // 2) / (D // 2)))
+                q = jnp.swapaxes(_rotary(jnp.swapaxes(q, 1, 2), positions,
+                                         freqs), 1, 2).astype(dt)
+                k = _rotary(k, positions, freqs).astype(dt)
+            else:
+                q, k = q.astype(dt), k.astype(dt)
         q, k, w = (jax.lax.stop_gradient(t) for t in (q, k, w))
         with jax.named_scope("dsa_select"):
-            words = (sa.select(q, k, w, self.top_k) if kernel
-                     else sa.select_plain(q, k, w, self.top_k))
+            if self.key_pool > 1:
+                words = sa.select_pooled(q, k, w, self.top_k, self.key_pool)
+            else:
+                words = (sa.select(q, k, w, self.top_k) if kernel
+                         else sa.select_plain(q, k, w, self.top_k))
             words = checkpoint_name(words, "dsa_mask")
         for name, value in sa.counts(words, sa.block_edge(L)).items():
             self.sow("intermediates", name, value)
@@ -1657,3 +1735,332 @@ def _write_kv_slots(slots, batched, cache, new, position):
             (jnp.asarray(slot, position.dtype), zero, zero, position[slot],
              zero))
     return cache, True
+
+
+def _use_kernels(use_flash) -> bool:
+    """Whether a mixer takes its Pallas kernels: ``"auto"`` takes them on a
+    TPU."""
+    if use_flash == "auto":
+        return jax.default_backend() == "tpu"
+    return bool(use_flash)
+
+
+class DeltaMixer(nn.Module):
+    """Kimi Delta Attention (KDA), the linear-attention mixer of Kimi
+    Linear, on the block's normed input ``h``::
+
+        q, k, v = SiLU(conv(h W_q)), SiLU(conv(h W_k)), SiLU(conv(h W_v))
+        q, k    <- L2-normalised per head
+        g    = lower * sigmoid(exp(A_log) * ((h W_fa) W_fb + dt_bias))
+        beta = sigmoid(h W_b)                              one a head
+        S_t  = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+        o_t  = head_dim^-0.5 S_t^T q_t
+        out  = (RMSNorm_head(o) * sigmoid((h W_ga) W_gb)) W_o
+
+    ``conv`` is a causal depthwise convolution of ``conv`` taps (no bias),
+    ``g`` a decay per channel in (``lower``, 0), ``A_log`` one a head. The
+    recurrence is :mod:`metisfl_tpu.ops.delta_attention`: the kernels
+    ``kda_fwd`` / ``kda_bwd`` where ``use_flash`` asks for them (``"auto"``:
+    on a TPU; elsewhere in Pallas's interpreter), the chunked twin
+    otherwise. The gate,
+    ``beta`` and the recurrence are float32; ``lora_rank`` puts adapters on
+    ``q_proj`` and ``v_proj``."""
+
+    dim: int
+    heads: int = 4
+    head_dim: int = 16
+    conv: int = 4
+    gate_rank: int = 8
+    gate_lower_bound: float = -5.0
+    eps: float = 1e-5           # the output's per-head RMSNorm
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    use_flash: Any = False
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        from metisfl_tpu.ops import delta_attention as da
+        B, L, _ = h.shape
+        H, D = self.heads, self.head_dim
+        width = H * D
+
+        def proj(name, features, rank=0):
+            return LoRADense(features, rank=rank, alpha=self.lora_alpha,
+                             use_bias=False, dtype=self.dtype,
+                             param_dtype=self.param_dtype, name=name)
+
+        def conv(name, x):
+            w = self.param(name, nn.initializers.normal(self.conv ** -0.5),
+                           (self.conv, width), jnp.float32)
+            x = jnp.pad(x.astype(jnp.float32),
+                        ((0, 0), (self.conv - 1, 0), (0, 0)))
+            return sum(x[:, i:i + L] * w[i] for i in range(self.conv))
+
+        def heads(x, normed):
+            x = nn.silu(x).reshape(B, L, H, D)
+            if normed:
+                x = x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                      + 1e-6)
+            return jnp.swapaxes(x, 1, 2).astype(self.dtype or jnp.float32)
+
+        with jax.named_scope("kda"):
+            with jax.named_scope("kda_proj"):
+                q = proj("q_proj", width, self.lora_rank)(h)
+                k = proj("k_proj", width)(h)
+                v = proj("v_proj", width, self.lora_rank)(h)
+            with jax.named_scope("kda_conv"):
+                q = heads(conv("q_conv", q), True)
+                k = heads(conv("k_conv", k), True)
+                v = heads(conv("v_conv", v), False)
+            with jax.named_scope("kda_gate"):
+                a_log = self.param("A_log", nn.initializers.zeros, (H,),
+                                   jnp.float32)
+                dt_bias = self.param("dt_bias", nn.initializers.zeros,
+                                     (width,), jnp.float32)
+                f = proj("f_b_proj", width)(proj("f_a_proj", self.gate_rank)(
+                    h)).astype(jnp.float32) + dt_bias
+                f = jnp.exp(a_log)[:, None] * f.reshape(B, L, H, D)
+                g = self.gate_lower_bound * nn.sigmoid(f)
+                beta = nn.sigmoid(proj("b_proj", H)(h).astype(jnp.float32))
+            with jax.named_scope("kda_scan"):
+                args = (q, k, v, jnp.swapaxes(g, 1, 2),
+                        jnp.swapaxes(beta, 1, 2), D ** -0.5)
+                o = (da.delta_attention_kernels(*args)
+                     if _use_kernels(self.use_flash)
+                     else da.delta_attention_plain(*args))
+            with jax.named_scope("kda_out"):
+                o = nn.RMSNorm(epsilon=self.eps, dtype=jnp.float32,
+                               name="o_norm")(jnp.swapaxes(o, 1, 2))
+                gate = proj("g_b_proj", width)(proj("g_a_proj",
+                                                    self.gate_rank)(h))
+                o = o * nn.sigmoid(gate.astype(jnp.float32)).reshape(
+                    B, L, H, D)
+                return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                                param_dtype=self.param_dtype,
+                                name="o_proj")(
+                    o.reshape(B, L, width).astype(self.dtype or jnp.float32))
+
+
+def sinkhorn(m, iters: int, eps: float):
+    """``iters`` rounds of Sinkhorn-Knopp on positive ``m`` (..., n, n):
+    rows normalised to sum to 1, then columns, in turn."""
+    for _ in range(iters):
+        m = m / (jnp.sum(m, -1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, -2, keepdims=True) + eps)
+    return m
+
+
+class HyperConnection(nn.Module):
+    """Manifold-constrained hyper-connections (mHC) around one sublayer
+    ``inner``: the token's state is ``n`` residual streams, ``x`` (B, L,
+    n, dim)::
+
+        u      = RMSNorm(vec x)                          n dim wide, no scale
+        H_pre  = sigmoid(a_pre * u phi_pre + b_pre)        (n,)
+        H_post = 2 sigmoid(a_post * u phi_post + b_post)   (n,)
+        H_res  = Sinkhorn(exp(a_res * mat(u phi_res) + b_res))   (n, n)
+        x     <- H_res x + H_post^T inner(RMSNorm(H_pre x))
+
+    ``phi`` is one (n dim, 2n + n^2) matrix; the coefficients and the
+    mixing are float32 (the product at ``highest``), the streams are held
+    in the compute type. ``scope_name`` names the sublayer's own scope."""
+
+    dim: int
+    inner: Any
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    eps: float = 1e-5
+    scope_name: str = "mlp"
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        B, L, n, D = x.shape
+        f32 = jnp.float32
+        with jax.named_scope("mhc"):
+            with jax.named_scope("mhc_coeff"):
+                u = x.reshape(B, L, n * D).astype(f32)
+                u = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True)
+                                      + self.eps)
+                z = nn.Dense(2 * n + n * n, use_bias=False, dtype=f32,
+                             precision=jax.lax.Precision.HIGHEST,
+                             kernel_init=nn.initializers.normal(
+                                 (n * D) ** -0.5), name="phi")(u)
+                a = self.param("alpha", nn.initializers.constant(0.1), (3,),
+                               f32)
+                b_pre = self.param("b_pre", nn.initializers.zeros, (n,), f32)
+                b_post = self.param("b_post", nn.initializers.zeros, (n,), f32)
+                b_res = self.param("b_res", lambda _, s, d: jnp.eye(
+                    s[0], dtype=d), (n, n), f32)
+                pre = nn.sigmoid(a[0] * z[..., :n] + b_pre)
+                post = 2.0 * nn.sigmoid(a[1] * z[..., n:2 * n] + b_post)
+                logits = a[2] * z[..., 2 * n:].reshape(B, L, n, n) + b_res
+            with jax.named_scope("mhc_sinkhorn"):
+                mix = sinkhorn(jnp.exp(logits), self.sinkhorn_iters,
+                               self.hc_eps)
+            with jax.named_scope("mhc_pre"):
+                h = jnp.einsum("bln,blnd->bld", pre,
+                               x.astype(f32)).astype(x.dtype)
+        with jax.named_scope(self.scope_name):
+            y = self.inner(nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                                      name="norm")(h))
+        with jax.named_scope("mhc"), jax.named_scope("mhc_post"):
+            out = (jnp.einsum("blij,bljd->blid", mix, x.astype(f32))
+                   + post[..., None] * y.astype(f32)[:, :, None])
+            return out.astype(x.dtype)
+
+
+class HyperLayer(nn.Module):
+    """One layer of :class:`KdaMoeLite`: the mixer and the FFN, each a
+    sublayer under its own :class:`HyperConnection` (``mix``, ``mlp``;
+    scopes ``attention_mixer`` and ``mlp``, beside ``mhc``)."""
+
+    dim: int
+    mixer: Any
+    ffn: Any
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    eps: float = 1e-5
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        hyper = functools.partial(
+            HyperConnection, self.dim, streams=self.streams,
+            sinkhorn_iters=self.sinkhorn_iters, hc_eps=self.hc_eps,
+            eps=self.eps, dtype=self.dtype)
+        x = hyper(self.mixer, scope_name="attention_mixer", name="mix")(x)
+        return hyper(self.ffn, scope_name="mlp", name="mlp")(x)
+
+
+class KdaMoeLite(nn.Module):
+    """Decoder-only causal LM of the GLM-5 hybrid: a layer is a mixer and
+    an FFN under four-stream mHC (:class:`HyperConnection`). The mixer is
+    KDA (:class:`DeltaMixer`) where ``layer_types`` says
+    ``linear_attention``, and latent attention with no rotary columns over
+    the keys a pooling indexer chooses from the query latent
+    (:class:`LatentAttention` with a :class:`SparseIndexer`) where it says
+    ``deepseek_sparse_attention``; the FFN a dense SwiGLU where
+    ``mlp_layer_types`` says ``dense`` and a routed-expert layer holding a
+    share of the experts beside a shared one (:class:`ExpertShareMLP`,
+    sigmoid scores with a choosing bias, normalised, scaled) where it says
+    ``sparse``; every SwiGLU clamped at ``swiglu_limit``. The embedding is
+    copied into the streams; the head reads RMSNorm of their sum, untied,
+    float32. ``lora_rank`` puts adapters on KDA's ``q_proj`` and
+    ``v_proj`` and on the four latent projections; ``param_dtype`` is the
+    type of the frozen matrices and the embedding (norm scales, mHC, the
+    router, the head and the adapters stay float32). Under ``remat`` a
+    layer saves its choice of keys (``dsa_mask``) and recomputes the rest.
+    No cached decode (KDA's state and an indexer cache are not kept)."""
+
+    vocab_size: int = 8192
+    dim: int = 64
+    layer_types: tuple = ("linear_attention", "deepseek_sparse_attention")
+    mlp_layer_types: tuple = ("dense", "sparse")
+    heads: int = 4              # KDA
+    head_dim: int = 16
+    conv: int = 4
+    gate_rank: int = 8
+    gate_lower_bound: float = -5.0
+    mla_heads: int = 4
+    q_rank: int = 16
+    kv_rank: int = 8
+    nope_dim: int = 16
+    v_dim: int = 16
+    index_heads: int = 2
+    index_head_dim: int = 8
+    index_top_k: int = 8
+    index_pool: int = 4
+    ffn_dim: int = 96
+    moe_hidden: int = 32
+    num_experts: int = 16
+    top_k: int = 4
+    experts_first: int = 0
+    experts_count: int = 0      # 0 = all of them
+    shared_experts: int = 1
+    routed_scale: float = 1.0
+    swiglu_limit: float = 0.0
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    eps: float = 1e-5
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    use_flash: Any = False
+    remat: bool = False
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+
+    def _mixer(self, kind: str):
+        if kind == "linear_attention":
+            return DeltaMixer(
+                self.dim, self.heads, self.head_dim, conv=self.conv,
+                gate_rank=self.gate_rank,
+                gate_lower_bound=self.gate_lower_bound, eps=self.eps,
+                lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
+                use_flash=self.use_flash, dtype=self.dtype,
+                param_dtype=self.param_dtype, parent=None)
+        if kind != "deepseek_sparse_attention":
+            raise ValueError(f"unknown layer type {kind!r}")
+        indexer = SparseIndexer(
+            self.index_heads, self.index_head_dim, self.index_top_k,
+            eps=self.eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            rotary=False, key_pool=self.index_pool, parent=None)
+        return LatentAttention(
+            self.dim, self.mla_heads, self.q_rank, self.kv_rank,
+            self.nope_dim, 0, self.v_dim, eps=self.eps,
+            lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
+            use_flash=self.use_flash, dtype=self.dtype,
+            param_dtype=self.param_dtype, indexer=indexer, parent=None)
+
+    def _ffn(self, kind: str):
+        if kind == "dense":
+            return SwiGLU(self.dim, self.ffn_dim, dtype=self.dtype,
+                          param_dtype=self.param_dtype,
+                          limit=self.swiglu_limit, parent=None)
+        if kind != "sparse":
+            raise ValueError(f"unknown FFN type {kind!r}")
+        return ExpertShareMLP(
+            self.dim, self.moe_hidden, self.num_experts, self.top_k,
+            first=self.experts_first, count=self.experts_count,
+            shared_hidden=self.shared_experts * self.moe_hidden,
+            routed_scale=self.routed_scale, dtype=self.dtype,
+            param_dtype=self.param_dtype, swiglu_limit=self.swiglu_limit,
+            parent=None)
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, caches=None,
+                 position=None):
+        del train                               # no dropout anywhere
+        if caches is not None:
+            self.init_cache(tokens.shape[0], tokens.shape[1])
+        if len(self.layer_types) != len(self.mlp_layer_types):
+            raise ValueError("one mixer type and one FFN type a layer")
+        x = nn.Embed(self.vocab_size, self.dim, dtype=self.dtype,
+                     param_dtype=self.param_dtype, name="embed")(tokens)
+        B, L, D = x.shape
+        x = jnp.broadcast_to(x[:, :, None], (B, L, self.streams, D))
+        layer_cls = (nn.remat(HyperLayer,
+                              policy=jax.checkpoint_policies
+                              .save_only_these_names("dsa_mask"))
+                     if self.remat else HyperLayer)
+        for i, (mix, mlp) in enumerate(zip(self.layer_types,
+                                           self.mlp_layer_types)):
+            x = layer_cls(
+                self.dim, self._mixer(mix), self._ffn(mlp),
+                streams=self.streams, sinkhorn_iters=self.sinkhorn_iters,
+                hc_eps=self.hc_eps, eps=self.eps, dtype=self.dtype,
+                name=f"block_{i}")(x)
+        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype)(jnp.sum(
+            x.astype(jnp.float32), axis=2).astype(x.dtype))
+        return nn.Dense(self.vocab_size, use_bias=False,
+                        name="lm_head")(x.astype(jnp.float32))
+
+    def init_cache(self, batch: int, max_len: int):
+        raise ValueError(
+            "KdaMoeLite has no cached decode: it needs KDA's recurrent state "
+            "and an indexer cache beside the latent cache")

@@ -283,14 +283,19 @@ def chunk_rows(tokens: int, top_k: int, count: int, num_experts: int) -> int:
 
 
 def _chunk(h, gates, w_gate, w_up, w_down, routing: Routing, index,
-           rows: int, interpret):
+           rows: int, interpret, limit: float = 0.0):
     """Chunk ``index`` of the sorted rows: its part of the tokens' sums."""
     with jax.named_scope("moe_dispatch"):
         plan = layout(routing, rows, index * rows)
         x = to_rows(h, plan)
     with jax.named_scope("moe_experts"):
         mm = functools.partial(grouped_matmul, plan=plan, interpret=interpret)
-        y = mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+        if limit:
+            gate = jnp.minimum(mm(x, w_gate), limit)
+            y = mm(jax.nn.silu(gate) * jnp.clip(mm(x, w_up), -limit, limit),
+                   w_down)
+        else:
+            y = mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
     with jax.named_scope("moe_combine"):
         # rows past the live tiles hold whatever was there: chosen out,
         # not multiplied out
@@ -303,31 +308,33 @@ def _chunks(routing: Routing, rows: int):
     return -(-routing.tiles * TILE // rows)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _routed(h, gates, w_gate, w_up, w_down, routing: Routing, rows: int,
-            interpret):
+            interpret, limit: float = 0.0):
     def body(i, acc):
         return acc + _chunk(h, gates, w_gate, w_up, w_down, routing, i,
-                            rows, interpret).astype(jnp.float32)
+                            rows, interpret, limit).astype(jnp.float32)
 
     return jax.lax.fori_loop(0, _chunks(routing, rows), body,
                              jnp.zeros(h.shape, jnp.float32)).astype(h.dtype)
 
 
-def _routed_fwd(h, gates, w_gate, w_up, w_down, routing, rows, interpret):
-    return (_routed(h, gates, w_gate, w_up, w_down, routing, rows, interpret),
+def _routed_fwd(h, gates, w_gate, w_up, w_down, routing, rows, interpret,
+                limit=0.0):
+    return (_routed(h, gates, w_gate, w_up, w_down, routing, rows, interpret,
+                    limit),
             (h, gates, w_gate, w_up, w_down, routing))
 
 
-def _routed_bwd(rows, interpret, res, g):
+def _routed_bwd(rows, interpret, limit, res, g):
     h, gates, w_gate, w_up, w_down, routing = res
 
     def body(i, carry):
         # a chunk's products are computed again here: nothing of a chunk
         # outlives it
         _, vjp = jax.vjp(lambda h, gates: _chunk(
-            h, gates, w_gate, w_up, w_down, routing, i, rows, interpret),
-            h, gates)
+            h, gates, w_gate, w_up, w_down, routing, i, rows, interpret,
+            limit), h, gates)
         dh, dgates = vjp(g)
         return carry[0] + dh.astype(jnp.float32), carry[1] + dgates
 
@@ -341,9 +348,11 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 def routed_experts(h, chosen, gates, w_gate, w_up, w_down, *, first: int,
-                   num_experts: int, interpret: Optional[bool] = None):
+                   num_experts: int, interpret: Optional[bool] = None,
+                   limit: float = 0.0):
     """``sum_{e chosen and held} g_e W_down_e(silu(W_gate_e h) * W_up_e
-    h)`` for tokens ``h`` (T, d) with choices ``chosen`` and gates
+    h)`` (``limit`` > 0 clamps the two branches as ``SwiGLU.limit`` does)
+    for tokens ``h`` (T, d) with choices ``chosen`` and gates
     ``gates`` (T, K) over ``num_experts`` experts, of which ``w_*`` (G, .,
     .) are experts ``first .. first + G``. Returns the (T, d) sum and the
     held assignments a group, ``sizes`` (G,). Differentiated to ``h`` and
@@ -356,5 +365,5 @@ def routed_experts(h, chosen, gates, w_gate, w_up, w_down, *, first: int,
     with jax.named_scope("moe_dispatch"):
         routing = sort_choices(chosen, first, G)
     out = _routed(h, gates, w_gate, w_up, w_down, routing,
-                  chunk_rows(T, K, G, num_experts), interpret)
+                  chunk_rows(T, K, G, num_experts), interpret, float(limit))
     return out, routing.sizes

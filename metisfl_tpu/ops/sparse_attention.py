@@ -167,6 +167,32 @@ def select_plain(qi, ki, w, top_k: int, edge: Optional[int] = None):
     return pack(keys >= floor, edge)
 
 
+def select_pooled(qi, ki, w, top_k: int, pool: int,
+                  edge: Optional[int] = None):
+    """The choice over keys pooled by ``pool`` in XLA: the indexer scores
+    ``kbar_j``, the mean of ``ki`` over positions ``pool j .. pool j +
+    pool - 1``, for the groups complete at ``t``; query ``t`` takes the
+    keys of the ``top_k / pool`` best groups (ties all taken; every
+    complete group while there are no more) and its own group's keys
+    ``<= t`` while that group is incomplete. -> (B, L / 32, L) int32, the
+    blocks' packing."""
+    B, L, _, D = qi.shape
+    if L % pool or top_k % pool:
+        raise ValueError(f"the sequence ({L}) and top_k ({top_k}) must be "
+                         f"multiples of the pool ({pool})")
+    edge = block_edge(L, edge)
+    groups = L // pool
+    kbar = jnp.mean(ki.astype(jnp.float32).reshape(B, groups, pool, D),
+                    axis=2).astype(ki.dtype)
+    t = jnp.arange(L)[:, None]
+    complete = pool * jnp.arange(groups)[None, :] + pool - 1 <= t
+    keys = jnp.where(complete, order_keys(index_scores(qi, kbar, w)), _MIN)
+    floor = jnp.maximum(threshold(keys, top_k // pool), _MIN + 1)
+    s = jnp.arange(L)[None, :]
+    tail = (s // pool == t // pool) & (s <= t) & (t % pool != pool - 1)
+    return pack(jnp.repeat(keys >= floor, pool, axis=-1) | tail[None], edge)
+
+
 def _select_kernel(q_ref, k_ref, w_ref, out_ref, keys_s, *, top_k: int,
                    edge: int, heads: int, nk: int):
     i, j = pl.program_id(1), pl.program_id(2)

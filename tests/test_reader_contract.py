@@ -31,6 +31,7 @@ TRACE_ONLY = {
     "scmoe_experts_share",
     "dsa_step_mfu", "dsa_flash_roofline", "dsa_index_roofline",
     "dsa_index_share", "dsa_experts_roofline",
+    "kda_moe_step_mfu", "kda_roofline", "kda_share",
     "device_idle_share.train", "device_idle_share.serve",
     "decode_gap_read_ms", "decode_gap_loop_ms", "decode_gap_launch_ms",
     "decode_gap_named_share"}
