@@ -22,9 +22,17 @@ causal key while ``t < top_k``). Two kernels and their plain-JAX twins:
   chosen key keeps its grid step but computes nothing and fetches nothing
   (its block indices repeat the last live step's).
 
+Two edges. The choice's edge (``BLOCK``, 512) is ``dsa_select``'s row
+block and the mask's packing. The walk's edge (:func:`walk_edge`) is the
+kernels' tile: 1024 where it divides the sequence and scores and values
+are at most 256 wide, as for the causal flash kernels (a step's cost
+outside its two products is paid a quarter as often per query row), else
+the choice's edge. A tile of 1024 query rows holds two of the choice's
+blocks one after the other; :func:`selected_walk_census` counts the walk.
+
 Packing: within a block of ``edge`` query rows, word row ``g`` (of
 ``edge / 32``) holds row ``b * (edge / 32) + g`` at bit ``b``, so that a
-kernel unpacks a tile by shifting a broadcast along a leading axis.
+kernel unpacks a block by shifting a broadcast along a leading axis.
 
 The kernels' step machinery and constants are the flash kernels'
 (:mod:`metisfl_tpu.ops.flash_attention`), imported and left as they are.
@@ -42,18 +50,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from metisfl_tpu.ops.flash_attention import (_F_FIRST, _F_LAST, _LANES,
-                                             _NEG, _SEQ_PARAMS,
-                                             _STAT_LANES, _block_classes,
-                                             _gqa_shapes, _kv_head_index,
-                                             _score_scale, _step_tables)
+                                             _NEG, _STAT_LANES,
+                                             _WIDE_EDGE_MAX_WIDTH,
+                                             _block_classes, _gqa_shapes,
+                                             _kv_head_index, _score_scale,
+                                             _step_tables)
 
-BLOCK = 512             # query and key block edge (``sa_config``'s chunks)
+BLOCK = 512             # the choice's edge: ``dsa_select``'s row block and
+                        # the mask's packing (``sa_config``'s chunks)
+WALK = 1024             # the selected kernels' tile edge where it fits
 WORD = 32
 _MIN = int(np.iinfo(np.int32).min)
 _F_LIVE = 8             # a step whose pair holds a chosen key
 # the selection keeps a row block's scores (edge x L int32) in VMEM: 32 MB
 # at 16,384 tokens
 _SELECT_VMEM = 100 * 2 ** 20
+# a walk's tile of 1024 keeps the float32 scores, probabilities, unpacked
+# mask and, backward, dp and ds, 4 MB each: at scores and values 256 wide
+# the forward asks 17.7 MB, over a v5e's default scoped 16 MB
+_WALK_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=32 * 2 ** 20)
 
 
 def block_edge(L: int, edge: Optional[int] = None) -> int:
@@ -64,6 +81,30 @@ def block_edge(L: int, edge: Optional[int] = None) -> int:
         raise ValueError(f"sequence length {L} must be a multiple of the "
                          f"block edge {edge}, and the edge of {WORD}")
     return edge
+
+
+def walk_edge(L: int, D: int = 128, Dv: int = 128,
+              edge: Optional[int] = None) -> int:
+    """The selected kernels' tile edge at sequence length ``L``, scores
+    ``D`` and values ``Dv`` wide, over a choice packed at ``edge``:
+    ``WALK`` where ``WALK`` divides ``L``, the choice's edge divides
+    ``WALK`` and scores and values are at most 256 wide (a 1024 x 1024
+    float32 tile is 4 MB of VMEM and the kernels keep a few), else the
+    choice's edge."""
+    edge = block_edge(L, edge)
+    if (L % WALK == 0 and WALK % edge == 0
+            and max(D, Dv) <= _WIDE_EDGE_MAX_WIDTH):
+        return WALK
+    return edge
+
+
+def selected_walk_census(L: int, D: int = 128, Dv: int = 128) -> dict:
+    """The walk one head's selected kernels take at sequence length ``L``:
+    its tile ``edge`` and its causal ``steps``, counted from the very
+    classes the step tables are built from (1024 and 136 at 16,384 tokens
+    and heads of 128, where a walk at 512 takes 528)."""
+    edge = walk_edge(L, D, Dv)
+    return {"edge": edge, "steps": len(_causal_steps(L, edge)[0])}
 
 
 def _interpret(interpret: Optional[bool]) -> bool:
@@ -92,11 +133,20 @@ def unpack(words, edge: int):
 
 
 def _unpack_tile(words, edge: int):
-    """A kernel's tile: (edge / 32, n) int32 -> (edge, n) bool."""
-    rows, n = words.shape
-    spread = jnp.broadcast_to(words[None], (WORD, rows, n))
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (WORD, rows, n), 0)
-    return ((spread >> shifts) & 1).reshape(edge, n) != 0
+    """A kernel's tile of a choice packed at ``edge``: (walk / 32, n)
+    int32 -> (walk, n) bool, its word rows ``edge / 32`` at a time (the
+    choice's blocks, one after the other)."""
+    rows, n = edge // WORD, words.shape[1]
+
+    def bits(block):                    # (rows, n) -> (edge, n) int32
+        spread = jnp.broadcast_to(block[None], (WORD, rows, n))
+        shifts = jax.lax.broadcasted_iota(jnp.int32, (WORD, rows, n), 0)
+        return ((spread >> shifts) & 1).reshape(edge, n)
+
+    blocks = [bits(words[r:r + rows])
+              for r in range(0, words.shape[0], rows)]
+    return (blocks[0] if len(blocks) == 1
+            else jnp.concatenate(blocks, axis=0)) != 0
 
 
 def live_tiles(words, edge: int):
@@ -290,12 +340,17 @@ def attend_plain(q, k, v, words, scale: Optional[float] = None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
+def _causal_steps(L: int, edge: int, members=None):
+    """The flash walk's step tables over the causal pairs of ``edge``."""
+    return _step_tables(_block_classes(L, edge, edge, L, True), members)
+
+
 def _walk_tables(live, L: int, edge: int, members=None):
     """The flash walk over causal pairs (static) with the choice's live
     pairs marked (on the device): (query side, key side, flags), each
     (B * steps,) int32. A dead step's moving side repeats the last live
     step's of its output block, so that it fetches nothing."""
-    static = _step_tables(_block_classes(L, edge, edge, L, True), members)
+    static = _causal_steps(L, edge, members)
     q_of, k_of, flags = (jnp.asarray(x) for x in static)
     nq = L // edge
     steps = q_of.shape[0]
@@ -424,9 +479,10 @@ def _sel_dkv_kernel(qm_of, k_of, flags, q_ref, k_ref, v_ref, w_ref, do_ref,
 def _sel_forward(q, k, v, words, scale, interpret, edge):
     B, H, Hkv, L, D, Dv = _gqa_shapes(q, k, v)
     edge = block_edge(L, edge)
+    walk = walk_edge(L, D, Dv, edge)
     scale = _score_scale(q, scale)
     kv_ix = _kv_head_index(H, Hkv)
-    tables, steps = _walk_tables(live_tiles(words, edge), L, edge)
+    tables, steps = _walk_tables(live_tiles(words, walk), L, walk)
     at = lambda b, t: (b // H) * steps + t                  # noqa: E731
     q_index = lambda b, t, q_of, k_of, f: (b, q_of[at(b, t)], 0)  # noqa
     kv_index = lambda b, t, q_of, k_of, f: (             # noqa: E731
@@ -441,16 +497,16 @@ def _sel_forward(q, k, v, words, scale, interpret, edge):
                                         jnp.float32)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B * H, steps),
-            in_specs=[pl.BlockSpec((1, edge, D), q_index),
-                      pl.BlockSpec((1, edge, D), kv_index),
-                      pl.BlockSpec((1, edge, Dv), kv_index),
-                      pl.BlockSpec((1, edge // WORD, edge), w_index)],
-            out_specs=[pl.BlockSpec((1, edge, Dv), q_index),
-                       pl.BlockSpec((1, edge, _STAT_LANES), q_index)],
-            scratch_shapes=[pltpu.VMEM((edge, _LANES), jnp.float32),
-                            pltpu.VMEM((edge, _LANES), jnp.float32),
-                            pltpu.VMEM((edge, Dv), jnp.float32)]),
-        compiler_params=None if interpret else _SEQ_PARAMS,
+            in_specs=[pl.BlockSpec((1, walk, D), q_index),
+                      pl.BlockSpec((1, walk, D), kv_index),
+                      pl.BlockSpec((1, walk, Dv), kv_index),
+                      pl.BlockSpec((1, walk // WORD, walk), w_index)],
+            out_specs=[pl.BlockSpec((1, walk, Dv), q_index),
+                       pl.BlockSpec((1, walk, _STAT_LANES), q_index)],
+            scratch_shapes=[pltpu.VMEM((walk, _LANES), jnp.float32),
+                            pltpu.VMEM((walk, _LANES), jnp.float32),
+                            pltpu.VMEM((walk, Dv), jnp.float32)]),
+        compiler_params=None if interpret else _WALK_PARAMS,
         interpret=interpret, name="flash_fwd_sel",
     )(*tables, q.reshape(B * H, L, D), k.reshape(B * Hkv, L, D),
       v.reshape(B * Hkv, L, Dv), words)
@@ -461,7 +517,8 @@ def _sel_backward(q, k, v, words, out, lse, g, scale, interpret, edge):
     B, H, Hkv, L, D, Dv = _gqa_shapes(q, k, v)
     G = H // Hkv
     edge = block_edge(L, edge)
-    nq = L // edge
+    walk = walk_edge(L, D, Dv, edge)
+    nq = L // walk
     scale = _score_scale(q, scale)
     kv_ix = _kv_head_index(H, Hkv)
     flat = lambda x: x.reshape(-1, L, x.shape[-1])          # noqa: E731
@@ -470,11 +527,11 @@ def _sel_backward(q, k, v, words, out, lse, g, scale, interpret, edge):
     stat = lambda x: jnp.broadcast_to(                      # noqa: E731
         x.reshape(B * H, L)[..., None], (B * H, L, _STAT_LANES))
     lse, delta = stat(lse.astype(jnp.float32)), stat(delta)
-    live = live_tiles(words, edge)
+    live = live_tiles(words, walk)
     operands = (qf, kf, vf, words, gf, lse, delta)
     static = dict(scale=scale, edge=edge)
 
-    tables, steps = _walk_tables(live, L, edge)
+    tables, steps = _walk_tables(live, L, walk)
     at = lambda b, t: (b // H) * steps + t                  # noqa: E731
     q_index = lambda b, t, q_of, k_of, f: (b, q_of[at(b, t)], 0)  # noqa
     kv_index = lambda b, t, q_of, k_of, f: (             # noqa: E731
@@ -486,22 +543,22 @@ def _sel_backward(q, k, v, words, out, lse, g, scale, interpret, edge):
         out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B * H, steps),
-            in_specs=[pl.BlockSpec((1, edge, D), q_index),
-                      pl.BlockSpec((1, edge, D), kv_index),
-                      pl.BlockSpec((1, edge, Dv), kv_index),
-                      pl.BlockSpec((1, edge // WORD, edge), w_index),
-                      pl.BlockSpec((1, edge, Dv), q_index),
-                      pl.BlockSpec((1, edge, _STAT_LANES), q_index),
-                      pl.BlockSpec((1, edge, _STAT_LANES), q_index)],
-            out_specs=pl.BlockSpec((1, edge, D), q_index),
-            scratch_shapes=[pltpu.VMEM((edge, D), jnp.float32)]),
-        compiler_params=None if interpret else _SEQ_PARAMS,
+            in_specs=[pl.BlockSpec((1, walk, D), q_index),
+                      pl.BlockSpec((1, walk, D), kv_index),
+                      pl.BlockSpec((1, walk, Dv), kv_index),
+                      pl.BlockSpec((1, walk // WORD, walk), w_index),
+                      pl.BlockSpec((1, walk, Dv), q_index),
+                      pl.BlockSpec((1, walk, _STAT_LANES), q_index),
+                      pl.BlockSpec((1, walk, _STAT_LANES), q_index)],
+            out_specs=pl.BlockSpec((1, walk, D), q_index),
+            scratch_shapes=[pltpu.VMEM((walk, D), jnp.float32)]),
+        compiler_params=None if interpret else _WALK_PARAMS,
         interpret=interpret, name="flash_bwd_dq_sel",
     )(*tables, *operands)
 
     # dK/dV: a KV head's key block walks (group member, query block),
     # member-major, as the flash kernels' own dK/dV does
-    tables, steps = _walk_tables(live, L, edge, members=G)
+    tables, steps = _walk_tables(live, L, walk, members=G)
     at = lambda b, t: (b // Hkv) * steps + t                # noqa: E731
 
     def qm_index(b, t, qm_of, k_of, f):
@@ -517,18 +574,18 @@ def _sel_backward(q, k, v, words, out, lse, g, scale, interpret, edge):
                    jax.ShapeDtypeStruct((B * Hkv, L, Dv), v.dtype)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B * Hkv, steps),
-            in_specs=[pl.BlockSpec((1, edge, D), qm_index),
-                      pl.BlockSpec((1, edge, D), k_index),
-                      pl.BlockSpec((1, edge, Dv), k_index),
-                      pl.BlockSpec((1, edge // WORD, edge), wk_index),
-                      pl.BlockSpec((1, edge, Dv), qm_index),
-                      pl.BlockSpec((1, edge, _STAT_LANES), qm_index),
-                      pl.BlockSpec((1, edge, _STAT_LANES), qm_index)],
-            out_specs=[pl.BlockSpec((1, edge, D), k_index),
-                       pl.BlockSpec((1, edge, Dv), k_index)],
-            scratch_shapes=[pltpu.VMEM((edge, D), jnp.float32),
-                            pltpu.VMEM((edge, Dv), jnp.float32)]),
-        compiler_params=None if interpret else _SEQ_PARAMS,
+            in_specs=[pl.BlockSpec((1, walk, D), qm_index),
+                      pl.BlockSpec((1, walk, D), k_index),
+                      pl.BlockSpec((1, walk, Dv), k_index),
+                      pl.BlockSpec((1, walk // WORD, walk), wk_index),
+                      pl.BlockSpec((1, walk, Dv), qm_index),
+                      pl.BlockSpec((1, walk, _STAT_LANES), qm_index),
+                      pl.BlockSpec((1, walk, _STAT_LANES), qm_index)],
+            out_specs=[pl.BlockSpec((1, walk, D), k_index),
+                       pl.BlockSpec((1, walk, Dv), k_index)],
+            scratch_shapes=[pltpu.VMEM((walk, D), jnp.float32),
+                            pltpu.VMEM((walk, Dv), jnp.float32)]),
+        compiler_params=None if interpret else _WALK_PARAMS,
         interpret=interpret, name="flash_bwd_dkv_sel",
     )(*tables, *operands)
     return (dq.reshape(B, H, L, D), dk.reshape(B, Hkv, L, D),
@@ -540,9 +597,10 @@ def selected_attention(q, k, v, words, scale: Optional[float] = None,
                        interpret: Optional[bool] = None,
                        edge: Optional[int] = None):
     """Flash attention of ``q`` (B, H, L, D) over the keys ``words`` (the
-    packed choice, (B, L / 32, L)) marks, GQA-native as
-    :func:`~metisfl_tpu.ops.flash_attention.flash_attention` is; the choice
-    carries no gradient."""
+    packed choice, (B, L / 32, L), at the choice's ``edge``) marks,
+    GQA-native as :func:`~metisfl_tpu.ops.flash_attention.flash_attention`
+    is, walked in tiles of :func:`walk_edge`; the choice carries no
+    gradient."""
     return _sel_forward(q, k, v, words, scale, _interpret(interpret),
                         edge)[0]
 

@@ -210,16 +210,31 @@ def _scattered_choice(L, edge, dead=()):
     return sa.pack(jnp.asarray(mask), edge)
 
 
-@pytest.mark.parametrize("dead", [(), ((2, 0), (3, 1), (3, 0))],
-                         ids=["all-live", "dead-tiles"])
-def test_selected_flash_kernels_match_plain_masked_attention(dead):
-    """GQA 4 on 2 heads, 128 tokens in blocks of 32: forward and the
-    gradients of q, k and v; with dead pairs the step tables mark them and
-    the kernels skip them."""
+# the (query block, key block) pairs of 32 left empty, and how many tiles
+# of the walk that kills: at 64 the tile (1, 0) holds four of them
+_DEAD = {32: (((2, 0), (3, 1), (3, 0)), 3),
+         64: (((2, 0), (2, 1), (3, 0), (3, 1)), 1)}
+
+
+@pytest.mark.parametrize("dead, walk", [
+    (False, 32), (True, 32), (False, 64), (True, 64),
+], ids=["all-live", "dead-tiles", "all-live-walk-64", "dead-tiles-walk-64"])
+def test_selected_flash_kernels_match_plain_masked_attention(
+        dead, walk, monkeypatch):
+    """GQA 4 on 2 heads, 128 tokens, the choice packed in blocks of 32 and
+    walked in tiles of 32 or, with the walk's edge set to 64, of 64 (two
+    of the choice's blocks a tile, as 1024 holds two of 512): forward and
+    the gradients of q, k and v; with dead pairs the step tables mark the
+    tiles that hold none and the kernels skip them."""
     L, edge = 128, 32
-    words = _scattered_choice(L, edge, dead)
-    live = np.asarray(sa.live_tiles(words, edge))
-    assert live.sum() == 2 * (10 - len(dead))
+    if walk != edge:
+        monkeypatch.setattr(sa, "WALK", walk)
+    assert sa.walk_edge(L, 16, 16, edge) == walk
+    pairs, killed = _DEAD[walk] if dead else ((), 0)
+    words = _scattered_choice(L, edge, pairs)
+    live = np.asarray(sa.live_tiles(words, walk))
+    n = L // walk
+    assert live.sum() == 2 * (n * (n + 1) // 2 - killed)
     q = _random(1, 2, 4, L, 16)
     k, v = _random(2, 2, 2, L, 16), _random(3, 2, 2, L, 16)
     ct = _random(4, 2, 4, L, 16)
@@ -233,10 +248,28 @@ def test_selected_flash_kernels_match_plain_masked_attention(dead):
     want = run(lambda q, k, v: sa.attend_plain(q, k, v, words, edge=edge))
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-5)
-    tables, steps = sa._walk_tables(jnp.asarray(live), L, edge)
+    tables, steps = sa._walk_tables(jnp.asarray(live), L, walk)
     flags = np.asarray(tables[2]).reshape(2, steps)
-    assert steps == 10
+    assert steps == n * (n + 1) // 2
     assert ((flags & sa._F_LIVE) != 0).sum() == live.sum()
+
+
+@pytest.mark.parametrize("L, D, Dv, edge, steps", [
+    (16384, 128, 128, 1024, 136),       # keye: 528 steps at 512
+    (4096, 256, 256, 1024, 10),         # glm's latent layer
+    (4096, 384, 128, 512, 36),          # wider than 256: the choice's edge
+    (1536, 128, 128, 512, 6),           # 1024 does not divide
+    (512, 128, 128, 512, 1),
+    (64, 128, 128, 64, 1),              # the toy cells' SEQ: the parent's
+])
+def test_walk_edge_and_its_census(L, D, Dv, edge, steps):
+    """The walk's edge follows the shapes as the causal kernels' does, and
+    at or under 512 tokens it is the choice's own (the parent's walk)."""
+    assert sa.walk_edge(L, D, Dv) == edge
+    assert sa.selected_walk_census(L, D, Dv) == {"edge": edge,
+                                                 "steps": steps}
+    if L <= sa.BLOCK:
+        assert edge == sa.block_edge(L)
 
 
 def test_packing_round_trips_and_counts():
@@ -366,11 +399,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_kernels_compile_for_the_chip_at_published_widths(one_chip):
-    """16,384 tokens: the choice (16 indexer heads of 64, top 2,048; a row
-    block's scores in VMEM) and attention over it (32 heads on 4 of 128),
-    forward and backward: one ``dsa_select`` and three selected-flash
-    kernels."""
+def _compile_for_the_chip(one_chip, H, Hkv, L, D, Dv, choose):
+    """Compile the choice (when ``choose``: 16 indexer heads of 64, top
+    2,048) and attention over it, forward and backward, for one v5e chip:
+    (the choice's executable or None, attention's)."""
     from jax.experimental.compilation_cache import compilation_cache
     before = {k: getattr(jax.config, k)
               for k in ("jax_enable_x64", "jax_enable_compilation_cache")}
@@ -380,8 +412,8 @@ def test_kernels_compile_for_the_chip_at_published_widths(one_chip):
     try:
         shape = lambda dtype, *s: jax.ShapeDtypeStruct(     # noqa: E731
             s, dtype, sharding=one_chip)
-        bf, L = jnp.bfloat16, 16384
-        select = jax.jit(lambda qi, ki, w: sa.select(
+        bf = jnp.bfloat16
+        select = choose and jax.jit(lambda qi, ki, w: sa.select(
             qi, ki, w, 2048, interpret=False)).lower(
                 shape(bf, 1, L, 16, 64), shape(bf, 1, L, 64),
                 shape(jnp.float32, 1, L, 16)).compile()
@@ -391,17 +423,37 @@ def test_kernels_compile_for_the_chip_at_published_widths(one_chip):
                 q, k, v, words, None, False).astype(jnp.float32) * ct)
 
         attention = jax.jit(jax.grad(attend, argnums=(0, 1, 2))).lower(
-            shape(bf, 1, 32, L, 128), shape(bf, 1, 4, L, 128),
-            shape(bf, 1, 4, L, 128), shape(jnp.int32, 1, L // 32, L),
-            shape(jnp.float32, 1, 32, L, 128)).compile()
+            shape(bf, 1, H, L, D), shape(bf, 1, Hkv, L, D),
+            shape(bf, 1, Hkv, L, Dv), shape(jnp.int32, 1, L // 32, L),
+            shape(jnp.float32, 1, H, L, Dv)).compile()
     finally:
         for k, v in before.items():
             jax.config.update(k, v)
         compilation_cache.reset_cache()
     call = 'custom_call_target="tpu_custom_call"'
-    assert select.as_text().count(call) == 1
     assert attention.as_text().count(call) == 3
     assert attention.memory_analysis().temp_size_in_bytes < 1e9
+    return select or None, attention
+
+
+def test_kernels_compile_for_the_chip_at_published_widths(one_chip):
+    """16,384 tokens: the choice (16 indexer heads of 64, top 2,048; a row
+    block's scores in VMEM) and attention over it (32 heads on 4 of 128),
+    forward and backward: one ``dsa_select`` and three selected-flash
+    kernels, walked in tiles of 1024."""
+    assert sa.walk_edge(16384, 128, 128) == 1024
+    select, _ = _compile_for_the_chip(one_chip, 32, 4, 16384, 128, 128,
+                                      True)
+    assert select.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 1
+
+
+def test_kernels_compile_for_the_chip_at_the_latent_layer_widths(one_chip):
+    """glm's latent layer, 64 heads of 256 / 256 at 4,096 tokens over a
+    pooled choice: the three selected-flash kernels at the walk's edge
+    fit the chip's VMEM."""
+    assert sa.walk_edge(4096, 256, 256) == 1024
+    _compile_for_the_chip(one_chip, 64, 64, 4096, 256, 256, False)
 
 
 # --------------------------------------------------------------------- #
